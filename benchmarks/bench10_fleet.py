@@ -93,9 +93,9 @@ def main() -> int:
         args.toggles = min(args.toggles, 20)
         args.failover_checks = min(args.failover_checks, 100)
 
-    from benchmarks.common import emit, maybe_force_cpu, note
+    from benchmarks.common import emit, start_backend, note
 
-    platform = maybe_force_cpu()
+    platform = start_backend()
 
     import random
     from dataclasses import replace

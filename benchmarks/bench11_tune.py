@@ -83,9 +83,9 @@ def main() -> int:
         args.lookups = min(args.lookups, 90)
 
     from benchmarks.bench9_serve import build_store_world
-    from benchmarks.common import emit, maybe_force_cpu, note
+    from benchmarks.common import emit, start_backend, note
 
-    platform = maybe_force_cpu()
+    platform = start_backend()
     import numpy as np
 
     from gochugaru_tpu import consistency

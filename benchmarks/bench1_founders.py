@@ -18,7 +18,7 @@ import sys as _sys
 _sys.path.insert(0, _os.path.dirname(_os.path.dirname(_os.path.abspath(__file__))))
 
 from benchmarks.common import (
-    maybe_force_cpu,
+    start_backend,
     NORTH_STAR_P99_MS,
     emit,
     emit_small_batch_row,
@@ -40,7 +40,7 @@ definition document {
 
 
 def main() -> None:
-    note(f"platform={maybe_force_cpu()}")
+    note(f"platform={start_backend()}")
     client = new_tpu_evaluator()
     ctx = background()
     client.write_schema(ctx, SCHEMA)

@@ -12,7 +12,7 @@ One JSON line:
    "shed_rate": N, "retry_count": N, "faults_injected": N, ...}
 
 CPU-proxy by design (`force_cpu_platform(8)`): sharded throughput has
-never been timed even on the virtual mesh (VERDICT r05 weak #6) — this
+never been timed even on the virtual mesh (round-5 review, weak #6) — this
 row is that timing, plus the collective-overhead ratio a real multichip
 run will be judged against.
 """
@@ -43,8 +43,9 @@ def main() -> int:
     from bench import build_world
     from gochugaru_tpu.engine.device import DeviceEngine
 
-    jax.config.update("jax_compilation_cache_dir", "/tmp/gochugaru_xla_cache_h2")
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+    from gochugaru_tpu.utils.platform import configure_compile_cache
+
+    configure_compile_cache()
 
     cs, snap, users, repos, slot = build_world(
         n_repos=args.repos, n_users=args.users

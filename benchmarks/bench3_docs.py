@@ -16,7 +16,7 @@ import sys as _sys
 _sys.path.insert(0, _os.path.dirname(_os.path.dirname(_os.path.abspath(__file__))))
 
 from benchmarks.common import (
-    maybe_force_cpu,
+    start_backend,
     NORTH_STAR_P99_MS,
     NORTH_STAR_RATE,
     emit,
@@ -138,7 +138,7 @@ def build_world():
 
 
 def main() -> None:
-    note(f"platform={maybe_force_cpu()}")
+    note(f"platform={start_backend()}")
     from gochugaru_tpu.engine.device import DeviceEngine
 
     cs, snap, users, docs, slot = build_world()

@@ -21,7 +21,7 @@ import sys as _sys
 _sys.path.insert(0, _os.path.dirname(_os.path.dirname(_os.path.abspath(__file__))))
 
 from benchmarks.common import (
-    maybe_force_cpu,
+    start_backend,
     NORTH_STAR_P99_MS,
     NORTH_STAR_RATE,
     emit,
@@ -109,7 +109,7 @@ def main() -> None:
     ap.add_argument("--edges", type=int, default=100_000_000)
     ap.add_argument("--batch", type=int, default=100_000)
     args = ap.parse_args()
-    note(f"platform={maybe_force_cpu()}")
+    note(f"platform={start_backend()}")
 
     from gochugaru_tpu.engine.device import DeviceEngine
 

@@ -31,7 +31,7 @@ import sys as _sys
 
 _sys.path.insert(0, _os.path.dirname(_os.path.dirname(_os.path.abspath(__file__))))
 
-from benchmarks.common import maybe_force_cpu, emit, note
+from benchmarks.common import start_backend, emit, note
 
 SCHEMA = """
 definition user {}
@@ -104,7 +104,7 @@ def main() -> None:
     # other ~95% of the chain sees (the excluded cost is printed)
     ap.add_argument("--warmup", type=int, default=20)
     args = ap.parse_args()
-    note(f"platform={maybe_force_cpu()}")
+    note(f"platform={start_backend()}")
 
     from gochugaru_tpu import rel as relmod
     from gochugaru_tpu.engine.device import DeviceEngine

@@ -40,7 +40,7 @@ from benchmarks.common import (
     emit,
     emit_small_batch_row,
     est_bytes_per_check,
-    maybe_force_cpu,
+    start_backend,
     measured_rate_flat,
     note,
     roofline_columns,
@@ -94,13 +94,13 @@ def _dispatch_once(eng, dsnap, snap, q_res, q_perm, q_subj):
 
 def main() -> None:
     plats = _os.environ.get("JAX_PLATFORMS", "").strip().lower()
-    if _os.environ.get("GOCHUGARU_FORCE_CPU") == "1" or plats.startswith("cpu"):
+    if plats.startswith("cpu"):
         # the routed section needs a multi-device proxy: 8 virtual CPU
         # devices, set BEFORE the backend initializes (bench2's recipe)
         from gochugaru_tpu.utils.platform import force_cpu_platform
 
         force_cpu_platform(8)
-    note(f"platform={maybe_force_cpu()}")
+    note(f"platform={start_backend()}")
     _sys.argv = [_sys.argv[0], "--scale", str(_ARGS.scale)]
     from benchmarks.bench3_docs import build_world
 

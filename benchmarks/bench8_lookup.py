@@ -52,7 +52,7 @@ _sys.path.insert(0, _os.path.dirname(_os.path.dirname(_os.path.abspath(__file__)
 from benchmarks.common import (
     emit,
     join_lookup_prewarm,
-    maybe_force_cpu,
+    start_backend,
     note,
 )
 
@@ -61,7 +61,7 @@ CANDIDATE_RATE_BAR = 1_000_000
 
 
 def main() -> None:
-    note(f"platform={maybe_force_cpu()}")
+    note(f"platform={start_backend()}")
     from benchmarks.bench3_docs import EPOCH, build_world
     from gochugaru_tpu.engine import lookup as lm
     from gochugaru_tpu.engine import spmv

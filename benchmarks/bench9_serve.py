@@ -133,12 +133,12 @@ def main() -> int:
     from benchmarks.common import (
         NORTH_STAR_RATE,
         emit,
-        maybe_force_cpu,
+        start_backend,
         note,
         small_batch_latency,
     )
 
-    platform = maybe_force_cpu()
+    platform = start_backend()
     import gc
 
     import numpy as np

@@ -16,14 +16,14 @@ import sys as _sys
 
 _sys.path.insert(0, _os.path.dirname(_os.path.dirname(_os.path.abspath(__file__))))
 
-from benchmarks.common import maybe_force_cpu, emit, note, peak_rss_mb
+from benchmarks.common import start_backend, emit, note, peak_rss_mb
 
 
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--edges", type=int, default=10_000_000)
     args = ap.parse_args()
-    note(f"platform={maybe_force_cpu()}")
+    note(f"platform={start_backend()}")
 
     from gochugaru_tpu import consistency, rel
     from gochugaru_tpu.client import Client

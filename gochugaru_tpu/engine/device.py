@@ -1475,7 +1475,7 @@ class DeviceEngine:
                 if out is not None:
                     return out
             now_flat = jnp.int32(snap.now_rel32(now_us))
-            PB = self._pipeline_batch()
+            PB = self.config.pipeline_batch()
             if PB and B > PB and dsnap.flat_meta is not None:
                 # sub-batch pipeline: dispatch every chunk before fetching
                 # any (the async queue overlaps lowering with compute); one
@@ -1588,14 +1588,6 @@ class DeviceEngine:
         }
         return queries, qctx
 
-    def _pipeline_batch(self) -> int:
-        """Resolved sub-batch pipeline size (config None = backend auto:
-        TPU queues overlap, one CPU core doesn't)."""
-        PB = self.config.flat_pipeline_batch
-        if PB is None:
-            return 32_768 if jax.default_backend() == "tpu" else 0
-        return PB
-
     def check_columns_pipelined(
         self,
         dsnap: DeviceSnapshot,
@@ -1615,7 +1607,7 @@ class DeviceEngine:
         the first results after one sub-batch latency instead of the
         whole batch's (BASELINE config-4 tail; the serving analogue of
         the reference's chunked CheckIter, client/client.go:164-180)."""
-        PB = sub_batch or self._pipeline_batch() or q_res.shape[0]
+        PB = sub_batch or self.config.pipeline_batch() or q_res.shape[0]
         B = q_res.shape[0]
         outs = []
         for lo in range(0, B, PB):

@@ -317,41 +317,61 @@ def slice_blocks(tbl, start, cap: int):
     tbl.shape[0] - cap (interleave_* pad enough rows for any real bucket
     offset).
 
-    The lowering is backend-dependent (measured on real silicon,
-    tpu_attempts/micro_blocks.py): on TPU a vmapped dynamic_slice
-    serializes to ~1.2us per block (0.75M blocks/s), while cap·w
-    independent flat 1-D gathers run ~10x faster (7M/s) because TPU 1-D
-    gathers pipeline many outstanding HBM loads.  Every other backend
-    (CPU at ~80-95M blocks/s, and any backend this lowering was never
-    measured on) keeps the fused dynamic_slice form.  The branch keys off
-    the process default backend at trace time — an explicit
-    jit(backend=...) override on a TPU host still traces the TPU form."""
+    The lowering is backend-dependent.  The one TPU run this repo has of
+    it (tpu_attempts/FIRST_CONTACT_r05.jsonl: 212k–272k checks/s, flat
+    in batch size) showed the vmapped dynamic_slice serializing, so on
+    TPU the block is fetched as cap·w independent flat 1-D gathers, which
+    pipeline many outstanding HBM loads; by how much that wins is not
+    measured.  Every other backend keeps the fused dynamic_slice form.
+    The branch keys off the process default backend at trace time — an
+    explicit jit(backend=...) override on a TPU host still traces the
+    TPU form.  Both forms return the same bits (tests/test_hash.py)."""
     import jax
     import jax.numpy as jnp
+
+    s = jnp.clip(start, 0, tbl.shape[0] - cap).reshape(-1)
+    form = (
+        _slice_blocks_flat if jax.default_backend() == "tpu"
+        else _slice_blocks_dynamic
+    )
+    return form(tbl, s, cap).reshape(
+        tuple(jnp.shape(start)) + (cap, tbl.shape[1])
+    )
+
+
+def _slice_blocks_dynamic(tbl, s, cap: int):
+    """[N, cap, w] blocks at clamped int32[N] starts: one vmapped
+    dynamic_slice."""
+    import jax
     from jax import lax
 
     w = tbl.shape[1]
-    s = jnp.clip(start, 0, tbl.shape[0] - cap)
-    if jax.default_backend() != "tpu":
-        blk = jax.vmap(lambda s: lax.dynamic_slice(tbl, (s, 0), (cap, w)))(
-            s.reshape(-1)
+    return jax.vmap(lambda s: lax.dynamic_slice(tbl, (s, 0), (cap, w)))(s)
+
+
+def _slice_blocks_flat(tbl, s, cap: int):
+    """[N, cap, w] blocks at clamped int32[N] starts: cap·w flat 1-D
+    gathers over the row-major table.  The flat offsets are int32 (JAX
+    narrows an int64 index without ``jax_enable_x64``, which would wrap
+    the offset under ``promise_in_bounds``), so a table they cannot
+    address is refused at trace time instead of read wrongly."""
+    import jax.numpy as jnp
+
+    rows, w = tbl.shape
+    if rows * w > 2**31 - 1:
+        raise ValueError(
+            f"slice_blocks: table {rows}x{w} has more than 2^31-1"
+            " elements; int32 flat gather offsets cannot address it —"
+            " split the table"
         )
-        return blk.reshape(tuple(jnp.shape(start)) + (cap, w))
     flat = tbl.reshape(-1)
-    # flat addressing can exceed int32 (n_pad·w > 2^31 at ~100M caveated
-    # rows): widen the base to int64 there — the gathers themselves move
-    # the same bytes, only the index math widens
-    if tbl.shape[0] * w > 2**31 - 1:
-        base = s.astype(jnp.int64) * w
-    else:
-        base = s * w
+    base = s * w
     cols = [
         take_in_bounds(flat, base + (j * w + k))
         for j in range(cap)
         for k in range(w)
     ]
-    blk = jnp.stack(cols, axis=-1)
-    return blk.reshape(tuple(jnp.shape(start)) + (cap, w))
+    return jnp.stack(cols, axis=-1).reshape(s.shape[0], cap, w)
 
 
 # ---------------------------------------------------------------------------
@@ -361,17 +381,16 @@ def slice_blocks(tbl, start, cap: int):
 # The off+interleave layout above still pays 2 sequential gathers per
 # probe (bucket offset, then block) and — worse — lets build_hash balloon
 # the offsets array to 8x entries chasing cap<=4 (a 2.6M-entry fold table
-# grew a 256MB off array).  On TPU the winning shape (measured:
-# tpu_attempts/micro_blocks.py, ~48M probes/s vs 0.75M for vmapped
-# dynamic_slice and 7M for flat gathers) is ONE row gather: store bucket
-# b's entries IN row b of an int32[size, cap*w] matrix, padded with -1.
-# Probe = hash -> tbl[h] -> compare, a single contiguous 64-128B fetch
-# per query.
+# grew a 256MB off array).  The shape built for TPU is ONE row gather:
+# store bucket b's entries IN row b of an int32[size, cap*w] matrix,
+# padded with -1.  Probe = hash -> tbl[h] -> compare, a single contiguous
+# 64-128B fetch per query.  Its rate on a TPU against the off+block
+# layout: not measured (no source for an earlier figure is in the tree).
 #
 # The Poisson tail would force cap (and the whole matrix width) up to the
 # fullest bucket, so entries beyond ``cap`` per bucket SPILL to a second,
 # much smaller aligned table under a salted hash; the probe fetches both
-# rows (2 gathers, still 24M+/s) and the kernel sees one concatenated
+# rows (2 gathers) and the kernel sees one concatenated
 # candidate block.  Worlds whose duplicate-key multiplicity exceeds the
 # spill cap fall back to the off+interleave layout (build returns None).
 

@@ -203,26 +203,6 @@ class LatencyPath:
             )
         return self._shape_fp
 
-    def _donate(self) -> bool:
-        cfg = self.engine.config
-        if cfg.latency_donate is not None:
-            return bool(cfg.latency_donate)
-        import jax
-
-        return jax.default_backend() == "tpu"
-
-    def _staged_timing(self) -> bool:
-        """Fence between budget stages?  Exact per-stage times on TPU;
-        on CPU the fences themselves cost ~0.3 ms per dispatch, so the
-        auto default folds the (synchronous) H2D remainder into the
-        kernel stage instead of paying fences to split hairs."""
-        cfg = self.engine.config
-        if cfg.latency_staged_timing is not None:
-            return bool(cfg.latency_staged_timing)
-        import jax
-
-        return jax.default_backend() == "tpu"
-
     def _pinned_for(self, slots, tier, qctx_key, args):
         """The pinned executable for this (slots, tier, qctx shape) —
         local-first, then the engine-wide cache, then a real compile.
@@ -246,7 +226,7 @@ class LatencyPath:
                 fn = self.engine._latency_pins.get(full_key)
             fresh = fn is None
             if fresh:
-                if self._donate():
+                if self.engine.config.donate_on():
                     from .flat import make_flat_fn
 
                     jfn = jax.jit(
@@ -349,7 +329,11 @@ class LatencyPath:
         # fill through upload so concurrent checkers can't corrupt it
         # (concurrent serving shards by path/thread; the lock only
         # covers the host-side window, not kernel execution)
-        staged = self._staged_timing()
+        # fence between budget stages?  Exact per-stage times on TPU; on
+        # CPU the fences themselves cost ~0.3 ms per dispatch, so the
+        # auto default folds the (synchronous) H2D remainder into the
+        # kernel stage instead
+        staged = self.engine.config.staged_timing_on()
         with self._lock:
             qm = self._qm_buf(tier)
             fill_qm(queries, qm, meta)

@@ -43,30 +43,24 @@ Kernel modes (one builder, static tails):
               DMA'd block, so the K-hop lookup programs inherit the
               fused probe too.
 
-Portability/fallback contract (ISSUE 20): ``EngineConfig.pallas`` is
-tri-state — None (auto: on for TPU, off elsewhere), True (force; used
-by tests, which run the kernels in INTERPRET mode under
-``JAX_PLATFORMS=cpu``), False (the XLA path, byte-for-byte the parity
-oracle).  ``jax.experimental.pallas`` is feature-probed ONCE (the
-shard_map feature-detect discipline from parallel/sharded.py): a
-jaxlib without it degrades auto/forced to the XLA path with a single
-``pallas.degraded`` warning counter — never an ImportError at client
-construction.
-
-Interpret-mode honesty: under ``JAX_PLATFORMS=cpu`` every kernel here
-runs through the Pallas interpreter — that checks CORRECTNESS
-(bitwise parity against the XLA path on randomized worlds), not speed.
-The one-pass byte accounting is a model (utils/perf.py
-``pallas_bytes_model``), asserted structurally in tests; the measured
-win is a silicon expectation, armed as tpu_watch.sh priority 4.0.
-First-silicon bring-up may need the scalar-prefetch grid variant
-(``PrefetchScalarGridSpec``) for the per-query offset scalars — the
-A/B harness exists to find out.
+Selection (``resolve``): ``EngineConfig.pallas`` None resolves OFF on
+every platform — the XLA chain in engine/flat.py is the main path.
+Mosaic refuses every kernel mode here on a v5e (the refusals are quoted
+in CHANGES.md, PR 21): the kernels read and write ``ANY``-space refs
+with plain indexing, their DMA windows are far below the (8,128) tile,
+and their outputs are written one scalar at a time.  Rewriting them for
+Mosaic (scalar-prefetch grid, tile-shaped windows) is a performance
+change of its own.  ``pallas=True`` is a demand, never a wish: on a TPU
+the kernels compile with Mosaic or the dispatch raises; on the CPU
+backend — and only there — they run through the Pallas interpreter,
+which checks CORRECTNESS (bitwise parity against the XLA path on
+randomized worlds), not speed.  The one-pass byte accounting is a model
+(utils/perf.py ``pallas_bytes_model``), asserted structurally in tests;
+its device effect is not measured.
 """
 
 from __future__ import annotations
 
-import warnings
 from typing import Any, Dict, Optional, Sequence, Tuple
 
 import numpy as np
@@ -75,66 +69,22 @@ from ..utils import metrics as _metrics
 
 _mt = _metrics.default
 
-# ---------------------------------------------------------------------------
-# feature detect (probed once; the shard_map check_vma discipline)
-# ---------------------------------------------------------------------------
-
-_FEATURE: Dict[str, Any] = {"probed": False, "ok": False, "err": ""}
-_WARNED: Dict[str, bool] = {"degraded": False}
-
-
-def available() -> bool:
-    """Whether this jaxlib ships a usable ``jax.experimental.pallas``.
-    Probed exactly once per process; a missing/old install records the
-    error and counts ``pallas.unavailable`` instead of raising."""
-    if not _FEATURE["probed"]:
-        _FEATURE["probed"] = True
-        try:
-            from jax.experimental import pallas as _pl  # noqa: F401
-            from jax.experimental.pallas import tpu as _pltpu  # noqa: F401
-
-            _FEATURE["ok"] = True
-        except Exception as e:  # pragma: no cover - depends on install
-            _FEATURE["ok"] = False
-            _FEATURE["err"] = f"{type(e).__name__}: {e}"
-            _mt.inc("pallas.unavailable")
-    return bool(_FEATURE["ok"])
-
 
 def resolve(config) -> bool:
-    """The resolved ``EngineConfig.pallas`` flag: None = auto (on for
-    TPU when available, off elsewhere — the XLA path stays the
-    portability default); True degrades to False when the feature probe
-    fails, with ONE warning + ``pallas.degraded`` counter."""
-    knob = getattr(config, "pallas", None)
-    if knob is False:
-        return False
-    ok = available()
-    if knob is True:
-        if not ok and not _WARNED["degraded"]:
-            _WARNED["degraded"] = True
-            _mt.inc("pallas.degraded")
-            warnings.warn(
-                "EngineConfig.pallas=True but jax.experimental.pallas is"
-                f" unavailable ({_FEATURE['err']}); serving on the XLA"
-                " path",
-                RuntimeWarning,
-                stacklevel=2,
-            )
-        return ok
-    if not ok:
-        return False
-    import jax
-
-    return jax.default_backend() == "tpu"
+    """The resolved ``EngineConfig.pallas`` flag: on only when the
+    caller demands it (True).  None — the default — is off on every
+    platform, because Mosaic does not lower these kernels (module
+    docstring); a forced True on a TPU compiles for real or raises."""
+    return getattr(config, "pallas", None) is True
 
 
 def interpret_mode() -> bool:
-    """Interpret off-TPU: the kernels then run through the Pallas
-    interpreter (correctness-only; tests pin ``JAX_PLATFORMS=cpu``)."""
+    """The Pallas interpreter runs the kernels on the CPU backend and
+    nowhere else: any other backend lowers them with its own compiler
+    or fails."""
     import jax
 
-    return jax.default_backend() != "tpu"
+    return jax.default_backend() == "cpu"
 
 
 # ---------------------------------------------------------------------------
@@ -142,8 +92,9 @@ def interpret_mode() -> bool:
 # ---------------------------------------------------------------------------
 
 #: per-array ceiling for pinning an offsets/anchor/ladder array
-#: VMEM-resident (v5e VMEM is 128 MB/core; the budget stays far under
-#: it so the compiler keeps headroom for the double-buffered scratch)
+#: VMEM-resident.  VMEM is ~16 MB/core and Mosaic's default scoped limit
+#: is 16 MiB for the whole kernel, so several arrays at this ceiling do
+#: not fit together — a kernel rewrite must budget the sum, not each
 VMEM_TABLE_MAX_BYTES = 4 << 20
 
 
@@ -151,9 +102,19 @@ def _nbytes(a) -> int:
     return int(np.prod(a.shape)) * int(np.dtype(a.dtype).itemsize)
 
 
-def vmem_ok(a) -> bool:
-    """Whether one array is small enough to pin VMEM-resident."""
-    return _nbytes(a) <= VMEM_TABLE_MAX_BYTES
+def require_vmem(name: str, *arrays) -> None:
+    """The fused kernels pin a site's offset arrays VMEM-resident; one
+    over the ceiling cannot be served.  ``pallas=True`` is a demand, so
+    that is an error at trace time — never a quiet return to the XLA
+    chain."""
+    for a in arrays:
+        if a is not None and _nbytes(a) > VMEM_TABLE_MAX_BYTES:
+            raise ValueError(
+                f"pallas=True: `{name}` is {_nbytes(a)} bytes, over the"
+                f" {VMEM_TABLE_MAX_BYTES}-byte VMEM-resident ceiling of"
+                " the fused probe kernel; this table needs the XLA chain"
+                " (pallas=None/False)"
+            )
 
 
 def vmem_plan(arrays) -> Dict[str, int]:
@@ -402,7 +363,7 @@ def fused_probe(
     refs_runs: Dict[str, Any] = {}
 
     # ---- specs: queries + offsets VMEM-resident, table stays in HBM ----
-    vm = pltpu.TPUMemorySpace.ANY
+    vm = pl.ANY
     in_specs = [pl.BlockSpec(memory_space=vm) for _ in range(n_in + 1)]
     out_specs, out_shapes = _out_layout(mode, B, cap, W, gate, jnp, pl, vm)
     args = list(qf)
@@ -557,7 +518,7 @@ def fused_probe_aligned(
 
         jax.lax.fori_loop(0, B, body, 0)
 
-    vm = pltpu.TPUMemorySpace.ANY
+    vm = pl.ANY
     in_specs = [pl.BlockSpec(memory_space=vm) for _ in range(n_in + L)]
     out_specs, out_shapes = _out_layout(
         mode, B, capT, W, gate, jnp, pl, vm

@@ -30,6 +30,17 @@ from ..schema.ast import (
 )
 from ..schema.compiler import CompiledSchema, _expr_refs
 
+
+def _on_tpu() -> bool:
+    import jax
+
+    return jax.default_backend() == "tpu"
+
+
+def _auto(knob: Optional[bool]) -> bool:
+    """A None = auto tri-state: on when the default backend is a TPU."""
+    return _on_tpu() if knob is None else bool(knob)
+
 # Expression IR: nested tuples, all leaves static ints.
 #   ("ref", slot) ("arrow", ts_idx, right_slot) ("union", (c...))
 #   ("inter", (c...)) ("excl", base, sub) ("nil",)
@@ -162,10 +173,10 @@ class EngineConfig:
     #: the live closure directly — answers those slots until compaction
     flat_tindex_dirty_cap: int = 65_536
     #: bucket-ALIGNED probe tables (engine/hash.py build_aligned): each
-    #: bucket is ONE table row fetched with a single row gather — on TPU
-    #: ~48M probes/s vs 0.75M for the off+block layout (measured,
-    #: tpu_attempts/micro_blocks.py).  None = auto (on when the default
-    #: backend is tpu); tests force True to exercise the layout on CPU
+    #: bucket is ONE table row fetched with a single row gather (rate on
+    #: a TPU against the off+block layout: not measured).  None = auto
+    #: (on when the default backend is tpu); tests force True to
+    #: exercise the layout on CPU
     flat_aligned: Optional[bool] = None
     #: per-table byte budget for the aligned layout; tables whose aligned
     #: form exceeds it keep the off+interleave layout
@@ -276,14 +287,47 @@ class EngineConfig:
     #: the frontier run probes) through the hand-fused Pallas kernel:
     #: hash → offset → double-buffered bucket DMA → packed decode → gate
     #: → reduce in ONE HBM pass per table, offsets/ladders VMEM-resident.
-    #: None = auto: on for TPU when jax.experimental.pallas is available,
-    #: off elsewhere.  False is the parity oracle — the XLA gather chain,
-    #: byte-for-byte (the spmm=False / flat_packed=False-style lever).
-    #: True forces the kernels even off-TPU (tests: Pallas INTERPRET
-    #: mode under JAX_PLATFORMS=cpu — correctness, not speed); a jaxlib
-    #: without Pallas degrades True/auto to the XLA path with a single
-    #: counted warning, never an ImportError
+    #: None and False are both OFF on every platform — the XLA gather
+    #: chain is the main path, because Mosaic refuses these kernels on a
+    #: v5e (CHANGES.md, PR 21).  True demands the kernels: on a TPU they
+    #: compile with Mosaic or the dispatch raises; on the CPU backend
+    #: (tests) they run in Pallas INTERPRET mode — correctness, not
+    #: speed.  Nothing degrades one to the other
     pallas: Optional[bool] = None
+
+    # -- the backend-keyed choices, resolved in ONE place -----------------
+    def aligned_on(self) -> bool:
+        """Resolved flat_aligned (None = auto: on for TPU)."""
+        return _auto(self.flat_aligned)
+
+    def donate_on(self) -> bool:
+        """Resolved latency_donate (None = auto: on for TPU)."""
+        return _auto(self.latency_donate)
+
+    def staged_timing_on(self) -> bool:
+        """Resolved latency_staged_timing (None = auto: on for TPU)."""
+        return _auto(self.latency_staged_timing)
+
+    def pipeline_batch(self) -> int:
+        """Resolved flat_pipeline_batch (None = auto: 32768 on TPU, 0 —
+        no sub-batch pipeline — elsewhere)."""
+        if self.flat_pipeline_batch is not None:
+            return int(self.flat_pipeline_batch)
+        return 32_768 if _on_tpu() else 0
+
+    def resolved(self) -> Dict[str, object]:
+        """What every None = auto knob resolves to in this process —
+        the record an entry script prints next to its results."""
+        from . import pallas as _pallas
+
+        return {
+            "pallas": _pallas.resolve(self),
+            "flat_aligned": self.aligned_on(),
+            "flat_packed": self.packed_on(),
+            "latency_donate": self.donate_on(),
+            "latency_staged_timing": self.staged_timing_on(),
+            "flat_pipeline_batch": self.pipeline_batch(),
+        }
 
     @staticmethod
     def for_schema(compiled: CompiledSchema, **overrides) -> "EngineConfig":

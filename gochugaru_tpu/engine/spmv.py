@@ -228,7 +228,8 @@ class FrontierKernels:
         # resolves on and the layout is single-shard — the sharded
         # engine shard_maps the raw bodies, where the XLA chain must
         # stay verbatim.  Per-call the offsets must also fit the
-        # VMEM-resident plan; otherwise the body keeps the XLA bisect.
+        # VMEM-resident plan (an error otherwise, never a quiet
+        # return to the XLA bisect).
         from . import pallas as _pallas
 
         self._pls = (not meta.sharded) and _pallas.resolve(config)
@@ -335,14 +336,14 @@ class FrontierKernels:
             if use_pls:
                 from . import pallas as _pallas
 
-                if _pallas.vmem_ok(off) and (
-                    shift is None or _pallas.vmem_ok(off_a)
-                ):
-                    return _pallas.fused_probe(
-                        (keys,), off, tbl, cap=cap, spec=spec,
-                        off_a=off_a if shift is not None else None,
-                        ashift=shift, mode="runs",
-                    )
+                _pallas.require_vmem(
+                    off_key, off, off_a if shift is not None else None
+                )
+                return _pallas.fused_probe(
+                    (keys,), off, tbl, cap=cap, spec=spec,
+                    off_a=off_a if shift is not None else None,
+                    ashift=shift, mode="runs",
+                )
             size = (off.shape[0] - 1)  # single-shard layout (M=1)
             h = (mix32([keys], jnp) & jnp.uint32(size - 1)).astype(jnp.int32)
             start = offr(off, off_a, h)

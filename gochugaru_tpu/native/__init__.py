@@ -45,16 +45,20 @@ def _src_hash() -> Optional[str]:
 
 
 def _build(src_hash: str) -> bool:
+    # build beside the target and rename: another process never loads a
+    # half-written library
+    tmp = f"{_SO}.{os.getpid()}.tmp"
     cmds = [
         ["g++", "-O3", "-shared", "-fPIC", "-fopenmp", "-std=c++17",
-         _SRC, "-o", _SO],
+         _SRC, "-o", tmp],
         # no-OpenMP fallback (serial sort)
-        ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", _SRC, "-o", _SO],
+        ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", _SRC, "-o", tmp],
     ]
     for cmd in cmds:
         try:
             r = subprocess.run(cmd, capture_output=True, timeout=120)
             if r.returncode == 0:
+                os.replace(tmp, _SO)
                 with open(_STAMP, "w") as f:
                     f.write(src_hash)
                 return True
@@ -188,6 +192,22 @@ def enabled() -> bool:
 
 def available() -> bool:
     return lib() is not None
+
+
+def rebuild() -> bool:
+    """Discard any cached binary and build ``ingest.cpp`` now; True when
+    the fresh library loaded.  For entry points that must not trust a
+    binary found in the tree (chip_smoke.py).  Call before anything has
+    used the library: handles already given out keep the old mapping."""
+    global _lib, _tried
+    with _lock:
+        for path in (_SO, _STAMP):
+            try:
+                os.remove(path)
+            except FileNotFoundError:
+                pass
+        _lib, _tried = None, False
+    return _load() is not None
 
 
 def lib() -> Optional[ctypes.CDLL]:

@@ -246,14 +246,8 @@ def dryrun_multihost(
             GOCHUGARU_NUM_PROCESSES=str(n_processes),
             GOCHUGARU_PROCESS_ID=str(pid),
             GOCHUGARU_DRYRUN_LOCAL_DEVICES=str(local),
+            # a CPU dryrun by design: N processes cannot share one chip
             JAX_PLATFORMS="cpu",
-            # children inherit the parent's probe verdict (or the pin
-            # above): a spawned dryrun must never re-pay the bounded
-            # 75 s degraded TPU probe per process (benchmarks/run_all.py
-            # exports GOCHUGARU_BACKEND_PROBED after ITS probe)
-            GOCHUGARU_BACKEND_PROBED=os.environ.get(
-                "GOCHUGARU_BACKEND_PROBED", "cpu"
-            ),
         )
         procs.append(subprocess.Popen(
             [sys.executable, "-m", "gochugaru_tpu.parallel.multihost"],
@@ -285,7 +279,7 @@ def dryrun_multihost(
         for line in (out or "").splitlines():
             if line.startswith("DRYRUN-OK"):
                 print(line)
-                frac = line.rsplit("verified=", 1)[1]
+                frac = line.rsplit("verified=", 1)[1].split()[0]
                 k, n = frac.split("/")
                 total += int(k)
                 want = int(n)
@@ -533,9 +527,6 @@ def _spawn_rss(mode: str, extra_env: dict, timeout_s: int):
         os.environ,
         GOCHUGARU_DRYRUN_MODE=mode,
         JAX_PLATFORMS="cpu",
-        GOCHUGARU_BACKEND_PROBED=os.environ.get(
-            "GOCHUGARU_BACKEND_PROBED", "cpu"
-        ),
         **extra_env,
     )
     return subprocess.Popen(

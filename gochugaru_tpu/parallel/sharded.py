@@ -26,20 +26,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # jax ≥ 0.8
-    from jax import shard_map
-except ImportError:  # pragma: no cover — older jax
-    from jax.experimental.shard_map import shard_map
-
-import inspect
-
-#: the replication-check kwarg was renamed check_rep → check_vma across
-#: jax versions; feature-detect so both signatures disable it
-_SHARD_MAP_NO_CHECK = (
-    {"check_vma": False}
-    if "check_vma" in inspect.signature(shard_map).parameters
-    else {"check_rep": False}
-)
+from jax import shard_map
 
 from ..engine.device import (
     DeviceEngine,
@@ -100,7 +87,7 @@ class ShardedEngine(DeviceEngine):
         self._fn = jax.jit(
             shard_map(
                 raw, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                **_SHARD_MAP_NO_CHECK,
+                check_vma=False,
             )
         )
         #: shard_mapped flat kernels per (slots, FlatMeta, array keys)
@@ -176,7 +163,7 @@ class ShardedEngine(DeviceEngine):
             shard_map(
                 raw, mesh=self.mesh, in_specs=in_specs,
                 out_specs=(P(batch_axis),) * 3,
-                **_SHARD_MAP_NO_CHECK,
+                check_vma=False,
             )
         )
         while len(self._flat_sharded_fns) >= self.FLAT_FN_CACHE_MAX:
@@ -814,7 +801,7 @@ class _ShardedLookupHops:
         runs = jax.jit(shard_map(
             self.kern.raw_runs[kind], mesh=self.mesh,
             in_specs=(MP, P(), MP, MP), out_specs=(MP, MP),
-            **_SHARD_MAP_NO_CHECK,
+            check_vma=False,
         ))
         body = self.kern.raw_emits[kind]
         CH = self.kern.CH  # fixed chunk per shard (static under jit)
@@ -822,7 +809,7 @@ class _ShardedLookupHops:
             lambda t, l, n, c0, nw: body(t, l, n, c0, nw, CH),
             mesh=self.mesh,
             in_specs=(MP, MP, MP, MP, P()), out_specs=(MP, MP),
-            **_SHARD_MAP_NO_CHECK,
+            check_vma=False,
         ))
         got = (runs, emit)
         while len(self._fns) >= 16:

@@ -19,9 +19,8 @@ rules that read them):
 - ``pad``      — pinned-tier pad-waste ledger (utils/perf.py)
 - ``cost``     — per-tier expected dispatch cost (utils/admission.py)
 - ``bytes``    — gathered-bytes model + device-table placement split
-- ``pallas``   — fused-probe backend evidence: feature probe, the
-  one-pass byte-model gauges prepare publishes (utils/perf.py
-  ``publish_pallas_model``), and the degrade counter
+- ``pallas``   — fused-probe backend evidence: the one-pass byte-model
+  gauges prepare publishes (utils/perf.py ``publish_pallas_model``)
 - ``wall``     — last closed wall-ledger window's bucket fractions
 - ``chain``    — write-path delta-chain depth (store/group.py gauges:
   overlay rows, chain length in revisions, background compactions,
@@ -129,12 +128,10 @@ def collect_snapshot(
 
     snap["pad"] = _perf.pad_stats(m)
     snap["pallas"] = {
-        "available": bool(_pallas.available()),
         "bytes_per_check": float(m.gauge("perf.pallas.bytes_per_check")),
         "bytes_saved_per_check": float(
             m.gauge("perf.pallas.bytes_saved_per_check")
         ),
-        "degraded": int(m.counter("pallas.degraded")),
     }
     if cost is not None:
         snap["cost"] = cost.state()

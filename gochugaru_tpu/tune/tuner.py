@@ -495,22 +495,11 @@ def _rule_pallas(snap):
     """Propose the fused Pallas probe backend from the one-pass byte
     model prepare publishes (utils/perf.publish_pallas_model gauges):
     fused HBM bytes/check against the XLA chain's gather + decode
-    traffic.  Two vetoes run first — the feature probe and the runtime
-    ``pallas.degraded`` counter — because a knob the engine cannot
-    serve (or has already fallen back from at dispatch) must be
-    proposed off regardless of how good the model looks."""
+    traffic.  The gauges exist only after a prepare with the knob on,
+    so a default (off) deployment never hears from this rule."""
     pl = snap.get("pallas")
     if not pl:
         return None
-    degraded = int(pl.get("degraded") or 0)
-    if not pl.get("available") or degraded:
-        why = (
-            "jax.experimental.pallas unavailable on this jaxlib"
-            if not pl.get("available")
-            else f"{degraded} runtime degrade(s) to the XLA path"
-        )
-        return (False, f"fused probe vetoed: {why} — pallas=False",
-                {"bytes_per_check_frac": 0.0})
     fused = float(pl.get("bytes_per_check") or 0.0)
     saved = float(pl.get("bytes_saved_per_check") or 0.0)
     if fused <= 0:

@@ -260,7 +260,7 @@ def install(log: Optional[DecisionLog]) -> Optional[DecisionLog]:
 
 def set_recording(log: Optional[DecisionLog]) -> Optional[DecisionLog]:
     """Swap the installed log WITHOUT closing the previous one — the
-    per-rep A/B toggle (explain_smoke, tpu_watch): ``install(None)``
+    per-rep A/B toggle (explain_smoke): ``install(None)``
     would close the JSONL sink, so every armed rep would pay a file
     reopen inside the timed window that a steady-state log never pays.
     Returns the previously installed log."""

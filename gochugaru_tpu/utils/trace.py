@@ -45,8 +45,8 @@ the store write path — attach events via ``event_if_active`` without
 plumbing a parameter through every signature.
 
 Profiler correlation: when a profiler session is active (the
-``GOCHUGARU_TRACE_DIR`` env var names its dump dir — tpu_watch.sh's
-harvest step and ``bench_tpu_harvest --trace`` set it),
+``GOCHUGARU_TRACE_DIR`` env var names its dump dir; ``profiler_session``
+sets it),
 ``annotate_dispatch(span)`` wraps dispatch in a
 ``jax.profiler.TraceAnnotation`` named by the trace id, so the XLA
 device trace carries request attribution for free.
@@ -558,8 +558,7 @@ class FlightRecorder:
 
         #: bundles dump here (created lazily); None ⇒ in-memory only.
         #: GOCHUGARU_INCIDENT_DIR is the zero-plumbing default so bench
-        #: children inside a tpu_watch.sh harvest window dump without
-        #: any wiring of their own
+        #: children dump without any wiring of their own
         self.incident_dir = (
             incident_dir
             if incident_dir is not None
@@ -1086,9 +1085,9 @@ def annotate_dispatch(span) -> Any:
 
 class profiler_session:
     """Marks a profiler session active for this process (sets
-    GOCHUGARU_TRACE_DIR and the cached flag) for the duration —
-    ``bench_tpu_harvest --trace`` wraps its ``jax.profiler.trace``
-    window in this so every dispatch inside is request-annotated."""
+    GOCHUGARU_TRACE_DIR and the cached flag) for the duration — wrap a
+    ``jax.profiler.trace`` window in this so every dispatch inside is
+    request-annotated."""
 
     def __init__(self, trace_dir: str) -> None:
         self.trace_dir = trace_dir

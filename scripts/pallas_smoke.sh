@@ -9,7 +9,7 @@
 # pallas_bytes_model must show a per-table bytes-accessed reduction and
 # prepare must publish vmem_resident_bytes > 0.  Interpret-mode honesty:
 # rates printed here are correctness-only — the bytes win is a model,
-# scored on silicon by tpu_watch.sh priority 4.0.  Prints
+# not measured on a device.  Prints
 # PALLAS-SMOKE-OK on success and one JSON metric line for
 # benchmarks/run_all.py (config 25).
 set -euo pipefail

@@ -2,10 +2,9 @@
 (engine/hash.py build_aligned / probe_aligned, wired through
 engine/flat.py put_block + the name-keyed pblock dispatch).
 
-The aligned layout is the TPU-shaped probe (one row gather per site,
-~48M probes/s measured vs 0.75M for the off+block slice —
-tpu_attempts/micro_blocks.py); it defaults on only when the backend is
-TPU, so these tests force ``flat_aligned=True`` to exercise it on the
+The aligned layout is the TPU-shaped probe (one row gather per site;
+its rate on a TPU is not measured); it defaults on only when the
+backend is TPU, so these tests force ``flat_aligned=True`` to exercise it on the
 CPU suite, asserting bit-identical results against the oracle and
 against the legacy layout.
 """

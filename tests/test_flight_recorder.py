@@ -348,11 +348,17 @@ def test_fault_storm_produces_bundle_with_failing_dispatch_traces(tmp_path):
         )),
         with_telemetry(port=0, incident_dir=str(tmp_path)),
     )
+    grace = c.recorder.grace_s
     try:
         # zero-config wiring: tracer (0% head sample) + recorder + SLO
         assert trace.enabled() and trace.recorder() is c.recorder
         assert c.slo is not None and c.telemetry is not None
         assert c.check(ctx, consistency.full(), *rs) == [True] * 8  # warm
+        # the tripped breaker reroutes the failing request onto the batch
+        # path, whose program compiles on first use: with a cold compile
+        # cache that outlasts the default 0.25 s capture grace and the
+        # bundle is written before the failing root span has ended
+        c.recorder.grace_s = 5.0
         faults.arm("latency.dispatch", times=2)
         # the retry envelope absorbs both injected faults; the second
         # consecutive failure trips the breaker mid-request
@@ -386,6 +392,7 @@ def test_fault_storm_produces_bundle_with_failing_dispatch_traces(tmp_path):
         assert any(k.startswith("cost_model") for k in ctx_keys)
         assert ctx_keys[adm_key]["breaker_state"] == 2
     finally:
+        c.recorder.grace_s = grace
         if c.slo is not None:
             c.slo.close()
         c.telemetry.close()

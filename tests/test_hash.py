@@ -206,3 +206,75 @@ def test_stack_point_and_range_cover_all_rows():
         assert match.shape[0] == 1, i
         seen.append(int(match[0, 1]))
     assert sorted(seen) == list(range(n))
+
+
+def test_slice_blocks_flat_gather_form_is_bitwise_the_dynamic_slice():
+    """The TPU lowering of slice_blocks (cap·w flat 1-D gathers) never
+    runs by default on the CPU suite: pin it bitwise against the
+    dynamic_slice form — packed uint16 lanes and plain int32 rows,
+    starts at both clamps — before it meets a chip."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from gochugaru_tpu.engine import hash as H
+
+    rng = np.random.default_rng(11)
+    for dtype, w, cap in ((np.uint16, 3, 8), (np.uint16, 6, 4),
+                          (np.int32, 4, 5), (np.int32, 1, 1)):
+        rows = 1 << 10
+        tbl = jnp.asarray(
+            rng.integers(0, np.iinfo(dtype).max, (rows, w)).astype(dtype)
+        )
+        s = jnp.asarray(np.concatenate([
+            rng.integers(0, rows - cap + 1, 200),
+            [0, rows - cap],  # both ends of the clamp range
+        ]).astype(np.int32))
+        flat = jax.jit(H._slice_blocks_flat, static_argnums=2)(tbl, s, cap)
+        dyn = jax.jit(H._slice_blocks_dynamic, static_argnums=2)(tbl, s, cap)
+        assert flat.dtype == dyn.dtype and flat.shape == (202, cap, w)
+        assert np.array_equal(np.asarray(flat), np.asarray(dyn))
+
+
+def test_slice_blocks_picks_the_flat_form_on_tpu(monkeypatch):
+    """The branch keys off the default backend at trace time; a lattice
+    of starts keeps its shape and out-of-range starts clamp either way."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from gochugaru_tpu.engine import hash as H
+
+    tbl = jnp.arange(64 * 3, dtype=jnp.int32).reshape(64, 3)
+    starts = jnp.asarray([[0, 5, 70], [-3, 60, 63]], jnp.int32)
+    want = np.asarray(H.slice_blocks(tbl, starts, 4))
+    called = []
+    orig = H._slice_blocks_flat
+    monkeypatch.setattr(
+        H, "_slice_blocks_flat",
+        lambda *a: called.append(1) or orig(*a),
+    )
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    got = np.asarray(H.slice_blocks(tbl, starts, 4))
+    assert called and got.shape == (2, 3, 4, 3)
+    assert np.array_equal(got, want)
+
+
+def test_slice_blocks_flat_form_refuses_a_table_int32_cannot_address():
+    """rows·w > 2³¹−1: an int64 offset would be narrowed to int32
+    without jax_enable_x64 and wrap under promise_in_bounds — refused at
+    trace time instead (no table is allocated: shapes only)."""
+    import jax
+    import jax.numpy as jnp
+    import pytest
+
+    from gochugaru_tpu.engine import hash as H
+
+    tbl = jax.ShapeDtypeStruct((1 << 29, 6), jnp.uint16)
+    s = jax.ShapeDtypeStruct((8,), jnp.int32)
+    with pytest.raises(ValueError, match="int32 flat gather offsets"):
+        jax.eval_shape(lambda t, s: H._slice_blocks_flat(t, s, 4), tbl, s)
+    ok = jax.ShapeDtypeStruct((1 << 28, 6), jnp.uint16)
+    assert jax.eval_shape(
+        lambda t, s: H._slice_blocks_flat(t, s, 4), ok, s
+    ).shape == (8, 4, 6)
