@@ -25,9 +25,11 @@ What it does:
 4. proves from ``utils/metrics.default`` that the device did the work:
    no oracle-served check, no host-resolved item, no retry, no breaker
    reroute, no compile on a warm shape;
-5. prints, as the last line of stdout, one JSON object with the device
-   as JAX reports it.  Times in it are set-up facts of ONE run, labelled
-   so; they are not benchmark numbers.
+5. prints one ``"event": "report"`` JSON line with everything the run
+   learned (times in it are set-up facts of ONE run, labelled so; they
+   are not benchmark numbers), and then, as the last line of stdout,
+   exactly ``{"ok": true, "device": {"platform", "kind", "count"}}`` with
+   the device as JAX reports it.
 
 ``--rehearse-cpu`` is the only way onto another backend: a tiny world on
 four virtual CPU devices whose every output line says ``platform: "cpu"``
@@ -682,10 +684,7 @@ def main(argv=None) -> int:
             say("mesh", label=label, per_device_bytes=sec["per_device_bytes"])
             sections.append(sec)
 
-    result = {
-        "ok": True,
-        "device": device,
-        "platform": PLATFORM,
+    report = {
         "jax": jax.__version__,
         "gochugaru_tpu": gochugaru_tpu.__version__,
         "seed": args.seed,
@@ -704,7 +703,10 @@ def main(argv=None) -> int:
                           "entries_after": cache_entries(cache_dir)},
         "sections": sections,
     }
-    print(json.dumps(result), flush=True)
+    say("report", device=device, **report)
+    # the last line is the driver's contract: these keys and no others.
+    # Everything the run learned is on the "report" line above it
+    print(json.dumps({"ok": True, "device": device}), flush=True)
     return 0
 
 

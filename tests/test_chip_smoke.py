@@ -35,10 +35,13 @@ def test_cpu_rehearsal_passes_and_says_cpu_on_every_line():
     r = _run_smoke("--rehearse-cpu", "--partitioned")
     assert r.returncode == 0, r.stderr[-2000:]
     lines = [json.loads(ln) for ln in r.stdout.splitlines()]
-    assert lines and all(ln["platform"] == "cpu" for ln in lines)
-    last = lines[-1]
-    assert last["ok"] is True
-    assert last["device"] == {"platform": "cpu", "kind": "cpu", "count": 4}
+    # the last line is the driver's contract, these keys and no others;
+    # the line before it is the report
+    device = {"platform": "cpu", "kind": "cpu", "count": 4}
+    assert lines[-1] == {"ok": True, "device": device}
+    assert all(ln["platform"] == "cpu" for ln in lines[:-1])
+    last = lines[-2]
+    assert last["event"] == "report" and last["device"] == device
     assert last["native_available"] is True
     assert last["resolved"]["pallas"] is False
     labels = [s["label"] for s in last["sections"]]
