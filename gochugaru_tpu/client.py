@@ -23,7 +23,6 @@ Check resolution is a three-tier cascade:
 
 from __future__ import annotations
 
-import contextlib
 import dataclasses as _dataclasses
 import threading
 from typing import (
@@ -137,7 +136,6 @@ class _Options:
         self.engine_config: Optional[EngineConfig] = None
         self.store: Optional[Store] = None
         self.use_device = True
-        self.profile_dir: Optional[str] = None
         self.latency_mode = False
         self.admission: Optional[AdmissionConfig] = None
         self.mesh = None  # jax.sharding.Mesh → sharded engine
@@ -367,18 +365,6 @@ def with_telemetry(
     return opt
 
 
-def with_profiling(trace_dir: str) -> Option:
-    """Capture a ``jax.profiler`` trace around every check dispatch into
-    ``trace_dir`` and publish a ``checks.device_time_s`` timer — the deep
-    analogue of the interceptors the reference admits through WithDialOpts
-    (client/client.go:95-97; SURVEY.md §5 tracing/profiling)."""
-
-    def opt(o: _Options) -> None:
-        o.profile_dir = trace_dir
-
-    return opt
-
-
 class Client:
     """An in-process authorization client with the gochugaru surface."""
 
@@ -421,13 +407,11 @@ class Client:
                 self._store, gcfg, registry=_metrics.default
             )
         self._use_device = o.use_device
-        self._profile_dir = o.profile_dir
         self._latency_mode = o.latency_mode
         self._mesh = o.mesh
         self._mesh_partitioned = o.mesh_partitioned
-        # jax.profiler allows one active trace per process: profiled
-        # dispatches serialize so concurrent check() calls don't collide
-        self._profile_lock = threading.Lock()
+        # full collections show up as the host.gc_s timer
+        _trace.install_gc_timer()
         self._lock = threading.Lock()
         self._engine: Optional[DeviceEngine] = None
         self._engine_schema = None  # CompiledSchema the engine was built for
@@ -1032,8 +1016,13 @@ class Client:
         caller-formed and coalesced batches."""
         adm = self._admission
         dsp = span
-        engine = self._engine_for(snap)
         with self._metrics.timer("checks.dispatch"):
+            with _trace.stage("client.snapshot", dsp):
+                engine = self._engine_for(snap)
+                dsnap = (
+                    self._dsnap_for(engine, snap) if engine is not None
+                    else None
+                )
             if engine is None:
                 self._metrics.inc("checks.oracle", len(rels))
                 with dsp.child("oracle.check", items=len(rels)):
@@ -1041,17 +1030,6 @@ class Client:
                     return [
                         oracle.check_relationship(r) == T for r in rels
                     ]
-            dsnap = self._dsnap_for(engine, snap)
-            dsp.event("snapshot.prepared")
-            if self._profile_dir is not None:
-                import jax
-
-                self._profile_lock.acquire()
-                prof = jax.profiler.trace(self._profile_dir)
-                unlock = self._profile_lock.release
-            else:
-                prof = contextlib.nullcontext()
-                unlock = lambda: None
             # circuit breaker: after consecutive transient dispatch
             # failures, latency-mode traffic reroutes onto the batch
             # path until the breaker half-opens a probe
@@ -1069,7 +1047,7 @@ class Client:
             lp = dsnap.latency_path if use_latency else None
             lp_n = lp.dispatch_count if lp is not None else 0
             try:
-                with prof, self._metrics.timer("checks.device_time_s"):
+                with self._metrics.timer("checks.device_time_s"):
                     d, p, ovf = engine.check_batch(
                         dsnap, rels, latency=use_latency, span=dsp
                     )
@@ -1089,17 +1067,14 @@ class Client:
                     and lp2.dispatch_count > lp_n
                 )
                 adm.breaker.record_success(probe=served_latency)
-            finally:
-                unlock()
-            needs_host = (p & ~d) | ovf
-            if not needs_host.any():
-                self._metrics.inc("checks.device_definite", len(rels))
-                return [bool(x) for x in d]
-            osp = dsp.child(
-                "oracle.fallback", items=int(needs_host.sum()),
-                overflow=int(ovf.sum()),
-            )
-            try:
+            with _trace.stage("client.verdicts", dsp) as st:
+                needs_host = (p & ~d) | ovf
+                if not needs_host.any():
+                    self._metrics.inc("checks.device_definite", len(rels))
+                    return [bool(x) for x in d]
+                st.note(
+                    items=int(needs_host.sum()), overflow=int(ovf.sum())
+                )
                 oracle = self._oracle_for(snap)
                 out = []
                 for i, r in enumerate(rels):
@@ -1121,8 +1096,6 @@ class Client:
                     else:
                         out.append(bool(d[i]))
                 return out
-            finally:
-                osp.end()
 
     def _evaluate_columns(
         self,
@@ -1252,7 +1225,11 @@ class Client:
         reconstruction.  Returns a bool verdict array of len(q_res)."""
         adm = self._admission
         B = int(q_res.shape[0])
-        engine = self._engine_for(snap)
+        with _trace.stage("client.snapshot", span):
+            engine = self._engine_for(snap)
+            dsnap = (
+                self._dsnap_for(engine, snap) if engine is not None else None
+            )
         if engine is None:
             self._metrics.inc("checks.oracle", B)
             oracle = self._oracle_for(snap)
@@ -1265,17 +1242,10 @@ class Client:
                 ),
                 bool, count=B,
             )
-        dsnap = self._dsnap_for(engine, snap)
         use_latency = latency and adm.breaker.allow_latency()
         if latency and not use_latency:
             self._metrics.inc("breaker.latency_rerouted")
             span.event("breaker.latency_rerouted")
-        # deliberately NO with_profiling (jax.profiler.trace) wrapper
-        # here, unlike _evaluate_rels: the process allows one active
-        # profiler trace, so per-batch traces would serialize the
-        # serving dispatcher behind _profile_lock — profiler
-        # correlation for serving dispatches goes through the
-        # GOCHUGARU_TRACE_DIR annotation path (trace.annotate_dispatch)
         lp = engine.latency_path(dsnap) if use_latency else None
         lp_n = lp.dispatch_count if lp is not None else 0
         try:
@@ -1284,7 +1254,9 @@ class Client:
                 if lp is not None:
                     out = lp.dispatch_columns(q_res, q_perm, q_subj, span=span)
                 if out is None:
-                    out = engine.check_columns(dsnap, q_res, q_perm, q_subj)
+                    out = engine.check_columns(
+                        dsnap, q_res, q_perm, q_subj, span=span
+                    )
         except Exception as e:
             classified = classify_dispatch_exception(e)
             if isinstance(classified, UnavailableError):
@@ -1298,12 +1270,15 @@ class Client:
                 probe=lp is not None and lp.dispatch_count > lp_n
             )
         d, p, ovf = out
-        res = np.asarray(d, bool).copy()
-        needs_host = (p & ~d) | ovf
-        if needs_host.any():
+        with _trace.stage("client.verdicts", span) as st:
+            res = np.asarray(d, bool).copy()
+            needs_host = (p & ~d) | ovf
+            if not needs_host.any():
+                self._metrics.inc("checks.device_definite", B)
+                return res
             oracle = self._oracle_for(snap)
             idx = np.nonzero(needs_host)[0]
-            span.event("oracle.fallback", items=int(idx.shape[0]))
+            st.note(items=int(idx.shape[0]), overflow=int(ovf.sum()))
             for i in idx:
                 self._metrics.inc(
                     "checks.fallback_overflow" if ovf[i]
@@ -1320,9 +1295,7 @@ class Client:
                     # — the serving batcher slices this back onto the
                     # co-batched submissions instead of failing them all
                     raise BulkCheckItemError(int(i), res[:int(i)], e) from e
-        else:
-            self._metrics.inc("checks.device_definite", B)
-        return res
+            return res
 
     def _check_interned(
         self, oracle: Oracle, snap: Snapshot, res_id, perm_slot, subj_id

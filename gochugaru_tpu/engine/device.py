@@ -1042,7 +1042,16 @@ class DeviceEngine:
     def _lower_queries(
         self, snap: Snapshot, rels: Sequence[Relationship],
         strings: Optional[Dict[str, int]] = None,
+        span=_trace.NOOP,
     ) -> Tuple[Dict[str, np.ndarray], np.ndarray, Dict[str, np.ndarray]]:
+        """Host lowering of one batch, Relationship objects to interned
+        int32 query columns, as one ``engine.lower`` stage (wall, and
+        thread CPU while it records) with the ``intern.*`` counters of
+        the batch."""
+        with _trace.stage("engine.lower", span, cpu=True) as st:
+            return self._lower(snap, rels, strings, st)
+
+    def _lower(self, snap, rels, strings, st):
         B = len(rels)
         interner = snap.interner
         slot_of = self.compiled.slot_of_name
@@ -1080,11 +1089,11 @@ class DeviceEngine:
         memo = self._intern_memo
         memo_get = memo.get
         lookup = interner.lookup
-        memo_hits = 0
+        memo_hits = memo_clears = 0
         memo_max = self.INTERN_MEMO_MAX
 
         def node_of(tname: str, oid: str) -> int:
-            nonlocal memo_hits
+            nonlocal memo_hits, memo_clears
             k = (tname, oid)
             v = memo_get(k)
             if v is not None:
@@ -1094,8 +1103,25 @@ class DeviceEngine:
             if v >= 0:
                 if len(memo) >= memo_max:
                     memo.clear()
+                    memo_clears += 1
                 memo[k] = v
             return v
+
+        timed = st.recording  # a profiler session is live or the span sampled
+        if timed:
+            # node_of then reaches the interner through a timed wrapper:
+            # chosen once per batch, the loop below runs the same
+            # statements either way
+            clock = _time.perf_counter
+            intern_s = 0.0
+            untimed_lookup = lookup
+
+            def lookup(tname: str, oid: str) -> int:
+                nonlocal intern_s
+                t = clock()
+                v = untimed_lookup(tname, oid)
+                intern_s += clock() - t
+                return v
 
         for i, r in enumerate(rels):
             q_res[i] = node_of(r.resource_type, r.resource_id)
@@ -1122,8 +1148,17 @@ class DeviceEngine:
                 and r.subject_relation != ""
             )
 
+        m = metrics.default
         if memo_hits:
-            metrics.default.inc("intern.memo_hits", memo_hits)
+            m.inc("intern.memo_hits", memo_hits)
+        # every node_of call either hit the memo or reached the interner
+        m.inc("intern.lookups", 2 * B - memo_hits)
+        if memo_clears:
+            m.inc("intern.memo_clears", memo_clears)
+        if timed:
+            m.observe("engine.intern_s", intern_s)
+            st.note(batch=B, intern_s=round(intern_s, 6),
+                    memo_hits=memo_hits)
         # unique (subject, query-context) rows for Phase A — context is part
         # of the key because caveat gates make closures context-dependent
         subj_key = np.stack([q_subj, q_srel, q_wc, q_ctx], axis=1)
@@ -1447,8 +1482,9 @@ class DeviceEngine:
         budget metrics); batches it cannot serve fall through to the
         ordinary dispatch below, same contract.  ``span`` is the
         request's trace span (utils/trace.py): sampled dispatches record
-        a ``device.check_batch`` child with lower/kernel/fetch stage
-        boundaries as events; the NOOP span costs one branch."""
+        a ``device.check_batch`` child whose children are the stages
+        ``engine.lower`` / ``.enqueue`` / ``.fetch``; the NOOP span costs
+        one branch."""
         if not rels:
             z = np.zeros(0, bool)
             return z, z, z
@@ -1458,14 +1494,13 @@ class DeviceEngine:
             # envelope as any dispatch: the chaos soak arms this site to
             # prove the fused-kernel path reroutes like the XLA one
             faults.fire("pallas.dispatch")
-        import time as _time
-
         t_lower = _time.perf_counter()
         dsp = span.child("device.check_batch", t=t_lower, batch=len(rels))
         try:
             snap = dsnap.snapshot
-            queries, uniq, qctx = self._lower_queries(snap, rels, dsnap.strings)
-            dsp.event("stage.lower")
+            queries, uniq, qctx = self._lower_queries(
+                snap, rels, dsnap.strings, span=dsp
+            )
             B = len(rels)
             if latency:
                 out = self.latency_path(dsnap).dispatch(
@@ -1481,7 +1516,7 @@ class DeviceEngine:
                 # any (the async queue overlaps lowering with compute); one
                 # shared compiled program per PB bucket
                 subs = []
-                with _trace.annotate_dispatch(span):
+                with _trace.stage("engine.enqueue", dsp):
                     for lo in range(0, B, PB):
                         sub = {k: v[lo:lo + PB] for k, v in queries.items()}
                         o = self._flat_call(
@@ -1493,43 +1528,43 @@ class DeviceEngine:
                             break
                         subs.append((min(PB, B - lo), o))
                 if subs is not None:
-                    dsp.event("stage.dispatch", pipelined=len(subs))
-                    ds, ps, os_ = [], [], []
-                    for n, o in subs:
-                        d, p, ovf = jax.device_get(o)
-                        ds.append(d[:n]); ps.append(p[:n]); os_.append(ovf[:n])
-                    dsp.event("stage.fetch")
-                    return (
-                        np.concatenate(ds), np.concatenate(ps),
-                        np.concatenate(os_),
-                    )
-            with _trace.annotate_dispatch(span):
+                    metrics.default.inc("engine.sub_batches", len(subs))
+                    with _trace.stage("engine.fetch", dsp):
+                        ds, ps, os_ = [], [], []
+                        for n, o in subs:
+                            d, p, ovf = jax.device_get(o)
+                            ds.append(d[:n]); ps.append(p[:n]); os_.append(ovf[:n])
+                        return (
+                            np.concatenate(ds), np.concatenate(ps),
+                            np.concatenate(os_),
+                        )
+            with _trace.stage("engine.enqueue", dsp):
                 out = self._flat_call(dsnap, queries, qctx, now_flat, B)
             if out is not None:
-                dsp.event("stage.dispatch")
-                d, p, ovf = jax.device_get(out)
-                dsp.event("stage.fetch")
+                with _trace.stage("engine.fetch", dsp):
+                    d, p, ovf = jax.device_get(out)
                 return d[:B], p[:B], ovf[:B]
-            BP = _ceil_pow2(B, self.config.batch_bucket_min)
-            U = uniq.shape[0]
-            UP = _ceil_pow2(U, self.config.batch_bucket_min)
+            with _trace.stage("engine.enqueue", dsp) as st:
+                st.note(legacy=True)
+                BP = _ceil_pow2(B, self.config.batch_bucket_min)
+                U = uniq.shape[0]
+                UP = _ceil_pow2(U, self.config.batch_bucket_min)
 
-            def padq(a, fill):
-                out = np.full(BP, fill, a.dtype)
-                out[:B] = a
-                return jnp.asarray(out)
+                def padq(a, fill):
+                    out = np.full(BP, fill, a.dtype)
+                    out[:B] = a
+                    return jnp.asarray(out)
 
-            u_subj = np.full(UP, -1, np.int32)
-            u_srel = np.full(UP, -1, np.int32)
-            u_wc = np.full(UP, -1, np.int32)
-            u_qctx = np.full(UP, -1, np.int32)
-            u_subj[:U] = uniq[:, 0]
-            u_srel[:U] = uniq[:, 1]
-            u_wc[:U] = uniq[:, 2]
-            u_qctx[:U] = uniq[:, 3]
+                u_subj = np.full(UP, -1, np.int32)
+                u_srel = np.full(UP, -1, np.int32)
+                u_wc = np.full(UP, -1, np.int32)
+                u_qctx = np.full(UP, -1, np.int32)
+                u_subj[:U] = uniq[:, 0]
+                u_srel[:U] = uniq[:, 1]
+                u_wc[:U] = uniq[:, 2]
+                u_qctx[:U] = uniq[:, 3]
 
-            now = jnp.int32(snap.now_rel32(now_us))
-            with _trace.annotate_dispatch(span):
+                now = jnp.int32(snap.now_rel32(now_us))
                 d, p, ovf = self._fn(
                     self._legacy_arrays(dsnap), dsnap.tid_map, now,
                     jnp.asarray(u_subj), jnp.asarray(u_srel), jnp.asarray(u_wc),
@@ -1540,12 +1575,11 @@ class DeviceEngine:
                     padq(queries["q_self"], False), padq(queries["q_ctx"], -1),
                     self._qctx_device(qctx),
                 )
-            dsp.event("stage.dispatch", legacy=True)
             # one device→host fetch for all three planes: separate np.asarray
             # calls round-trip the dispatch boundary once each, which dominates
             # small-batch latency on remote-attached TPUs
-            d, p, ovf = jax.device_get((d, p, ovf))
-            dsp.event("stage.fetch")
+            with _trace.stage("engine.fetch", dsp):
+                d, p, ovf = jax.device_get((d, p, ovf))
             return d[:B], p[:B], ovf[:B]
         finally:
             dsp.end()
@@ -1561,10 +1595,20 @@ class DeviceEngine:
         q_wc: Optional[np.ndarray],
         q_ctx: Optional[np.ndarray],
         qctx_rows,
+        span=_trace.NOOP,
     ) -> Tuple[Dict[str, np.ndarray], Dict[str, np.ndarray]]:
         """Shared columnar-check preamble: optional-column defaulting,
         query-context encoding, and the reflexive-self derivation — one
-        definition so the single-chip and sharded paths cannot drift."""
+        definition so the single-chip and sharded paths cannot drift.
+        The columnar path's ``engine.lower`` stage."""
+        with _trace.stage("engine.lower", span, cpu=True):
+            return self._preamble(
+                dsnap, q_res, q_perm, q_subj, q_srel, q_wc, q_ctx, qctx_rows
+            )
+
+    def _preamble(
+        self, dsnap, q_res, q_perm, q_subj, q_srel, q_wc, q_ctx, qctx_rows
+    ):
         B = q_res.shape[0]
         if q_srel is None:
             q_srel = np.full(B, -1, np.int32)
@@ -1619,7 +1663,8 @@ class DeviceEngine:
                 fetch=False, bucket_min=PB,
             )))
         for lo, hi, out in outs:
-            d, p, ovf = jax.device_get(out)
+            with _trace.stage("engine.fetch"):
+                d, p, ovf = jax.device_get(out)
             n = hi - lo
             yield lo, hi, d[:n], p[:n], ovf[:n]
 
@@ -1637,6 +1682,7 @@ class DeviceEngine:
         now_us: Optional[int] = None,
         fetch: bool = True,
         bucket_min: int = 0,
+        span=_trace.NOOP,
     ):
         """Bulk check straight from pre-interned int32 columns — the fast
         path for 100k+-item batches, where per-item Relationship objects
@@ -1661,45 +1707,51 @@ class DeviceEngine:
         B = q_res.shape[0]
         BP = _ceil_pow2(B, max(bucket_min, self.config.batch_bucket_min))
         queries, qctx = self._columns_preamble(
-            dsnap, q_res, q_perm, q_subj, q_srel, q_wc, q_ctx, qctx_rows
+            dsnap, q_res, q_perm, q_subj, q_srel, q_wc, q_ctx, qctx_rows,
+            span=span,
         )
         now_flat = jnp.int32(snap.now_rel32(now_us))
-        out = self._flat_call(
-            dsnap, queries, qctx, now_flat, B, bucket_min=bucket_min
-        )
+        with _trace.stage("engine.enqueue", span):
+            out = self._flat_call(
+                dsnap, queries, qctx, now_flat, B, bucket_min=bucket_min
+            )
         if out is not None:
             if not fetch:
                 return out
-            d, p, ovf = jax.device_get(out)
+            with _trace.stage("engine.fetch", span):
+                d, p, ovf = jax.device_get(out)
             return d[:B], p[:B], ovf[:B]
-        q_res, q_perm, q_subj = queries["q_res"], queries["q_perm"], queries["q_subj"]
-        q_srel, q_wc, q_ctx = queries["q_srel"], queries["q_wc"], queries["q_ctx"]
-        q_self = queries["q_self"]
+        with _trace.stage("engine.enqueue", span) as st:
+            st.note(legacy=True)
+            q_res, q_perm, q_subj = queries["q_res"], queries["q_perm"], queries["q_subj"]
+            q_srel, q_wc, q_ctx = queries["q_srel"], queries["q_wc"], queries["q_ctx"]
+            q_self = queries["q_self"]
 
-        subj_key = np.stack([q_subj, q_srel, q_wc, q_ctx], axis=1)
-        uniq, q_row = np.unique(subj_key, axis=0, return_inverse=True)
-        U = uniq.shape[0]
-        UP = _ceil_pow2(U, self.config.batch_bucket_min)
-        u = np.full((UP, 4), -1, np.int32)
-        u[:U] = uniq
+            subj_key = np.stack([q_subj, q_srel, q_wc, q_ctx], axis=1)
+            uniq, q_row = np.unique(subj_key, axis=0, return_inverse=True)
+            U = uniq.shape[0]
+            UP = _ceil_pow2(U, self.config.batch_bucket_min)
+            u = np.full((UP, 4), -1, np.int32)
+            u[:U] = uniq
 
-        def padq(a, fill):
-            out = np.full(BP, fill, np.asarray(a).dtype)
-            out[:B] = a
-            return jnp.asarray(out)
+            def padq(a, fill):
+                out = np.full(BP, fill, np.asarray(a).dtype)
+                out[:B] = a
+                return jnp.asarray(out)
 
-        now = jnp.int32(snap.now_rel32(now_us))
-        d, p, ovf = self._fn(
-            self._legacy_arrays(dsnap), dsnap.tid_map, now,
-            jnp.asarray(u[:, 0]), jnp.asarray(u[:, 1]), jnp.asarray(u[:, 2]),
-            jnp.asarray(u[:, 3]),
-            padq(q_res, -1), padq(q_perm, -1), padq(q_subj, -1),
-            padq(q_srel, -1), padq(q_wc, -1),
-            padq(q_row.astype(np.int32), 0),
-            padq(q_self, False), padq(q_ctx, -1),
-            self._qctx_device(qctx),
-        )
+            now = jnp.int32(snap.now_rel32(now_us))
+            d, p, ovf = self._fn(
+                self._legacy_arrays(dsnap), dsnap.tid_map, now,
+                jnp.asarray(u[:, 0]), jnp.asarray(u[:, 1]), jnp.asarray(u[:, 2]),
+                jnp.asarray(u[:, 3]),
+                padq(q_res, -1), padq(q_perm, -1), padq(q_subj, -1),
+                padq(q_srel, -1), padq(q_wc, -1),
+                padq(q_row.astype(np.int32), 0),
+                padq(q_self, False), padq(q_ctx, -1),
+                self._qctx_device(qctx),
+            )
         if not fetch:
             return d, p, ovf
-        d, p, ovf = jax.device_get((d, p, ovf))
+        with _trace.stage("engine.fetch", span):
+            d, p, ovf = jax.device_get((d, p, ovf))
         return d[:B], p[:B], ovf[:B]

@@ -334,36 +334,43 @@ class LatencyPath:
         # auto default folds the (synchronous) H2D remainder into the
         # kernel stage instead
         staged = self.engine.config.staged_timing_on()
+        # each budget stage is also a leaf on the profiler's clock
+        # (``gochugaru.engine.latency.*``, utils/trace.py); the budget's
+        # own t0 reaches back over the caller's lowering, the ``fill``
+        # annotation opens here, where ``engine.lower`` closed
+        ann = _trace.annotation
         with self._lock:
-            qm = self._qm_buf(tier)
-            fill_qm(queries, qm, meta)
-            qctx_dev = self.engine._qctx_device(qctx)
-            kc = self._qctx_key_cache
-            if kc is not None and kc[0] is qctx_dev:
-                qctx_key = kc[1]
-            else:
-                qctx_key = tuple(
-                    (k, tuple(v.shape), str(v.dtype))
-                    for k, v in sorted(qctx_dev.items())
-                )
-                self._qctx_key_cache = (qctx_dev, qctx_key)
-            t1 = time.perf_counter()
+            with ann("engine.latency.fill", span):
+                qm = self._qm_buf(tier)
+                fill_qm(queries, qm, meta)
+                qctx_dev = self.engine._qctx_device(qctx)
+                kc = self._qctx_key_cache
+                if kc is not None and kc[0] is qctx_dev:
+                    qctx_key = kc[1]
+                else:
+                    qctx_key = tuple(
+                        (k, tuple(v.shape), str(v.dtype))
+                        for k, v in sorted(qctx_dev.items())
+                    )
+                    self._qctx_key_cache = (qctx_dev, qctx_key)
+                t1 = time.perf_counter()
 
             # ---- stage 2: H2D (staging buffer + clock scalar) ----------
-            qm_dev = jax.device_put(qm)
-            nc = self._now_cache
-            if nc is not None and nc[0] == int(now):
-                now_dev = nc[1]
-            else:
-                now_dev = jax.device_put(np.int32(now))
-                self._now_cache = (int(now), now_dev)
-            if staged or jax.default_backend() != "cpu":
-                # the fence is load-bearing off-CPU regardless of the
-                # timing knob: the shared staging buffer must not be
-                # refilled (lock released) while an async H2D still
-                # reads it.  On CPU device_put copies synchronously, so
-                # only there may the knob elide the fence
-                jax.block_until_ready((qm_dev, now_dev))
+            with ann("engine.latency.h2d", span):
+                qm_dev = jax.device_put(qm)
+                nc = self._now_cache
+                if nc is not None and nc[0] == int(now):
+                    now_dev = nc[1]
+                else:
+                    now_dev = jax.device_put(np.int32(now))
+                    self._now_cache = (int(now), now_dev)
+                if staged or jax.default_backend() != "cpu":
+                    # the fence is load-bearing off-CPU regardless of the
+                    # timing knob: the shared staging buffer must not be
+                    # refilled (lock released) while an async H2D still
+                    # reads it.  On CPU device_put copies synchronously, so
+                    # only there may the knob elide the fence
+                    jax.block_until_ready((qm_dev, now_dev))
         t2 = time.perf_counter()
 
         # ---- stage 3: pinned kernel (blocked) --------------------------
@@ -382,21 +389,19 @@ class LatencyPath:
             _trace.trigger_incident(
                 "latency.retrace", tier=tier, batch=B, slots=len(slots),
             )
-        # profiler correlation: inside a GOCHUGARU_TRACE_DIR session the
-        # kernel window is annotated with the request's trace id, so the
-        # harvested device trace attributes back to this dispatch
-        with _trace.annotate_dispatch(span):
+        with ann("engine.latency.kernel", span):
             out = fn(*args)
             jax.block_until_ready(out)
         t3 = time.perf_counter()
 
         # ---- stage 4: D2H readback -------------------------------------
-        got = jax.device_get(out)
-        if len(got) == 4:  # witness-armed kernel: fourth plane = codes
-            d, p, ovf, w = got
-            self.last_witness = w[:B]
-        else:
-            d, p, ovf = got
+        with ann("engine.latency.d2h", span):
+            got = jax.device_get(out)
+            if len(got) == 4:  # witness-armed kernel: fourth plane = codes
+                d, p, ovf, w = got
+                self.last_witness = w[:B]
+            else:
+                d, p, ovf = got
         t4 = time.perf_counter()
 
         budget = DispatchBudget(
@@ -463,7 +468,8 @@ class LatencyPath:
         this).  Returns (d, p, ovf) or None → caller falls back."""
         t0 = time.perf_counter()
         queries, qctx = self.engine._columns_preamble(
-            self.dsnap, q_res, q_perm, q_subj, q_srel, q_wc, q_ctx, qctx_rows
+            self.dsnap, q_res, q_perm, q_subj, q_srel, q_wc, q_ctx, qctx_rows,
+            span=span,
         )
         now = self.dsnap.snapshot.now_rel32(now_us)
         return self.dispatch(

@@ -459,33 +459,36 @@ class ShardedEngine(DeviceEngine):
 
             k1d = _dense_np(dsnap.flat_meta.k1_dense)
         d = p = ovf = None
-        for at in range(0, max(len(all_slots), 1), cap):
-            chunk = tuple(all_slots[at : at + cap])
-            if multi:
-                pc = np.full(BP, -1, np.int32)
-                pc[:B] = np.where(
-                    np.isin(q_perm, np.asarray(chunk, np.int32)), q_perm, -1
+        with _trace.stage("engine.enqueue", span):
+            for at in range(0, max(len(all_slots), 1), cap):
+                chunk = tuple(all_slots[at : at + cap])
+                if multi:
+                    pc = np.full(BP, -1, np.int32)
+                    pc[:B] = np.where(
+                        np.isin(q_perm, np.asarray(chunk, np.int32)),
+                        q_perm, -1,
+                    )
+                    pk = np.where(
+                        pc >= 0, k1d[np.clip(pc, 0, k1d.shape[0] - 1)], -1
+                    ).astype(np.int32)
+                    qmc = set_perm(
+                        qm_dev,
+                        jax.device_put(pc, row_sh),
+                        jax.device_put(pk, row_sh),
+                    )
+                else:
+                    qmc = qm_dev
+                fn = self._flat_sharded_fn(chunk, dsnap.flat_meta, arr_keys)
+                cd, cp, covf = fn(
+                    dsnap.arrays, dsnap.tid_map, now, qmc, qctx_dev,
                 )
-                pk = np.where(
-                    pc >= 0, k1d[np.clip(pc, 0, k1d.shape[0] - 1)], -1
-                ).astype(np.int32)
-                qmc = set_perm(
-                    qm_dev,
-                    jax.device_put(pc, row_sh),
-                    jax.device_put(pk, row_sh),
-                )
-            else:
-                qmc = qm_dev
-            fn = self._flat_sharded_fn(chunk, dsnap.flat_meta, arr_keys)
-            cd, cp, covf = fn(
-                dsnap.arrays, dsnap.tid_map, now, qmc, qctx_dev,
-            )
-            d = cd if d is None else d | cd
-            p = cp if p is None else p | cp
-            ovf = covf if ovf is None else ovf | covf
+                d = cd if d is None else d | cd
+                p = cp if p is None else p | cp
+                ovf = covf if ovf is None else ovf | covf
         if not fetch:
             return d, p, ovf
-        d, p, ovf = jax.device_get((d, p, ovf))
+        with _trace.stage("engine.fetch", span):
+            d, p, ovf = jax.device_get((d, p, ovf))
         return d[:B], p[:B], ovf[:B]
 
     def _dispatch_flat_routed(
@@ -569,30 +572,33 @@ class ShardedEngine(DeviceEngine):
         cap = max(self.config.flat_max_slots, 1)
         k1d = _dense_np(meta.k1_dense)
         d = p = ovf = None
-        for at in range(0, max(len(all_slots), 1), cap):
-            chunk = tuple(all_slots[at : at + cap])
-            if len(all_slots) > cap:
-                # multi-chunk: splice the slot rows on the ROUTED layout
-                # host-side (rare path — distinct permissions > cap)
-                qmc_h = qm_r.copy()
-                pc = qm_r[1]
-                keep = np.isin(pc, np.asarray(chunk, np.int32))
-                qmc_h[1] = np.where(keep, pc, -1)
-                qmc_h[7] = np.where(
-                    keep & (pc >= 0),
-                    k1d[np.clip(pc, 0, k1d.shape[0] - 1)], -1,
-                ).astype(np.int32)
-                qm_dev = jax.device_put(qmc_h, dsh)
-            else:
-                qm_dev = jax.device_put(qm_r, dsh)
-            fn = self._flat_sharded_fn(chunk, meta, arr_keys, routed=True)
-            cd, cp, covf = fn(
-                dsnap.arrays, dsnap.tid_map, now, qm_dev, qctx_dev,
-            )
-            d = cd if d is None else d | cd
-            p = cp if p is None else p | cp
-            ovf = covf if ovf is None else ovf | covf
-        d, p, ovf = jax.device_get((d, p, ovf))
+        with _trace.stage("engine.enqueue", span):
+            for at in range(0, max(len(all_slots), 1), cap):
+                chunk = tuple(all_slots[at : at + cap])
+                if len(all_slots) > cap:
+                    # multi-chunk: splice the slot rows on the ROUTED
+                    # layout host-side (rare path — distinct
+                    # permissions > cap)
+                    qmc_h = qm_r.copy()
+                    pc = qm_r[1]
+                    keep = np.isin(pc, np.asarray(chunk, np.int32))
+                    qmc_h[1] = np.where(keep, pc, -1)
+                    qmc_h[7] = np.where(
+                        keep & (pc >= 0),
+                        k1d[np.clip(pc, 0, k1d.shape[0] - 1)], -1,
+                    ).astype(np.int32)
+                    qm_dev = jax.device_put(qmc_h, dsh)
+                else:
+                    qm_dev = jax.device_put(qm_r, dsh)
+                fn = self._flat_sharded_fn(chunk, meta, arr_keys, routed=True)
+                cd, cp, covf = fn(
+                    dsnap.arrays, dsnap.tid_map, now, qm_dev, qctx_dev,
+                )
+                d = cd if d is None else d | cd
+                p = cp if p is None else p | cp
+                ovf = covf if ovf is None else ovf | covf
+        with _trace.stage("engine.fetch", span):
+            d, p, ovf = jax.device_get((d, p, ovf))
         span.event("unroute")
         return (
             np.asarray(d)[dst], np.asarray(p)[dst], np.asarray(ovf)[dst]
@@ -615,8 +621,8 @@ class ShardedEngine(DeviceEngine):
         here per shard.  With ``fetch=False`` the raw padded sharded
         device outputs (length BP ≥ B) are returned for pipelined
         dispatch, mirroring DeviceEngine.check_columns.  A sampled
-        ``span`` records a ``sharded.dispatch`` child (partition /
-        collective / fetch stage events)."""
+        ``span`` records a ``sharded.dispatch`` child holding the
+        ``engine.enqueue`` / ``engine.fetch`` stages."""
         faults.fire("sharded.dispatch")
         ssp = span.child(
             "sharded.dispatch",
@@ -625,11 +631,10 @@ class ShardedEngine(DeviceEngine):
         )
         try:
             if dsnap.flat_meta is not None:
-                with _trace.annotate_dispatch(span):
-                    return self._dispatch_flat(
-                        dsnap, queries, qctx, now_us, fetch,
-                        bucket_min=bucket_min, span=ssp,
-                    )
+                return self._dispatch_flat(
+                    dsnap, queries, qctx, now_us, fetch,
+                    bucket_min=bucket_min, span=ssp,
+                )
             snap = dsnap.snapshot
             D = self.data_size
             B = queries["q_res"].shape[0]
@@ -677,7 +682,7 @@ class ShardedEngine(DeviceEngine):
             def put(a):
                 return jax.device_put(a, dsh)
 
-            with _trace.annotate_dispatch(span):
+            with _trace.stage("engine.enqueue", ssp):
                 d, p, ovf = self._fn(
                     dsnap.arrays, dsnap.tid_map, now,
                     put(u_subj), put(u_srel), put(u_wc), put(u_qctx),
@@ -686,11 +691,10 @@ class ShardedEngine(DeviceEngine):
                     put(q["q_ctx"]),
                     {k: jax.device_put(v, rep) for k, v in qctx.items()},
                 )
-            ssp.event("stage.collective")
             if not fetch:
                 return d, p, ovf
-            d, p, ovf = jax.device_get((d, p, ovf))
-            ssp.event("stage.fetch")
+            with _trace.stage("engine.fetch", ssp):
+                d, p, ovf = jax.device_get((d, p, ovf))
             return d[:B], p[:B], ovf[:B]
         finally:
             ssp.end()
@@ -708,7 +712,9 @@ class ShardedEngine(DeviceEngine):
         if not rels:
             z = np.zeros(0, bool)
             return z, z, z
-        queries, _, qctx = self._lower_queries(dsnap.snapshot, rels, dsnap.strings)
+        queries, _, qctx = self._lower_queries(
+            dsnap.snapshot, rels, dsnap.strings, span=span
+        )
         return self._dispatch_columns(dsnap, queries, qctx, now_us, span=span)
 
     # -- owner-routed lookup hops (engine/spmv.py frontier SpMV) ----------
@@ -736,16 +742,19 @@ class ShardedEngine(DeviceEngine):
         now_us: Optional[int] = None,
         fetch: bool = True,
         bucket_min: int = 0,
+        span=_trace.NOOP,
     ):
         """Columnar bulk check with the sharded layout (the base-class fast
         path assumes an unsharded q_row/uniq table, which would be wrong
         under shard_map — see _dispatch_columns).  ``bucket_min`` raises
         the per-data-shard padding floor, matching DeviceEngine."""
         queries, qctx = self._columns_preamble(
-            dsnap, q_res, q_perm, q_subj, q_srel, q_wc, q_ctx, qctx_rows
+            dsnap, q_res, q_perm, q_subj, q_srel, q_wc, q_ctx, qctx_rows,
+            span=span,
         )
         return self._dispatch_columns(
-            dsnap, queries, qctx, now_us, fetch=fetch, bucket_min=bucket_min
+            dsnap, queries, qctx, now_us, fetch=fetch, bucket_min=bucket_min,
+            span=span,
         )
 
 

@@ -191,6 +191,13 @@ class SubmitFuture:
                     )
                 step = min(step, remaining)
             self._ev.wait(step)
+        if self.t_done is not None:
+            # resolved → in the caller's hand: the woken caller waiting
+            # to run.  Timer only: this thread was blocked from submit
+            # to answer, an annotation here would enclose every stage
+            _trace.observe_stage(
+                "serve.wake", self.t_done, time.perf_counter()
+            )
         if self._error is not None:
             raise self._error
         return self._value
@@ -548,11 +555,9 @@ class MicroBatcher:
                     # not lost to idle (the chaos closure test's subject)
                     _perf.report_wall("form", now, time.perf_counter())
                     continue
-                batch = self._form_locked(reason, now)
-                t_f1 = time.perf_counter()
-                _perf.report_wall("form", now, t_f1)
-                self._m.observe("serve.form_s", t_f1 - now)
-                return batch
+                with _trace.stage("serve.form", wall="form",
+                                  registry=self._m):
+                    return self._form_locked(reason, now)
 
     def _form_locked(self, reason: str, now: float) -> _FormedBatch:
         cfg = self.config
@@ -663,12 +668,17 @@ class MicroBatcher:
         if not batch.subs:
             return
         t0 = time.perf_counter()
-        # wall ledger: formed→dispatch-start is the formed batch's queue
-        # wait; the dispatch window itself reports as ``filter`` (host
-        # concat/slice/settle) with the device stages — reported by the
-        # latency path from the same stamps its budget uses — overlaying
-        # it at higher priority, so filter ends up the host-side residue
-        _perf.report_wall("queue_wait", batch.t_formed, t0)
+        # formed→dispatch-start is the formed batch waiting for this
+        # thread (``queue_wait`` to the wall ledger; timer only, no child
+        # span: it precedes the root below).  The dispatch window itself
+        # reports as ``filter`` (host concat/slice/settle) with the
+        # device stages — reported by the latency path from the same
+        # stamps its budget uses — overlaying it at higher priority, so
+        # filter ends up the host-side residue
+        _trace.observe_stage(
+            "serve.formed_wait", batch.t_formed, t0, wall="queue_wait",
+            registry=m,
+        )
         sp = _trace.root_span(
             "serve.dispatch",
             batch=batch.total, target=batch.target, reason=batch.reason,
@@ -682,42 +692,48 @@ class MicroBatcher:
             try:
                 faults.fire("batcher.dispatch")
                 use_latency = self.config.use_latency and batch.tier is not None
-                if batch.kind == "cols":
-                    if len(batch.subs) == 1:
-                        q_res, q_perm, q_subj = batch.subs[0].cols
-                    else:
-                        q_res = np.concatenate([s.cols[0] for s in batch.subs])
-                        q_perm = np.concatenate([s.cols[1] for s in batch.subs])
-                        q_subj = np.concatenate([s.cols[2] for s in batch.subs])
-                    if sf is not None:
-                        keys = _vcache.pack_cols(q_perm, q_res, q_subj)
-                        if isinstance(keys, np.ndarray):
-                            ks = np.sort(keys)
-                            # unique-work count off the same sort the
-                            # window probes use — effective occupancy
-                            unique = int(
-                                1 + (ks[1:] != ks[:-1]).sum()
-                            ) if ks.shape[0] else 0
-                            sf.open_cols(keys, ks)
+                with _trace.stage("serve.concat", sp, registry=m):
+                    if batch.kind == "cols":
+                        if len(batch.subs) == 1:
+                            q_res, q_perm, q_subj = batch.subs[0].cols
                         else:
-                            key_map = dict(zip(keys, range(len(keys))))
+                            q_res = np.concatenate(
+                                [s.cols[0] for s in batch.subs])
+                            q_perm = np.concatenate(
+                                [s.cols[1] for s in batch.subs])
+                            q_subj = np.concatenate(
+                                [s.cols[2] for s in batch.subs])
+                        if sf is not None:
+                            keys = _vcache.pack_cols(q_perm, q_res, q_subj)
+                            if isinstance(keys, np.ndarray):
+                                ks = np.sort(keys)
+                                # unique-work count off the same sort the
+                                # window probes use — effective occupancy
+                                unique = int(
+                                    1 + (ks[1:] != ks[:-1]).sum()
+                                ) if ks.shape[0] else 0
+                                sf.open_cols(keys, ks)
+                            else:
+                                key_map = dict(zip(keys, range(len(keys))))
+                                unique = len(key_map)
+                                sf.open_map(key_map)
+                            window_open = True
+                    else:
+                        rels = [r for s in batch.subs for r in s.rels]
+                        if sf is not None:
+                            kl = [_vcache.rel_key(r) for r in rels]
+                            key_map = dict(zip(kl, range(len(kl))))
                             unique = len(key_map)
                             sf.open_map(key_map)
+                            window_open = True
+                    if window_open:
                         sp.set_attr("unique", unique)
                         m.inc("serve.unique_checks", unique)
-                        window_open = True
+                if batch.kind == "cols":
                     verdicts = self._dispatch_cols(
                         q_res, q_perm, q_subj, use_latency, sp
                     )
                 else:
-                    rels = [r for s in batch.subs for r in s.rels]
-                    if sf is not None:
-                        kl = [_vcache.rel_key(r) for r in rels]
-                        key_map = dict(zip(kl, range(len(kl))))
-                        sp.set_attr("unique", len(key_map))
-                        m.inc("serve.unique_checks", len(key_map))
-                        sf.open_map(key_map)
-                        window_open = True
                     verdicts = self._dispatch_rels(rels, use_latency, sp)
             except BulkCheckItemError as e:
                 # a per-item oracle failure is batch-relative: slice it
@@ -759,35 +775,39 @@ class MicroBatcher:
                     s.future._reject(err, t1)
                 return
             dt = time.perf_counter() - t0
-            # feed the shared cost model at this batch's ladder tier —
-            # the hold-back's estimate learns from real coalesced
-            # dispatches, not just caller-formed ones.  Batch-path
-            # (breaker-open) batches have no ladder tier; they tag with
-            # their target cap instead of the tier-less channel, which
-            # is reserved for CALLER-formed dispatch costs (see
-            # CostModel.observe)
-            self._cost.observe(
-                dt, tier=batch.tier if batch.tier is not None else batch.target
-            )
             m.observe("serve.dispatch_s", dt)
-            t1 = time.perf_counter()
-            # exemplar: the batch's dispatch trace id, so a fat latency
-            # bucket on /metrics links straight to a recorded trace
-            # (flight-only spans carry ids too — the recorder retains
-            # them even when the head sample dropped the trace)
-            tid = sp.trace_id if sp.sampled else None
-            off = 0
-            for s in batch.subs:
-                s.future._resolve(verdicts[off:off + s.n], t1)
-                lat = t1 - s.future.t_submit
-                m.observe("serve.request_s", lat)
-                m.observe_hist(
-                    "serve.request_latency", lat,
-                    REQUEST_LATENCY_BUCKETS, trace_id=tid,
+            with _trace.stage("serve.settle", sp, registry=m) as st:
+                # feed the shared cost model at this batch's ladder tier —
+                # the hold-back's estimate learns from real coalesced
+                # dispatches, not just caller-formed ones.  Batch-path
+                # (breaker-open) batches have no ladder tier; they tag
+                # with their target cap instead of the tier-less channel,
+                # which is reserved for CALLER-formed dispatch costs (see
+                # CostModel.observe)
+                self._cost.observe(
+                    dt,
+                    tier=batch.tier if batch.tier is not None
+                    else batch.target,
                 )
-                off += s.n
-            m.inc("serve.batches")
-            m.inc("serve.checks", batch.total)
+                t1 = st.t0
+                # exemplar: the batch's dispatch trace id, so a fat
+                # latency bucket on /metrics links straight to a recorded
+                # trace (flight-only spans carry ids too — the recorder
+                # retains them even when the head sample dropped the
+                # trace)
+                tid = sp.trace_id if sp.sampled else None
+                off = 0
+                for s in batch.subs:
+                    s.future._resolve(verdicts[off:off + s.n], t1)
+                    lat = t1 - s.future.t_submit
+                    m.observe("serve.request_s", lat)
+                    m.observe_hist(
+                        "serve.request_latency", lat,
+                        REQUEST_LATENCY_BUCKETS, trace_id=tid,
+                    )
+                    off += s.n
+                m.inc("serve.batches")
+                m.inc("serve.checks", batch.total)
         finally:
             # settle-exactly-once backstop: a BaseException escaping the
             # paths above (interpreter shutdown, a settle-path bug) must
@@ -861,7 +881,9 @@ class MicroBatcher:
         try:
             while True:
                 try:
-                    batch = self._form_q.get(timeout=0.25)
+                    # nothing formed yet: the dispatcher has nothing to run
+                    with _trace.stage("serve.idle", registry=self._m):
+                        batch = self._form_q.get(timeout=0.25)
                 except _queue.Empty:
                     # sentinel-less exit: a dead/finished former sends
                     # nothing more, so closed + empty queue = done

@@ -103,19 +103,35 @@ class Metrics:
 
     def observe(self, name: str, seconds: float) -> None:
         with self._lock:
-            t = self._timings[name]
-            t[0] += 1
-            t[1] += seconds
-            s = self._samples[name]
-            if len(s) < self.SAMPLE_CAP:
-                s.append(seconds)
-            else:
-                cur = self._scursor[name]
-                s[cur] = seconds
-                self._scursor[name] = (cur + 1) % self.SAMPLE_CAP
-            thr = self._thr.get(name)
-            if thr is not None and seconds > thr:
-                self._over[name] += 1
+            self._observe_locked(name, seconds)
+
+    def try_observe(self, name: str, seconds: float) -> bool:
+        """``observe`` that never blocks: False (nothing recorded) when
+        the registry's lock is held.  For a caller that can run INSIDE
+        that lock on the thread holding it — the collector's callback
+        (utils/trace.py ``host.gc``), which any allocation can start."""
+        if not self._lock.acquire(blocking=False):
+            return False
+        try:
+            self._observe_locked(name, seconds)
+        finally:
+            self._lock.release()
+        return True
+
+    def _observe_locked(self, name: str, seconds: float) -> None:
+        t = self._timings[name]
+        t[0] += 1
+        t[1] += seconds
+        s = self._samples[name]
+        if len(s) < self.SAMPLE_CAP:
+            s.append(seconds)
+        else:
+            cur = self._scursor[name]
+            s[cur] = seconds
+            self._scursor[name] = (cur + 1) % self.SAMPLE_CAP
+        thr = self._thr.get(name)
+        if thr is not None and seconds > thr:
+            self._over[name] += 1
 
     def set_timer_threshold(self, name: str, seconds: Optional[float]) -> None:
         """Arm (or with ``None`` disarm) over-objective counting for a

@@ -1,5 +1,6 @@
 """Request-scoped tracing: spans, head sampling with a keep-slow tail
-rule, and profiler-correlated dispatch.
+rule, and the stage primitive that puts the check path's boundaries on
+the profiler's clock.
 
 Every number this project shipped before this module was a
 benchmark-harness aggregate; a serving system must answer "why was THIS
@@ -44,12 +45,37 @@ deep sites that never see a Context — the incremental closure advance,
 the store write path — attach events via ``event_if_active`` without
 plumbing a parameter through every signature.
 
-Profiler correlation: when a profiler session is active (the
-``GOCHUGARU_TRACE_DIR`` env var names its dump dir; ``profiler_session``
-sets it),
-``annotate_dispatch(span)`` wraps dispatch in a
-``jax.profiler.TraceAnnotation`` named by the trace id, so the XLA
-device trace carries request attribution for free.
+Stages (``stage`` / ``observe_stage`` / ``annotation``): one primitive
+at every layer boundary of the check path, per batch and per request,
+never per check or per key.  A stage is the interval between two
+``perf_counter`` stamps and feeds, from those SAME two stamps,
+
+1. the registry timer ``<name>_s`` (always on);
+2. a child span of the request's span when that is sampled (span
+   duration == timer sample, exactly);
+3. the wall ledger (utils/perf.py) where the boundary names a bucket;
+
+and, when ANY ``jax.profiler`` session is live on entry
+(``TraceAnnotation.is_enabled()`` — the benchmark's, an operator's
+``jax.profiler.trace(dir)``; no environment variable, no option), holds a
+``TraceAnnotation("gochugaru.<name>")`` open for the interval, so the
+stage lands in the same ``.xplane.pb`` as the device operations, on
+their clock, with ``trace_id`` metadata when the span is sampled.  While
+the stage records (a session is live or the span is sampled) ``cpu=True``
+also observes ``<name>_cpu_s`` from ``time.thread_time()`` read at the
+same two points, so wall minus CPU is the time the thread waited for the
+GIL, a lock or the device — only then, because that clock is a system
+call: 5.8 µs on the v5e host's sandbox and 200 µs with eight threads
+after the GIL (PERF.md §6, PR 25), which cost the serving dispatcher 5 %.
+**Stages are leaves**: no ``gochugaru.*`` annotation encloses another on
+its thread (a trace reduction that names an idle gap by the span
+overlapping it longest would give every gap to the enclosing one), so
+enclosing intervals (``checks.dispatch``, ``serve.dispatch_s``) stay
+plain timers, and an interval that another thread's stages fill
+(``serve.formed_wait``, ``serve.wake``, ``host.gc``) goes through
+``observe_stage``: timer only.  Off — no session, no tracer — a stage is
+two clock reads, one ``is_enabled()`` and one ``observe``: no ``Span``,
+no ``TraceAnnotation``.
 
 Flight recorder (this round): head sampling answers "why was THIS check
 slow" but not "what was the system doing when the breaker tripped" — by
@@ -84,6 +110,7 @@ from collections import deque
 from typing import Any, Dict, List, Optional
 
 from . import metrics as _metrics
+from . import perf as _perf
 
 #: events kept per span before dropping (the drop count is recorded on
 #: the span as ``events_dropped``)
@@ -103,10 +130,6 @@ _TRACER: Optional["Tracer"] = None
 #: the installed flight recorder (None ⇒ anomaly sites are one load +
 #: branch; requests the head sample drops stay on the NOOP path)
 _RECORDER: Optional["FlightRecorder"] = None
-
-#: cached profiler-session dir (GOCHUGARU_TRACE_DIR), refreshed by
-#: profiler_session()/refresh_profiler() — not re-read per dispatch
-_PROFILER_DIR: Optional[str] = os.environ.get("GOCHUGARU_TRACE_DIR") or None
 
 #: pid hex for trace ids, read ONCE — os.getpid() is a syscall per call
 #: (~46 µs under this container's sandbox; it dominated the traced-path
@@ -316,7 +339,7 @@ class _TraceRec:
 
     The trace id string renders lazily (``trace_id``): the eager
     sequence number is one atomic ``next()`` and the string only exists
-    when something reads it — export, or ``annotate_dispatch`` inside a
+    when something reads it — export, or a stage's annotation inside a
     profiler session.  The render is deterministic from (pid, seq,
     tracer salt), so concurrent readers agree without a lock."""
 
@@ -1040,7 +1063,7 @@ def event_if_active(name: str, **attrs) -> None:
         sp.event(name, **attrs)
 
 
-# -- profiler correlation ---------------------------------------------------
+# -- stages: the check path's boundaries, on every clock ---------------------
 
 
 class _NullCtx:
@@ -1055,56 +1078,147 @@ class _NullCtx:
 
 _NULL_CTX = _NullCtx()
 
-
-def refresh_profiler() -> Optional[str]:
-    """Re-read GOCHUGARU_TRACE_DIR (the profiler-session marker) into
-    the cached module flag; returns the active dir or None."""
-    global _PROFILER_DIR
-    _PROFILER_DIR = os.environ.get("GOCHUGARU_TRACE_DIR") or None
-    return _PROFILER_DIR
+#: ``jax.profiler.TraceAnnotation``, resolved on first use (this module
+#: imports without JAX); its ``is_enabled()`` says whether a profiler
+#: session is live in this process
+_ANNOTATION: Any = None
 
 
-def profiler_active() -> bool:
-    return _PROFILER_DIR is not None
+def _annotation_cls():
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        from jax.profiler import TraceAnnotation
+
+        _ANNOTATION = TraceAnnotation
+    return _ANNOTATION
 
 
-def annotate_dispatch(span) -> Any:
-    """A context manager for the kernel-execution window: when a
-    GOCHUGARU_TRACE_DIR profiler session is active, a
-    ``jax.profiler.TraceAnnotation`` named by the request's trace id
-    (``gochugaru:<trace_id>``, or ``gochugaru:untraced`` for unsampled
-    requests), so the harvested device trace carries request
-    attribution.  Otherwise a shared null context — no allocation."""
-    if _PROFILER_DIR is None:
+def annotation(name: str, span=NOOP):
+    """The profiler sink alone, as a context manager: for an interval
+    whose timers and spans are already built from stamps of its own
+    (the latency path's four budget stages).  The shared null context
+    when no session is live."""
+    ta = _ANNOTATION or _annotation_cls()
+    if not ta.is_enabled():
         return _NULL_CTX
-    import jax
+    if span.sampled:
+        return ta("gochugaru." + name, trace_id=span.trace_id)
+    return ta("gochugaru." + name)
 
-    name = f"gochugaru:{span.trace_id}" if span is not NOOP else "gochugaru:untraced"
-    return jax.profiler.TraceAnnotation(name)
+
+def observe_stage(name: str, t0: float, t1: float, span=NOOP,
+                  wall: Optional[str] = None, registry=None,
+                  attrs: Optional[Dict[str, Any]] = None) -> None:
+    """One stage from two ``perf_counter`` stamps the caller took: the
+    ``<name>_s`` timer, the child span (with ``attrs``) when ``span`` is
+    sampled, the wall-ledger bucket ``wall`` when given.  No annotation —
+    called directly, this is the form for an interval that is not the
+    calling thread's own work."""
+    if _GC_PENDING:
+        _drain_gc()
+    (registry or _metrics.default).observe(name + "_s", t1 - t0)
+    if span.sampled:
+        child = span.child_at(name, t0)
+        child.attrs = attrs
+        child.end(t=t1)
+    if wall is not None:
+        _perf.report_wall(wall, t0, t1)
 
 
-class profiler_session:
-    """Marks a profiler session active for this process (sets
-    GOCHUGARU_TRACE_DIR and the cached flag) for the duration — wrap a
-    ``jax.profiler.trace`` window in this so every dispatch inside is
-    request-annotated."""
+class stage:
+    """``with stage(name, span):`` around the work of one
+    boundary-to-boundary interval (module docstring: three sinks plus
+    the profiler, leaves only).  ``note(k=v)`` attaches metadata to the
+    annotation and the child span, and is dropped when neither records;
+    ``recording`` says whether either does (the engine picks its timing
+    closure by it, once per batch)."""
 
-    def __init__(self, trace_dir: str) -> None:
-        self.trace_dir = trace_dir
-        self._prev: Optional[str] = None
+    __slots__ = ("name", "span", "cpu", "wall", "registry",
+                 "t0", "_c0", "_ann", "_meta")
 
-    def __enter__(self) -> "profiler_session":
-        global _PROFILER_DIR
-        self._prev = os.environ.get("GOCHUGARU_TRACE_DIR")
-        os.environ["GOCHUGARU_TRACE_DIR"] = self.trace_dir
-        _PROFILER_DIR = self.trace_dir
+    def __init__(self, name: str, span=NOOP, cpu: bool = False,
+                 wall: Optional[str] = None, registry=None) -> None:
+        self.name = name
+        self.span = span
+        self.cpu = cpu
+        self.wall = wall
+        self.registry = registry
+        self._ann = None
+        self._meta: Optional[Dict[str, Any]] = None
+
+    @property
+    def recording(self) -> bool:
+        return self._ann is not None or self.span.sampled
+
+    def note(self, **meta) -> None:
+        if self._ann is not None:
+            self._ann.set_metadata(**meta)
+        if self.span.sampled:
+            if self._meta is None:
+                self._meta = meta
+            else:
+                self._meta.update(meta)
+
+    def __enter__(self) -> "stage":
+        ann = annotation(self.name, self.span)
+        if ann is not _NULL_CTX:
+            ann.__enter__()
+            self._ann = ann
+        # the thread's CPU clock is a system call: only while recording
+        self.cpu = self.cpu and self.recording
+        self.t0 = time.perf_counter()
+        if self.cpu:  # read inside the wall stamps: CPU <= wall
+            self._c0 = time.thread_time()
         return self
 
-    def __exit__(self, *exc) -> bool:
-        global _PROFILER_DIR
-        if self._prev is None:
-            os.environ.pop("GOCHUGARU_TRACE_DIR", None)
-        else:
-            os.environ["GOCHUGARU_TRACE_DIR"] = self._prev
-        _PROFILER_DIR = self._prev
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        c1 = time.thread_time() if self.cpu else 0.0
+        t1 = time.perf_counter()
+        if exc is not None:
+            self.note(error=type(exc).__name__)
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+        observe_stage(self.name, self.t0, t1, self.span, self.wall,
+                      self.registry, self._meta)
+        if self.cpu:
+            (self.registry or _metrics.default).observe(
+                self.name + "_cpu_s", c1 - self._c0)
         return False
+
+
+# -- host.gc: full collections as a timer -----------------------------------
+
+#: seconds of finished generation-2 collections not yet in the registry.
+#: The collector can start inside the registry's own lock, on the thread
+#: that holds it (any allocation there can trigger it), so its callback
+#: observes only if the lock is free; what it could not hand over, the
+#: next stage to close does.
+_GC_PENDING: List[float] = []
+_GC_T0 = 0.0
+
+
+def _on_gc(phase: str, info: Dict[str, Any]) -> None:
+    global _GC_T0
+    if info.get("generation") != 2:
+        return
+    if phase == "start":
+        _GC_T0 = time.perf_counter()
+    elif _GC_T0:
+        took, _GC_T0 = time.perf_counter() - _GC_T0, 0.0
+        if not _metrics.default.try_observe("host.gc_s", took):
+            _GC_PENDING.append(took)
+
+
+def _drain_gc() -> None:
+    while _GC_PENDING:
+        _metrics.default.observe("host.gc_s", _GC_PENDING.pop())
+
+
+def install_gc_timer() -> None:
+    """Time full (generation 2) collections into ``host.gc_s``: once per
+    process, by the first client.  Timer only — a collection runs inside
+    whatever stage its thread is in, and stages are leaves."""
+    import gc
+
+    if _on_gc not in gc.callbacks:
+        gc.callbacks.append(_on_gc)

@@ -1,10 +1,8 @@
-"""Client cache-pinning (round-2 Weak #5) and the jax.profiler escape
-hatch (SURVEY.md §5 tracing/profiling)."""
-
-import os
+"""Client cache-pinning (round-2 Weak #5) and the check's stages inside a
+jax.profiler session (SURVEY.md §5 tracing/profiling)."""
 
 from gochugaru_tpu import consistency, rel
-from gochugaru_tpu.client import Client, with_profiling
+from gochugaru_tpu.client import Client
 from gochugaru_tpu.utils import metrics
 from gochugaru_tpu.utils.context import background
 
@@ -70,25 +68,34 @@ def test_lowest_revision_not_preferentially_evicted():
     assert snap.revision in c._dsnap_cache
 
 
-def test_profiling_option_writes_trace_and_metric(tmp_path):
-    trace_dir = str(tmp_path / "trace")
-    c = Client(with_profiling(trace_dir))
-    ctx = background()
-    c.write_schema(ctx, SCHEMA)
-    txn = rel.Txn()
-    txn.create(rel.must_from_triple("doc:d", "reader", "user:u"))
-    rev = c.write(ctx, txn)
-    before = metrics.default.snapshot().get("checks.device_time_s.count", 0)
-    assert c.check_one(
-        ctx, consistency.at_least(rev),
-        rel.must_from_triple("doc:d", "view", "user:u"),
+def test_check_inside_a_jax_profiler_trace_holds_the_stages(tmp_path):
+    """The profiling hook is JAX's own: wrap the window in
+    ``jax.profiler.trace(dir)`` and the check's stages are events of that
+    trace (``gochugaru.<layer>.<stage>``), leaves on their thread."""
+    import jax
+
+    from tests.test_trace import (
+        assert_stages_are_leaves,
+        profiled_stage_events,
+        stage_names_on_thread_of,
     )
-    found = []
-    for root, _dirs, files in os.walk(trace_dir):
-        found.extend(files)
-    assert found, "profiler trace directory is empty"
+
+    c, ctx, rev = seeded_client()
+    r = rel.must_from_triple("doc:d", "view", "user:u")
+    assert c.check_one(ctx, consistency.at_least(rev), r)  # prepare, compile
+    before = metrics.default.snapshot().get("checks.device_time_s.count", 0)
+    with jax.profiler.trace(str(tmp_path / "trace")):
+        assert c.check_one(ctx, consistency.at_least(rev), r)
+    events = profiled_stage_events(tmp_path / "trace")
+    names = stage_names_on_thread_of(events, "gochugaru.engine.lower")
+    assert names == [
+        "gochugaru.client.snapshot", "gochugaru.engine.lower",
+        "gochugaru.engine.enqueue", "gochugaru.engine.fetch",
+        "gochugaru.client.verdicts",
+    ], names
+    assert_stages_are_leaves(events)
     after = metrics.default.snapshot().get("checks.device_time_s.count", 0)
-    assert after > before
+    assert after == before + 1
 
 
 def test_client_takes_incremental_device_path():

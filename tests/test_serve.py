@@ -779,3 +779,56 @@ def test_metrics_histogram_and_prometheus_render():
     assert 'gochugaru_serve_batch_fill_bucket{le="256"} 5' in text
     assert 'gochugaru_serve_batch_fill_bucket{le="+Inf"} 6' in text
     assert "gochugaru_serve_batch_fill_count 6" in text
+
+
+# ---------------------------------------------------------------------------
+# stage accounting: a served request is covered end to end
+# ---------------------------------------------------------------------------
+
+def test_serve_stages_account_for_caller_latency(store_world):
+    """Over 200 requests from 8 threads the four serve intervals — queue
+    wait, formed-batch wait, dispatch, wake — add up to the latency the
+    callers measured themselves, within 10 % in total: nothing of a
+    served request is outside every stage."""
+    c, _oracle = store_world
+    ctx = background()
+    m = metrics.default
+    parts = ("serve.queue_wait_s", "serve.formed_wait_s", "serve.dispatch_s",
+             "serve.wake_s")
+    measured = []
+    with c.with_serving() as h:
+        warm = _rand_checks(np.random.default_rng(99), 6)
+        h.check(ctx, *warm)  # the tier's first dispatch may compile
+        snap0 = m.snapshot()
+
+        def worker(w):
+            lr = np.random.default_rng(100 + w)
+            mine = []
+            for _ in range(25):
+                qs = _rand_checks(lr, 6)
+                t0 = time.perf_counter()
+                h.check(ctx, *qs, client_id=w)
+                mine.append(time.perf_counter() - t0)
+            measured.extend(mine)
+
+        ts = [threading.Thread(target=worker, args=(w,)) for w in range(8)]
+        for t in ts:
+            t.start()
+        for t in ts:
+            t.join()
+        snap1 = m.snapshot()
+    delta = lambda k: snap1.get(k, 0) - snap0.get(k, 0)
+    assert len(measured) == 200
+    assert delta("serve.queue_wait_s.count") == 200  # one a submission
+    assert delta("serve.wake_s.count") == 200
+    batches = delta("serve.dispatch_s.count")
+    assert delta("serve.formed_wait_s.count") == batches  # one a batch
+    # per-submission intervals sum as they are; a per-batch interval is
+    # paid by every submission of its batch, so it enters at its mean
+    staged = sum(delta(f"{p}.total_s") / delta(f"{p}.count") for p in parts)
+    mean = sum(measured) / len(measured)
+    assert abs(staged - mean) <= 0.10 * mean, (staged, mean, batches)
+    # the dispatcher's own leaves ran once a batch
+    for p in ("serve.concat_s", "serve.settle_s", "serve.form_s"):
+        assert delta(f"{p}.count") == batches, p
+    assert delta("serve.idle_s.count") >= 1

@@ -237,3 +237,294 @@ def test_span_event_cap_bounded():
     for i in range(10):
         trace.root_span("r", i=i).end()
     assert len(tr.traces()) == 4
+
+
+# ---------------------------------------------------------------------------
+# stages: one primitive at every boundary of the check path
+# ---------------------------------------------------------------------------
+
+BATCH_STAGES = (
+    "client.snapshot", "engine.lower", "engine.enqueue", "engine.fetch",
+    "client.verdicts",
+)
+
+
+@pytest.fixture(scope="module")
+def batch_client():
+    """No latency mode: a check takes the batch path of check_batch
+    (lower → enqueue → fetch)."""
+    c = new_tpu_evaluator()
+    ctx = background()
+    c.write_schema(ctx, SCHEMA)
+    txn = rel.Txn()
+    for i in range(16):
+        txn.create(rel.must_from_triple(f"doc:d{i}", "reader", f"user:u{i}"))
+    c.write(ctx, txn)
+    rs = [rel.must_from_triple(f"doc:d{i}", "read", f"user:u{i}") for i in range(8)]
+    assert c.check(ctx, consistency.full(), *rs) == [True] * 8  # warm
+    return c, ctx, rs
+
+
+def _counts(*timers):
+    snap = metrics.default.snapshot()
+    return {t: snap.get(f"{t}.count", 0) for t in timers}
+
+
+def profiled_stage_events(trace_dir):
+    """[(thread line, name, start_ns, end_ns, stats)] of the program's
+    ``gochugaru.*`` events in the newest ``.xplane.pb`` under
+    ``trace_dir`` (also used by tests/test_client_caching.py)."""
+    import glob
+    import os
+    import warnings
+
+    import jax
+
+    found = sorted(glob.glob(os.path.join(
+        str(trace_dir), "plugins", "profile", "*", "*.xplane.pb")))
+    assert found, "the profiler session left no .xplane.pb"
+    profile = jax.profiler.ProfileData.from_file(found[-1])
+    out = []
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore", DeprecationWarning)
+        for plane in profile.planes:
+            for n, line in enumerate(plane.lines):
+                for e in line.events:
+                    if e.name.startswith("gochugaru."):
+                        out.append((
+                            (plane.name, n), e.name, e.start_ns,
+                            e.start_ns + e.duration_ns, dict(e.stats),
+                        ))
+    return out
+
+
+def stage_names_on_thread_of(events, name):
+    """Names, in time order, of the stages on the thread that ran
+    ``name`` (other tests' idle serving threads may be annotating too)."""
+    thread = next(e[0] for e in events if e[1] == name)
+    return [e[1] for e in sorted(events, key=lambda e: e[2]) if e[0] == thread]
+
+
+def assert_stages_are_leaves(events):
+    """No ``gochugaru.*`` event may enclose (or overlap) another on its
+    thread: a reduction that names a gap by the longest-overlapping span
+    would hand every gap to the enclosing one."""
+    by_thread = {}
+    for thread, name, start, end, _stats in events:
+        by_thread.setdefault(thread, []).append((start, end, name))
+    for thread, evs in by_thread.items():
+        evs.sort()
+        for (s0, e0, n0), (s1, _e1, n1) in zip(evs, evs[1:]):
+            assert s1 >= e0, f"{n1} starts inside {n0} on {thread}"
+
+
+class _CountingAnnotation:
+    """Stands in for jax.profiler.TraceAnnotation: records constructions."""
+
+    built: list = []
+    live = False
+
+    def __init__(self, name, **kwargs):
+        self.built.append(name)
+
+    @classmethod
+    def is_enabled(cls):
+        return cls.live
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def set_metadata(self, **kwargs):
+        pass
+
+
+def test_stages_off_path_builds_no_span_and_no_annotation(
+        batch_client, monkeypatch):
+    """(a) No tracer, no profiler session: a full client.check builds no
+    Span and no TraceAnnotation, yet every stage's timer gained exactly
+    one sample."""
+    c, ctx, rs = batch_client
+    _CountingAnnotation.built, _CountingAnnotation.live = [], False
+    monkeypatch.setattr(trace, "_ANNOTATION", _CountingAnnotation)
+    timers = [f"{s}_s" for s in BATCH_STAGES]
+    recorded = ("engine.intern_s", "engine.lower_cpu_s")
+    before, n0 = _counts(*timers, *recorded), trace.spans_created()
+    assert c.check(ctx, consistency.full(), *rs) == [True] * 8
+    after = _counts(*timers, *recorded)
+    assert trace.spans_created() == n0
+    assert _CountingAnnotation.built == []
+    for t in timers:
+        assert after[t] == before[t] + 1, t
+    # the interner and the thread's CPU clock (a system call) are read
+    # only while something records
+    for t in recorded:
+        assert after[t] == before[t], t
+    # ... and with a session live each stage holds exactly one annotation
+    _CountingAnnotation.live = True
+    assert c.check(ctx, consistency.full(), *rs) == [True] * 8
+    mine = [f"gochugaru.{s}" for s in BATCH_STAGES]  # idle serving threads
+    assert [n for n in _CountingAnnotation.built if n in mine] == mine  # may add theirs
+    live = _counts(*recorded)
+    for t in recorded:
+        assert live[t] == after[t] + 1, t
+
+
+def test_stages_land_in_the_profilers_trace_as_leaves(batch_client, tmp_path):
+    """(b) Under any jax.profiler session the stages are events of the
+    same .xplane.pb, none encloses another on its thread, and the time
+    inside the interner is observed — outside the session it is not."""
+    import jax
+
+    c, ctx, rs = batch_client
+    before = _counts("engine.intern_s")
+    with jax.profiler.trace(str(tmp_path)):
+        assert c.check(ctx, consistency.full(), *rs) == [True] * 8
+        with c.with_serving(cs=consistency.full()) as h:
+            assert h.check(ctx, *rs) == [True] * 8
+    inside = _counts("engine.intern_s")
+    assert inside["engine.intern_s"] >= before["engine.intern_s"] + 2
+    assert c.check(ctx, consistency.full(), *rs) == [True] * 8
+    assert _counts("engine.intern_s") == inside
+    events = profiled_stage_events(tmp_path)
+    names = {e[1] for e in events}
+    want = {f"gochugaru.{s}" for s in BATCH_STAGES} | {
+        "gochugaru.serve.form", "gochugaru.serve.concat",
+        "gochugaru.serve.settle", "gochugaru.serve.idle",
+    }
+    assert want <= names, want - names
+    # timer-only stages never become annotations
+    assert not names & {"gochugaru.serve.wake", "gochugaru.serve.formed_wait",
+                        "gochugaru.host.gc"}
+    assert_stages_are_leaves(events)
+    lower = [e for e in events if e[1] == "gochugaru.engine.lower"]
+    assert all(e[4].get("batch") == 8 and "intern_s" in e[4] for e in lower)
+
+
+def test_latency_stage_annotations_are_leaves(doc_client, tmp_path):
+    """The latency path's four budget stages are annotated too, after
+    ``engine.lower`` has closed."""
+    import jax
+
+    c, ctx, rs = doc_client
+    with jax.profiler.trace(str(tmp_path)):
+        assert c.check(ctx, consistency.full(), *rs) == [True] * 8
+    events = profiled_stage_events(tmp_path)
+    names = stage_names_on_thread_of(events, "gochugaru.engine.lower")
+    assert names == [f"gochugaru.{s}" for s in (
+        "client.snapshot", "engine.lower", "engine.latency.fill",
+        "engine.latency.h2d", "engine.latency.kernel", "engine.latency.d2h",
+        "client.verdicts")], names
+    assert_stages_are_leaves(events)
+
+
+def test_sampled_batch_path_stage_spans_equal_timer_samples(batch_client):
+    """(c) A sampled request's stage child spans are built from the same
+    two stamps as the timers: duration == timer sample, exactly."""
+    c, ctx, rs = batch_client
+    tr = trace.configure(sample_rate=1.0, slow_threshold_s=None, capacity=32)
+    before = _counts("engine.intern_s")
+    assert c.check(ctx, consistency.full(), *rs) == [True] * 8
+    t = [t for t in tr.traces() if t["name"] == "check"][-1]
+    by = _spans_by_name(t)
+    dev = by["device.check_batch"][0]
+    for s in BATCH_STAGES:
+        sp = by[s][0]
+        ring = metrics.default._samples[f"{s}_s"]
+        assert any(abs(v - sp["dur_s"]) < 1e-9 for v in ring), s
+        parent = dev if s.startswith("engine.") else by["dispatch"][0]
+        assert sp["parent_id"] == parent["span_id"], s
+    # a sampled span is reason enough to time the interner
+    assert _counts("engine.intern_s")["engine.intern_s"] == (
+        before["engine.intern_s"] + 1)
+    assert "intern_s" in by["engine.lower"][0]["attrs"]
+
+
+def test_intern_counters_account_for_every_node_lookup(batch_client):
+    """(e) Every node look-up of a batch either hit the engine's memo or
+    reached the interner; the memo being emptied is counted."""
+    c, ctx, _ = batch_client
+    m = metrics.default
+    rs = [rel.must_from_triple(f"doc:d{i % 16}", "read", f"user:u{i % 5}")
+          for i in range(40)]
+    keys = ("intern.lookups", "intern.memo_hits", "intern.memo_clears")
+    before = {k: m.counter(k) for k in keys}
+    c.check(ctx, consistency.full(), *rs)
+    after = {k: m.counter(k) for k in keys}
+    asked = 2 * len(rs)  # one resource and one subject a check
+    assert (after["intern.lookups"] - before["intern.lookups"]
+            + after["intern.memo_hits"] - before["intern.memo_hits"]) == asked
+    assert after["intern.memo_clears"] == before["intern.memo_clears"]
+    # shrink the memo under the batch's 21 distinct keys: it must clear
+    engine = c._engine
+    engine._intern_memo.clear()
+    engine.INTERN_MEMO_MAX = 4
+    try:
+        c.check(ctx, consistency.full(), *rs)
+    finally:
+        del engine.INTERN_MEMO_MAX
+    assert m.counter("intern.memo_clears") > after["intern.memo_clears"]
+    assert (m.counter("intern.lookups") - after["intern.lookups"]
+            + m.counter("intern.memo_hits") - after["intern.memo_hits"]) == asked
+
+
+def test_stage_cpu_time_never_exceeds_wall_time(batch_client):
+    """(f) ``<name>_cpu_s`` is read inside the wall stamps, while the
+    stage records: for every sample CPU <= wall, and a stage that sleeps
+    is mostly off-CPU."""
+    reg = metrics.Metrics()
+    with trace.stage("t.off", cpu=True, registry=reg):
+        pass
+    assert "t.off_cpu_s" not in reg._samples  # nothing records: no clock read
+    trace.configure(sample_rate=1.0, slow_threshold_s=None, capacity=4)
+    root = trace.root_span("t")
+    x = 0
+    for i in range(300):
+        with trace.stage("t.busy", root, cpu=True, registry=reg):
+            for j in range(i % 40):
+                x += j
+    with trace.stage("t.asleep", root, cpu=True, registry=reg):
+        time.sleep(0.02)
+    root.end()
+    wall, cpu = reg._samples["t.busy_s"], reg._samples["t.busy_cpu_s"]
+    assert len(wall) == len(cpu) == 300
+    assert all(c_ <= w for w, c_ in zip(wall, cpu))
+    assert reg._samples["t.asleep_cpu_s"][0] < 0.5 * reg._samples["t.asleep_s"][0]
+    # through the engine: the batch's lowering, summed over a few checks
+    c, ctx, rs = batch_client
+    snap0 = metrics.default.snapshot()
+    for _ in range(5):
+        c.check(ctx, consistency.full(), *rs)
+    snap1 = metrics.default.snapshot()
+    d = lambda k: snap1[k] - snap0.get(k, 0)
+    assert d("engine.lower_cpu_s.count") == d("engine.lower_s.count") == 5
+    assert d("engine.lower_cpu_s.total_s") <= d("engine.lower_s.total_s")
+
+
+def test_gc_timer_observes_full_collections_only():
+    """``host.gc_s`` gains one sample a generation-2 collection.  The
+    collector can start inside the registry's own lock, on the thread
+    holding it: then the callback must not block, and the sample reaches
+    the registry with the next stage that closes."""
+    import gc
+
+    new_tpu_evaluator()  # the first client installs the hook, once
+    assert gc.callbacks.count(trace._on_gc) == 1
+    new_tpu_evaluator()
+    assert gc.callbacks.count(trace._on_gc) == 1
+    count = lambda: _counts("host.gc_s")["host.gc_s"]
+    n0 = count()
+    gc.collect(0)
+    gc.collect(1)
+    assert count() == n0
+    gc.collect()
+    assert count() >= n0 + 1
+    n1 = count()
+    with metrics.default._lock:  # as if an allocation in observe() started it
+        gc.collect()  # returns: the callback did not wait for the lock
+        assert metrics.default._timings["host.gc_s"][0] == n1
+    with trace.stage("t.drain", registry=metrics.Metrics()):
+        pass
+    assert count() >= n1 + 1 and not trace._GC_PENDING
