@@ -691,19 +691,6 @@ class DeviceEngine:
         #: context-free qctx singletons (host + device forms)
         self._empty_qctx_np: Optional[Dict[str, np.ndarray]] = None
         self._empty_qctx_jnp = None
-        #: per-client string→node-id memo over the interner (bounded):
-        #: the interner's own dict spans EVERY node in the store, so a
-        #: lookup under zipf-skewed traffic thrashes CPU cache on a
-        #: structure ~10^6× larger than the hot working set — this map
-        #: holds just the hot keys.  Sound because node ids are append-
-        #: only and stable; MISSES are never memoized (an unknown object
-        #: can be interned by a later write, so -1 is not stable)
-        self._intern_memo: Dict[Tuple[str, str], int] = {}
-        self._intern_memo_src = None  # the Interner the memo is valid for
-
-    #: hot-key memo capacity; on overflow the map clears and re-warms
-    #: (zipf traffic repopulates the head in a few batches)
-    INTERN_MEMO_MAX = 1 << 16
 
     #: every per-edge/lookup column _host_arrays emits (the sharded engine
     #: derives its shard_map specs from this — keep in lockstep, enforced
@@ -1046,8 +1033,10 @@ class DeviceEngine:
     ) -> Tuple[Dict[str, np.ndarray], np.ndarray, Dict[str, np.ndarray]]:
         """Host lowering of one batch, Relationship objects to interned
         int32 query columns, as one ``engine.lower`` stage (wall, and
-        thread CPU while it records) with the ``intern.*`` counters of
-        the batch."""
+        thread CPU while it records).  The batch's node ids come from
+        one ``interner.lookup_pairs`` call: ``engine.intern_s`` is
+        observed around it, ``intern.lookups`` counts its 2·B keys and
+        ``intern.batch_calls`` the call."""
         with _trace.stage("engine.lower", span, cpu=True) as st:
             return self._lower(snap, rels, strings, st)
 
@@ -1057,9 +1046,7 @@ class DeviceEngine:
         slot_of = self.compiled.slot_of_name
         wc_of = snap.wildcard_node_of_type
 
-        q_res = np.full(B, -1, np.int32)
         q_perm = np.full(B, -1, np.int32)
-        q_subj = np.full(B, -1, np.int32)
         q_srel = np.full(B, -1, np.int32)
         q_wc = np.full(B, -1, np.int32)
         q_ctx = np.full(B, -1, np.int32)
@@ -1080,53 +1067,24 @@ class DeviceEngine:
                         ctx_rows.append(r.caveat_context)
                     q_ctx[i] = at
 
-        if self._intern_memo_src is not interner:
-            # memoized ids are only valid against the interner that
-            # assigned them — a snapshot from a different store resets
-            # the memo (id identity, not equality: interners only grow)
-            self._intern_memo = {}
-            self._intern_memo_src = interner
-        memo = self._intern_memo
-        memo_get = memo.get
-        lookup = interner.lookup
-        memo_hits = memo_clears = 0
-        memo_max = self.INTERN_MEMO_MAX
-
-        def node_of(tname: str, oid: str) -> int:
-            nonlocal memo_hits, memo_clears
-            k = (tname, oid)
-            v = memo_get(k)
-            if v is not None:
-                memo_hits += 1
-                return v
-            v = lookup(tname, oid)
-            if v >= 0:
-                if len(memo) >= memo_max:
-                    memo.clear()
-                    memo_clears += 1
-                memo[k] = v
-            return v
-
-        timed = st.recording  # a profiler session is live or the span sampled
-        if timed:
-            # node_of then reaches the interner through a timed wrapper:
-            # chosen once per batch, the loop below runs the same
-            # statements either way
-            clock = _time.perf_counter
-            intern_s = 0.0
-            untimed_lookup = lookup
-
-            def lookup(tname: str, oid: str) -> int:
-                nonlocal intern_s
-                t = clock()
-                v = untimed_lookup(tname, oid)
-                intern_s += clock() - t
-                return v
+        # every node id of the batch in ONE interner call (2·B keys):
+        # the loop below never reaches the interner
+        res_type = [r.resource_type for r in rels]
+        res_id = [r.resource_id for r in rels]
+        subj_type = [r.subject_type for r in rels]
+        subj_id = [r.subject_id for r in rels]
+        t0 = _time.perf_counter()
+        nodes, type_ids = interner.lookup_pairs(
+            res_type + subj_type, res_id + subj_id)
+        intern_s = _time.perf_counter() - t0
+        q_res, q_subj = nodes[:B], nodes[B:]
+        stid = type_ids[B:]
+        has_wc = (stid >= 0) & (stid < wc_of.shape[0]) & np.fromiter(
+            map(WILDCARD_ID.__ne__, subj_id), bool, B)
+        q_wc[has_wc] = wc_of[stid[has_wc]]
 
         for i, r in enumerate(rels):
-            q_res[i] = node_of(r.resource_type, r.resource_id)
             q_perm[i] = slot_of.get(r.resource_relation, -1)
-            q_subj[i] = node_of(r.subject_type, r.subject_id)
             if r.subject_relation:
                 srel = slot_of.get(r.subject_relation)
                 if srel is None:
@@ -1138,9 +1096,6 @@ class DeviceEngine:
                     q_srel[i] = srel
             else:
                 q_srel[i] = -1
-            stid = interner.type_lookup(r.subject_type)
-            if stid >= 0 and stid < wc_of.shape[0] and r.subject_id != WILDCARD_ID:
-                q_wc[i] = wc_of[stid]
             q_self[i] = (
                 r.resource_type == r.subject_type
                 and r.resource_id == r.subject_id
@@ -1149,16 +1104,10 @@ class DeviceEngine:
             )
 
         m = metrics.default
-        if memo_hits:
-            m.inc("intern.memo_hits", memo_hits)
-        # every node_of call either hit the memo or reached the interner
-        m.inc("intern.lookups", 2 * B - memo_hits)
-        if memo_clears:
-            m.inc("intern.memo_clears", memo_clears)
-        if timed:
-            m.observe("engine.intern_s", intern_s)
-            st.note(batch=B, intern_s=round(intern_s, 6),
-                    memo_hits=memo_hits)
+        m.inc("intern.lookups", 2 * B)
+        m.inc("intern.batch_calls")
+        m.observe("engine.intern_s", intern_s)
+        st.note(batch=B, intern_s=round(intern_s, 6))
         # unique (subject, query-context) rows for Phase A — context is part
         # of the key because caveat gates make closures context-dependent
         subj_key = np.stack([q_subj, q_srel, q_wc, q_ctx], axis=1)

@@ -82,12 +82,16 @@ class NativeInterner:
         return b"".join(bufs), offsets
 
     def _batch(self, fn, type_ids: np.ndarray, ids: Sequence[str]) -> np.ndarray:
-        buf, offsets = self._pack(ids)
-        out = np.empty(len(ids), np.int32)
+        return self._call(fn, type_ids, *self._pack(ids))
+
+    def _call(self, fn, type_ids: np.ndarray, buf: bytes,
+              offsets: np.ndarray) -> np.ndarray:
+        n = len(offsets) - 1
+        out = np.empty(n, np.int32)
         fn(
             self._h, buf,
             offsets.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)),
-            ctypes.c_int64(len(ids)),
+            ctypes.c_int64(n),
             np.ascontiguousarray(type_ids, np.int32).ctypes.data_as(
                 ctypes.POINTER(ctypes.c_int32)
             ),
@@ -233,15 +237,28 @@ class NativeInterner:
         with self._lock:
             return self._batch(self._lib.gi_intern_batch, type_ids, ids)
 
-    def lookup_batch(self, type_name: str, ids: Sequence[str]) -> np.ndarray:
+    def lookup_pairs(
+        self, type_names: Sequence[str], ids: Sequence[str]
+    ) -> Tuple[np.ndarray, np.ndarray]:
+        """Node ids of many (type name, id) pairs of mixed types, without
+        interning, in ONE native call: ``(nodes, type_ids)``, both
+        int32[n], -1 where the id or its type is unknown (see
+        store/interner.py).  The ids are packed outside the lock; it is
+        held for the type table and the call, which releases the GIL."""
+        if len(type_names) != len(ids):
+            raise ValueError("lookup_pairs: one type name per id")
+        buf, offsets = self._pack(ids)
+        names = set(type_names)
         with self._lock:
-            tid = self._types.get(type_name)
-            if tid is None:
-                return np.full(len(ids), -1, np.int32)
-            return self._batch(
-                self._lib.gi_lookup_batch,
-                np.full(len(ids), tid, np.int32), ids,
-            )
+            tid_of = {t: self._types.get(t, -1) for t in names}
+            tids = np.fromiter(
+                map(tid_of.__getitem__, type_names), np.int32, len(ids))
+            # an unknown type never reaches the C hash: its keys are
+            # looked up as type 0 and masked
+            nodes = self._call(
+                self._lib.gi_lookup_batch, np.maximum(tids, 0), buf, offsets)
+        nodes[tids < 0] = -1
+        return nodes, tids
 
 
 def make_interner():

@@ -70,6 +70,23 @@ class Interner:
         False, not an error, client/client_test.go:209-215)."""
         return self._node_of.get((type_name, object_id), -1)
 
+    def lookup_pairs(self, type_names, ids) -> Tuple[np.ndarray, np.ndarray]:
+        """``lookup`` of many (type name, id) pairs of mixed types at
+        once — the engine's one interner call a batch.  Returns
+        ``(nodes, type_ids)``, both int32[n]: the node id of each pair
+        and the interner type id of each pair's type name, -1 where
+        unknown.  Type names are resolved once per distinct name under
+        one take of the lock."""
+        if len(type_names) != len(ids):
+            raise ValueError("lookup_pairs: one type name per id")
+        with self._lock:
+            tid_of = {t: self._types.get(t, -1) for t in set(type_names)}
+        get = self._node_of.get
+        nodes = [get(k, -1) for k in zip(type_names, ids)]
+        return (np.asarray(nodes, np.int32),
+                np.fromiter(map(tid_of.__getitem__, type_names),
+                            np.int32, len(nodes)))
+
     def key_of(self, node: int) -> Tuple[str, str]:
         return self._keys[node]
 

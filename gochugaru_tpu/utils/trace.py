@@ -1130,8 +1130,8 @@ class stage:
     boundary-to-boundary interval (module docstring: three sinks plus
     the profiler, leaves only).  ``note(k=v)`` attaches metadata to the
     annotation and the child span, and is dropped when neither records;
-    ``recording`` says whether either does (the engine picks its timing
-    closure by it, once per batch)."""
+    ``recording`` says whether either does (the thread's CPU clock is
+    read only then)."""
 
     __slots__ = ("name", "span", "cpu", "wall", "registry",
                  "t0", "_c0", "_ann", "_meta")

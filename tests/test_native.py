@@ -45,7 +45,7 @@ def test_interner_batch_equivalence_and_growth():
     # re-interning returns identical ids; singles agree with batch
     assert (it.node_batch("user", ids[:1000]) == nodes[:1000]).all()
     assert it.node("user", "id500") == nodes[500]
-    found = it.lookup_batch("user", ["id0", "missing", "id199999"])
+    found, _ = it.lookup_pairs(["user"] * 3, ["id0", "missing", "id199999"])
     assert found[0] == nodes[0] and found[1] == -1 and found[2] == nodes[-1]
 
 
@@ -109,3 +109,67 @@ def test_store_uses_available_interner():
     s = Store()
     it = make_interner()
     assert type(s.interner) is type(it)
+
+
+def _interner(kind):
+    from gochugaru_tpu.native.interner import NativeInterner
+    from gochugaru_tpu.store.interner import Interner
+
+    it = NativeInterner() if kind == "native" else Interner()
+    for t, i in [("user", "alice"), ("user", "bob"), ("doc", "alice"),
+                 ("team", "eng"), ("doc", ""), ("user", "ünïcode-οκ"),
+                 ("user", "*"), ("doc", "d1")]:
+        it.node(t, i)
+    it.type_id("empty_type")  # a type with no node
+    return it
+
+
+LOOKUP_PAIRS_CASES = {
+    "mixed_types": [("doc", "alice"), ("user", "alice"), ("team", "eng"),
+                    ("user", "bob"), ("doc", "d1"), ("user", "alice")],
+    "unknown_type": [("ghost", "alice"), ("user", "alice"), ("ghost", ""),
+                     ("empty_type", "alice")],
+    "unknown_id": [("user", "nope"), ("doc", "bob"), ("user", "bob"),
+                   ("team", "alice")],
+    "empty_id": [("doc", ""), ("user", ""), ("user", "bob")],
+    "non_ascii_id": [("user", "ünïcode-οκ"), ("doc", "ünïcode-οκ"),
+                     ("user", "alice"), ("user", "ünïcode")],
+    "wildcard_id": [("user", "*"), ("doc", "*"), ("ghost", "*")],
+    "empty_batch": [],
+}
+
+
+@pytest.mark.parametrize("case", sorted(LOOKUP_PAIRS_CASES))
+@pytest.mark.parametrize("kind", ["native", "python"])
+def test_lookup_pairs_equals_per_key_lookup(kind, case):
+    """One call over mixed types answers what per-key ``lookup`` and
+    ``type_lookup`` answer, key for key."""
+    it = _interner(kind)
+    pairs = LOOKUP_PAIRS_CASES[case]
+    types, ids = [t for t, _ in pairs], [i for _, i in pairs]
+    n0 = len(it)
+    nodes, type_ids = it.lookup_pairs(types, ids)
+    assert nodes.dtype == np.int32 and type_ids.dtype == np.int32
+    assert nodes.shape == type_ids.shape == (len(pairs),)
+    assert nodes.tolist() == [it.lookup(t, i) for t, i in pairs]
+    assert type_ids.tolist() == [it.type_lookup(t) for t in types]
+    assert len(it) == n0 and it.type_lookup("ghost") == -1  # interned nothing
+    with pytest.raises(ValueError):
+        it.lookup_pairs(types + ["user"], ids)
+
+
+def test_lookup_pairs_native_matches_python_on_random_pairs():
+    from gochugaru_tpu.native.interner import NativeInterner
+    from gochugaru_tpu.store.interner import Interner
+
+    rng = np.random.default_rng(11)
+    names = ["user", "doc", "team", "folder", "ghost"]
+    nat, ref = NativeInterner(), Interner()
+    for t, i in zip(rng.integers(0, 4, 5_000), rng.integers(0, 3_000, 5_000)):
+        assert nat.node(names[t], f"o{i}") == ref.node(names[t], f"o{i}")
+    types = [names[t] for t in rng.integers(0, 5, 10_000)]
+    ids = [f"o{i}" for i in rng.integers(0, 4_000, 10_000)]
+    got, got_t = nat.lookup_pairs(types, ids)
+    want, want_t = ref.lookup_pairs(types, ids)
+    assert (got == want).all() and (got_t == want_t).all()
+    assert (got >= 0).any() and (got < 0).any()

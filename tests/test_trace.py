@@ -349,8 +349,9 @@ def test_stages_off_path_builds_no_span_and_no_annotation(
     c, ctx, rs = batch_client
     _CountingAnnotation.built, _CountingAnnotation.live = [], False
     monkeypatch.setattr(trace, "_ANNOTATION", _CountingAnnotation)
-    timers = [f"{s}_s" for s in BATCH_STAGES]
-    recorded = ("engine.intern_s", "engine.lower_cpu_s")
+    # the batch's one interner call is timed always, like a stage
+    timers = [f"{s}_s" for s in BATCH_STAGES] + ["engine.intern_s"]
+    recorded = ("engine.lower_cpu_s",)
     before, n0 = _counts(*timers, *recorded), trace.spans_created()
     assert c.check(ctx, consistency.full(), *rs) == [True] * 8
     after = _counts(*timers, *recorded)
@@ -358,8 +359,8 @@ def test_stages_off_path_builds_no_span_and_no_annotation(
     assert _CountingAnnotation.built == []
     for t in timers:
         assert after[t] == before[t] + 1, t
-    # the interner and the thread's CPU clock (a system call) are read
-    # only while something records
+    # the thread's CPU clock (a system call) is read only while
+    # something records
     for t in recorded:
         assert after[t] == before[t], t
     # ... and with a session live each stage holds exactly one annotation
@@ -367,27 +368,32 @@ def test_stages_off_path_builds_no_span_and_no_annotation(
     assert c.check(ctx, consistency.full(), *rs) == [True] * 8
     mine = [f"gochugaru.{s}" for s in BATCH_STAGES]  # idle serving threads
     assert [n for n in _CountingAnnotation.built if n in mine] == mine  # may add theirs
-    live = _counts(*recorded)
-    for t in recorded:
+    live = _counts("engine.intern_s", *recorded)
+    for t in ("engine.intern_s", *recorded):
         assert live[t] == after[t] + 1, t
 
 
 def test_stages_land_in_the_profilers_trace_as_leaves(batch_client, tmp_path):
     """(b) Under any jax.profiler session the stages are events of the
     same .xplane.pb, none encloses another on its thread, and the time
-    inside the interner is observed — outside the session it is not."""
+    inside the interner is observed once a batch — inside the session
+    and outside it alike."""
     import jax
 
     c, ctx, rs = batch_client
-    before = _counts("engine.intern_s")
+    before = _counts("engine.intern_s", "engine.lower_s")
     with jax.profiler.trace(str(tmp_path)):
         assert c.check(ctx, consistency.full(), *rs) == [True] * 8
         with c.with_serving(cs=consistency.full()) as h:
             assert h.check(ctx, *rs) == [True] * 8
-    inside = _counts("engine.intern_s")
-    assert inside["engine.intern_s"] >= before["engine.intern_s"] + 2
+    inside = _counts("engine.intern_s", "engine.lower_s")
+    assert inside["engine.intern_s"] == before["engine.intern_s"] + 2
     assert c.check(ctx, consistency.full(), *rs) == [True] * 8
-    assert _counts("engine.intern_s") == inside
+    outside = _counts("engine.intern_s", "engine.lower_s")
+    assert outside["engine.intern_s"] == inside["engine.intern_s"] + 1
+    # one observation a lowered batch, whoever records
+    assert (outside["engine.intern_s"] - before["engine.intern_s"]
+            == outside["engine.lower_s"] - before["engine.lower_s"])
     events = profiled_stage_events(tmp_path)
     names = {e[1] for e in events}
     want = {f"gochugaru.{s}" for s in BATCH_STAGES} | {
@@ -436,38 +442,32 @@ def test_sampled_batch_path_stage_spans_equal_timer_samples(batch_client):
         assert any(abs(v - sp["dur_s"]) < 1e-9 for v in ring), s
         parent = dev if s.startswith("engine.") else by["dispatch"][0]
         assert sp["parent_id"] == parent["span_id"], s
-    # a sampled span is reason enough to time the interner
+    # the interner's one observation of the batch is the span's attribute
     assert _counts("engine.intern_s")["engine.intern_s"] == (
         before["engine.intern_s"] + 1)
-    assert "intern_s" in by["engine.lower"][0]["attrs"]
+    attrs = by["engine.lower"][0]["attrs"]
+    assert attrs["batch"] == 8 and "memo_hits" not in attrs
+    ring = metrics.default._samples["engine.intern_s"]
+    assert any(abs(v - attrs["intern_s"]) < 1e-6 for v in ring)
 
 
-def test_intern_counters_account_for_every_node_lookup(batch_client):
-    """(e) Every node look-up of a batch either hit the engine's memo or
-    reached the interner; the memo being emptied is counted."""
+@pytest.mark.parametrize("n_checks", [1, 40, 300])
+def test_intern_counters_one_native_call_a_batch(batch_client, n_checks):
+    """(e) A batch of B checks reaches the interner once, with 2·B keys
+    (one resource and one subject a check): ``intern.batch_calls`` moves
+    by 1 and ``intern.lookups`` by 2·B, so keys per call is B-scale."""
     c, ctx, _ = batch_client
     m = metrics.default
-    rs = [rel.must_from_triple(f"doc:d{i % 16}", "read", f"user:u{i % 5}")
-          for i in range(40)]
-    keys = ("intern.lookups", "intern.memo_hits", "intern.memo_clears")
+    rs = [rel.must_from_triple(f"doc:d{i % 16}", "read", f"user:x{i}")
+          for i in range(n_checks)]
+    keys = ("intern.lookups", "intern.batch_calls")
     before = {k: m.counter(k) for k in keys}
     c.check(ctx, consistency.full(), *rs)
     after = {k: m.counter(k) for k in keys}
-    asked = 2 * len(rs)  # one resource and one subject a check
-    assert (after["intern.lookups"] - before["intern.lookups"]
-            + after["intern.memo_hits"] - before["intern.memo_hits"]) == asked
-    assert after["intern.memo_clears"] == before["intern.memo_clears"]
-    # shrink the memo under the batch's 21 distinct keys: it must clear
-    engine = c._engine
-    engine._intern_memo.clear()
-    engine.INTERN_MEMO_MAX = 4
-    try:
-        c.check(ctx, consistency.full(), *rs)
-    finally:
-        del engine.INTERN_MEMO_MAX
-    assert m.counter("intern.memo_clears") > after["intern.memo_clears"]
-    assert (m.counter("intern.lookups") - after["intern.lookups"]
-            + m.counter("intern.memo_hits") - after["intern.memo_hits"]) == asked
+    assert after["intern.batch_calls"] - before["intern.batch_calls"] == 1
+    assert after["intern.lookups"] - before["intern.lookups"] == 2 * n_checks
+    snap = m.snapshot()
+    assert not [k for k in snap if "memo" in k], "the memo's counters are gone"
 
 
 def test_stage_cpu_time_never_exceeds_wall_time(batch_client):
