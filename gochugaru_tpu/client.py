@@ -242,8 +242,11 @@ def with_verdict_cache(cache=True) -> Option:
     a byte-bounded LRU, and the consistency strategy of each call is the
     read policy — ``snapshot``/``at_least`` hit the resolved revision's
     shard, ``min_latency`` the freshest resident one, ``full`` bypasses
-    entirely.  Caveated verdicts that read live query context are never
-    cached; time-gated verdicts cache with a pinned now_us.
+    entirely.  Over budget the least recently used REVISION goes first;
+    inside the one revision that is left, the entries not read since the
+    generation before last was opened go, whole, and an entry that keeps
+    being read stays.  Caveated verdicts that read live query context
+    are never cached; time-gated verdicts cache with a pinned now_us.
 
     ``cache`` may be ``True`` (default 64 MB cache), an int byte budget,
     or a prebuilt ``VerdictCache`` (shared between clients)."""
@@ -861,27 +864,50 @@ class Client:
             )
             return out
 
-        B = len(rels)
-        keys = [_vcache.rel_key(r) for r in rels]
-        # live-context items (non-empty query caveat_context) bypass the
-        # cache entirely — their caveat may read the live context
-        cacheable = [k[1] == _vcache.EMPTY_CTX_FP for k in keys]
-        out: List[Optional[bool]] = [None] * B
-        now_us = int(_time.time() * 1_000_000)
-        if pol.read:
-            vals = vc.lookup_rels(
-                snap.revision,
-                [k if cacheable[i] else None for i, k in enumerate(keys)],
-            )
-            for i, v in enumerate(vals):
-                if v is not None:
-                    out[i] = v[0]
-        pend = [i for i in range(B) if out[i] is None]
-        hitflags = [out[i] is not None for i in range(B)]
-        nh = B - len(pend)
-        if nh:
-            span.event("cache.hits", items=nh)
-            span.set_attr("cache_hits", nh)
+        # the cache layer is two stages around the direct evaluation
+        # (which has its own): ``client.cache_read`` — keys, look-up,
+        # pending list, dedup map — and ``client.cache_write`` — fan-out,
+        # insert and whatever eviction it triggers
+        with _trace.stage("client.cache_read", span):
+            B = len(rels)
+            keys = [_vcache.rel_key(r) for r in rels]
+            # live-context items (non-empty query caveat_context) bypass
+            # the cache entirely — their caveat may read the live context
+            cacheable = [k[1] == _vcache.EMPTY_CTX_FP for k in keys]
+            out: List[Optional[bool]] = [None] * B
+            now_us = int(_time.time() * 1_000_000)
+            if pol.read:
+                vals = vc.lookup_rels(
+                    snap.revision,
+                    [k if cacheable[i] else None
+                     for i, k in enumerate(keys)],
+                )
+                for i, v in enumerate(vals):
+                    if v is not None:
+                        out[i] = v[0]
+            pend = [i for i in range(B) if out[i] is None]
+            hitflags = [out[i] is not None for i in range(B)]
+            nh = B - len(pend)
+            if nh:
+                span.event("cache.hits", items=nh)
+                span.set_attr("cache_hits", nh)
+            if dedup and len(pend) > 1:
+                first: Dict[Any, int] = {}
+                uidx: List[int] = []
+                inverse: List[int] = []
+                for i in pend:
+                    u = first.get(keys[i])
+                    if u is None:
+                        u = first[keys[i]] = len(uidx)
+                        uidx.append(i)
+                    inverse.append(u)
+                dups = len(pend) - len(uidx)
+                if dups:
+                    self._metrics.inc("dedup.batch_dups", dups)
+            else:
+                uidx = pend
+                inverse = list(range(len(pend)))
+            todo = [rels[i] for i in uidx]
         if not pend:
             res = [bool(v) for v in out]
             self._provenance_rels(
@@ -889,40 +915,25 @@ class Client:
                 _time.perf_counter() - t_ev, span,
             )
             return res
-        if dedup and len(pend) > 1:
-            first: Dict[Any, int] = {}
-            uidx: List[int] = []
-            inverse: List[int] = []
-            for i in pend:
-                u = first.get(keys[i])
-                if u is None:
-                    u = first[keys[i]] = len(uidx)
-                    uidx.append(i)
-                inverse.append(u)
-            dups = len(pend) - len(uidx)
-            if dups:
-                self._metrics.inc("dedup.batch_dups", dups)
-        else:
-            uidx = pend
-            inverse = list(range(len(pend)))
         try:
             sub = self._evaluate_rels_direct(
-                snap, [rels[i] for i in uidx], latency=latency, span=span
+                snap, todo, latency=latency, span=span
             )
         except BulkCheckItemError as e:
             raise self._remap_bulk_error(
                 e, out, pend, inverse, lambda vs: list(vs)
             ) from (e.__cause__ or e)
-        for j, i in enumerate(pend):
-            out[i] = bool(sub[inverse[j]])
-        if pol.write:
-            vc.insert_rels(
-                snap.revision,
-                [(keys[i], sub[j]) for j, i in enumerate(uidx)
-                 if cacheable[i]],
-                now_us,
-            )
-        res = [bool(v) for v in out]
+        with _trace.stage("client.cache_write", span):
+            for j, i in enumerate(pend):
+                out[i] = bool(sub[inverse[j]])
+            if pol.write:
+                vc.insert_rels(
+                    snap.revision,
+                    [(keys[i], sub[j]) for j, i in enumerate(uidx)
+                     if cacheable[i]],
+                    now_us,
+                )
+            res = [bool(v) for v in out]
         self._provenance_rels(
             rels, res, snap, cs, hitflags, _time.perf_counter() - t_ev, span
         )
@@ -1131,58 +1142,62 @@ class Client:
             )
             return out
 
-        B = int(q_res.shape[0])
-        keys = _vcache.pack_cols(q_perm, q_res, q_subj)
-        res = np.zeros(B, bool)
-        resolved = np.zeros(B, bool)
-        now_us = int(_time.time() * 1_000_000)
-        if pol.read:
-            arr = vc.lookup_cols(snap.revision, keys)
-            if arr is not None:
-                resolved = arr >= 0
-                res = (arr & 1).astype(bool)
-                res[~resolved] = False
-        pend = np.nonzero(~resolved)[0]
-        nh = B - int(pend.shape[0])
-        if nh:
-            span.event("cache.hits", items=nh)
-            span.set_attr("cache_hits", nh)
+        # the same two stages as ``_evaluate_rels``
+        with _trace.stage("client.cache_read", span):
+            B = int(q_res.shape[0])
+            keys = _vcache.pack_cols(q_perm, q_res, q_subj)
+            res = np.zeros(B, bool)
+            resolved = np.zeros(B, bool)
+            now_us = int(_time.time() * 1_000_000)
+            if pol.read:
+                arr = vc.lookup_cols(snap.revision, keys)
+                if arr is not None:
+                    resolved = arr >= 0
+                    res = (arr & 1).astype(bool)
+                    res[~resolved] = False
+            pend = np.nonzero(~resolved)[0]
+            nh = B - int(pend.shape[0])
+            if nh:
+                span.event("cache.hits", items=nh)
+                span.set_attr("cache_hits", nh)
+            if dedup and pend.shape[0] > 1:
+                if isinstance(keys, np.ndarray):
+                    _, uix, inverse = np.unique(
+                        keys[pend], return_index=True, return_inverse=True
+                    )
+                    uidx = pend[uix]
+                else:
+                    first: Dict[Any, int] = {}
+                    ulist: List[int] = []
+                    inverse = np.empty(pend.shape[0], np.int64)
+                    for j, i in enumerate(pend):
+                        k = keys[i]
+                        u = first.get(k)
+                        if u is None:
+                            u = first[k] = len(ulist)
+                            ulist.append(int(i))
+                        inverse[j] = u
+                    uidx = np.asarray(ulist, np.int64)
+                dups = int(pend.shape[0] - uidx.shape[0])
+                if dups:
+                    self._metrics.inc("dedup.batch_dups", dups)
+            else:
+                uidx = pend
+                inverse = np.arange(pend.shape[0])
+            todo = (
+                np.ascontiguousarray(q_res[uidx]),
+                np.ascontiguousarray(q_perm[uidx]),
+                np.ascontiguousarray(q_subj[uidx]),
+            )
         if pend.shape[0] == 0:
             self._provenance_cols(
                 snap, q_res, q_perm, q_subj, res, cs, resolved,
                 _time.perf_counter() - t_ev, span,
             )
             return res
-        if dedup and pend.shape[0] > 1:
-            if isinstance(keys, np.ndarray):
-                _, uix, inverse = np.unique(
-                    keys[pend], return_index=True, return_inverse=True
-                )
-                uidx = pend[uix]
-            else:
-                first: Dict[Any, int] = {}
-                ulist: List[int] = []
-                inverse = np.empty(pend.shape[0], np.int64)
-                for j, i in enumerate(pend):
-                    k = keys[i]
-                    u = first.get(k)
-                    if u is None:
-                        u = first[k] = len(ulist)
-                        ulist.append(int(i))
-                    inverse[j] = u
-                uidx = np.asarray(ulist, np.int64)
-            dups = int(pend.shape[0] - uidx.shape[0])
-            if dups:
-                self._metrics.inc("dedup.batch_dups", dups)
-        else:
-            uidx = pend
-            inverse = np.arange(pend.shape[0])
         try:
             sub = self._evaluate_columns_direct(
-                snap, np.ascontiguousarray(q_res[uidx]),
-                np.ascontiguousarray(q_perm[uidx]),
-                np.ascontiguousarray(q_subj[uidx]),
-                latency=latency, span=span,
+                snap, *todo, latency=latency, span=span,
             )
         except BulkCheckItemError as e:
             # unique-space → caller-space: scatter the resolved unique
@@ -1196,12 +1211,15 @@ class Client:
             raise BulkCheckItemError(
                 first_bad, res[:first_bad], e.__cause__ or e
             ) from (e.__cause__ or e)
-        res[pend] = np.asarray(sub, bool)[inverse]
-        if pol.write:
-            ku = keys[uidx] if isinstance(keys, np.ndarray) else [
-                keys[int(i)] for i in uidx
-            ]
-            vc.insert_cols(snap.revision, ku, np.asarray(sub, bool), now_us)
+        with _trace.stage("client.cache_write", span):
+            res[pend] = np.asarray(sub, bool)[inverse]
+            if pol.write:
+                ku = keys[uidx] if isinstance(keys, np.ndarray) else [
+                    keys[int(i)] for i in uidx
+                ]
+                vc.insert_cols(
+                    snap.revision, ku, np.asarray(sub, bool), now_us
+                )
         self._provenance_cols(
             snap, q_res, q_perm, q_subj, res, cs, resolved,
             _time.perf_counter() - t_ev, span,

@@ -9,7 +9,9 @@ graph.  This module supplies both halves:
 **VerdictCache** — definite check verdicts keyed on (snapshot revision,
 permission slot, resource id, subject id, query-context fingerprint)
 under a byte-bounded LRU whose eviction granularity is a whole revision
-shard.  Revision keying makes invalidation *structural*: a write mints a
+shard — and, inside the one revision a read-only deployment lives in, a
+whole *generation* of that shard (``VerdictCache``).  Revision keying
+makes invalidation *structural*: a write mints a
 new revision, so a fresh snapshot simply opens a fresh keyspace — there
 is no invalidation protocol to get wrong, and a pinned ``Snapshot``
 reader keeps hitting its own revision's shard for as long as it stays
@@ -51,6 +53,7 @@ parity straight through it.
 
 from __future__ import annotations
 
+import itertools
 import threading
 from collections import OrderedDict
 from typing import (
@@ -151,6 +154,13 @@ def keys_list(keys) -> list:
     return keys.tolist() if isinstance(keys, np.ndarray) else keys
 
 
+def _take(keys, at: np.ndarray):
+    """``keys[at]`` for either form of pack_cols output."""
+    if isinstance(keys, np.ndarray):
+        return keys[at]
+    return [keys[i] for i in at.tolist()]
+
+
 # ---------------------------------------------------------------------------
 # Read policy (consistency.py strategies → cache behavior)
 # ---------------------------------------------------------------------------
@@ -215,12 +225,43 @@ class _ColShard:
     def __len__(self) -> int:
         return self.snap[0].shape[0] + len(self.extra)
 
-    def maybe_rebuild(self) -> None:
+    def probe(self, keys) -> np.ndarray:
+        """Encoded entries of ``keys`` (pack_cols output), -1 at misses:
+        the sorted snapshot by searchsorted, the residue in ``extra``."""
+        n = len(keys)
+        if isinstance(keys, np.ndarray) and not self.tuple_mode:
+            out = np.full(n, -1, np.int64)
+            ck, cv = self.snap  # ONE attribute read → never a torn pair
+            if ck.shape[0]:
+                pos = np.minimum(
+                    np.searchsorted(ck, keys), ck.shape[0] - 1
+                )
+                hit = ck[pos] == keys
+                out[hit] = cv[pos[hit]]
+            if self.extra:
+                miss = np.nonzero(out < 0)[0]
+                if miss.size:
+                    out[miss] = np.fromiter(
+                        map(self.extra.get, keys[miss].tolist(),
+                            itertools.repeat(-1)),
+                        np.int64, count=miss.size,
+                    )
+            return out
+        return np.fromiter(
+            map(self.extra.get, keys_list(keys), itertools.repeat(-1)),
+            np.int64, count=n,
+        )
+
+    def maybe_rebuild(self) -> int:
+        """Merge ``extra`` into the snapshot once it has outgrown it.
+        Returns the entries the merge dropped as duplicates of snapshot
+        keys (racing inserters), so the caller's count stays exact."""
         keys, vals = self.snap
         if self.tuple_mode or len(self.extra) <= max(
             self.REBUILD_MIN, keys.shape[0] // 4
         ):
-            return
+            return 0
+        held = len(self)
         ne = len(self.extra)
         ek = np.fromiter(self.extra.keys(), np.int64, count=ne)
         ev = np.fromiter(self.extra.values(), np.int64, count=ne)
@@ -235,6 +276,7 @@ class _ColShard:
             allk, allv = allk[keep], allv[keep]
         self.snap = (allk, allv)  # one atomic publish
         self.extra = {}
+        return held - int(allk.shape[0])
 
 
 class VerdictCache:
@@ -247,11 +289,29 @@ class VerdictCache:
     first, so a pinned Snapshot reader's shard stays warm under head
     writes for as long as its reads keep refreshing it.
 
-    Thread-safety: mutation is locked; bulk lookups read the shard's
-    snapshot arrays and dicts lock-free (arrays are replaced wholesale,
-    never mutated; CPython dict gets are safe against concurrent
-    inserts; eviction drops whole shard objects) — the same discipline
-    as ``Interner.keys_batch``."""
+    Inside the one revision that is left (a read-only deployment never
+    has another) a shard holds two **generations** of each kind of
+    entry: inserts go to the young one, a look-up that finds its key
+    only in the old one promotes it to the young one, and when the
+    budget is crossed the old generation is dropped WHOLE and the young
+    one takes its place.  So what goes first inside one revision is what
+    has not been read since the generation before last was opened; an
+    entry that keeps being read is promoted in every generation and
+    never goes, and an eviction's work is the entries it drops, not the
+    entries the shard holds (``rotations`` in ``stats()`` counts the
+    generations retired, ``cache.evicted_entries`` the entries in them).
+    A relationship entry moves on promotion; a columnar entry is copied
+    (the old generation's sorted arrays are never mutated) and counts in
+    both generations until the old one goes.  The byte and entry counts
+    are exactly what the generations hold, under the lock.
+
+    Thread-safety: mutation is locked; bulk lookups PROBE lock-free
+    (arrays are replaced wholesale, never mutated; CPython dict gets are
+    safe against concurrent inserts; eviction drops whole generation
+    objects) — the same discipline as ``Interner.keys_batch`` — and take
+    the lock once more, briefly, only when a call has old-generation
+    hits to promote.  A probe racing a promotion or a rotation can see a
+    spurious miss (the row re-dispatches), never a wrong hit."""
 
     #: rough per-entry cost estimates driving the byte bound (key +
     #: value tuple + dict slot overhead)
@@ -270,9 +330,11 @@ class VerdictCache:
         self._m = registry or _metrics.default
         self._lock = threading.Lock()
         #: revision → {"c": _ColShard, "r": {rel_key: (bool, now_us)}}
+        #: (the young generation) + {"c_old", "r_old"} (the old one)
         self._revs: "OrderedDict[int, Dict[str, Any]]" = OrderedDict()
         self._bytes = 0
         self._entries = 0
+        self._rotations = 0
         if self._m is _metrics.default:
             # /perf carries the cache's state next to the cost ledger
             # (last-created cache per process wins — the common shape
@@ -282,6 +344,10 @@ class VerdictCache:
             _perf.register_report_section("vcache", self.stats)
 
     # -- internals -------------------------------------------------------
+    @staticmethod
+    def _new_shard() -> Dict[str, Any]:
+        return {"c": _ColShard(), "r": {}, "c_old": _ColShard(), "r_old": {}}
+
     def _shard(self, revision: int, create: bool):
         with self._lock:
             sh = self._revs.get(revision)
@@ -290,8 +356,7 @@ class VerdictCache:
                 return sh
             if not create:
                 return None
-            sh = {"c": _ColShard(), "r": {}}
-            self._revs[revision] = sh
+            sh = self._revs[revision] = self._new_shard()
             self._evict_locked()
             self._publish_locked()
             return sh
@@ -302,36 +367,49 @@ class VerdictCache:
         ):
             _, sh = self._revs.popitem(last=False)
             self._bytes -= self._shard_bytes(sh)
-            self._entries -= len(sh["c"]) + len(sh["r"])
+            self._entries -= self._shard_entries(sh)
             self._m.inc("cache.evicted_revisions")
-        if self._bytes > self.max_bytes and self._revs:
-            # a single over-budget shard: shed half its columnar
-            # snapshot (arrays replaced wholesale — concurrent readers
-            # keep their reference) and its oldest rel entries
-            sh = next(iter(self._revs.values()))
-            c = sh["c"]
-            ck, cv = c.snap
-            drop = len(c.extra) + ck.shape[0] // 2
-            if drop:
-                c.extra = {}
-                half = ck.shape[0] // 2
-                c.snap = (  # one atomic publish — see _ColShard
-                    np.ascontiguousarray(ck[half:]),
-                    np.ascontiguousarray(cv[half:]),
-                )
-                self._bytes -= drop * self.COL_ENTRY_BYTES
-                self._entries -= drop
-            d = sh["r"]
-            it = iter(list(d))
-            while self._bytes > self.max_bytes and d:
-                d.pop(next(it), None)
-                self._bytes -= self.REL_ENTRY_BYTES
-                self._entries -= 1
+        if not self._revs:
+            return
+        # inside the freshest revision (over budget it is the only one
+        # left): retire a generation when the budget is crossed, and open
+        # the second one at half of it — with no old generation to drop,
+        # the first crossing would otherwise drop everything.  The second
+        # turn only runs for what alone exceeds the budget: a batch
+        # larger than the cache, or a budget the tuner shrank
+        sh = next(reversed(self._revs.values()))
+        if self._bytes > self.max_bytes or (
+            not (len(sh["c_old"]) or sh["r_old"])
+            and self._shard_bytes(sh) > self.max_bytes // 2
+        ):
+            self._rotate_locked(sh)
+            if self._bytes > self.max_bytes:
+                self._rotate_locked(sh)
+
+    def _rotate_locked(self, sh) -> None:
+        """Drop the old generation whole; the young one becomes the old.
+        New objects are published, none is mutated: a reader keeps the
+        generation it fetched."""
+        nc, nr = len(sh["c_old"]), len(sh["r_old"])
+        self._bytes -= nc * self.COL_ENTRY_BYTES + nr * self.REL_ENTRY_BYTES
+        self._entries -= nc + nr
+        if nc + nr:
+            self._m.inc("cache.evicted_entries", nc + nr)
+        # old first: a reader probes young then old, so between the two
+        # stores it finds the retiring young generation under both names
+        sh["c_old"], sh["r_old"] = sh["c"], sh["r"]
+        sh["c"], sh["r"] = _ColShard(), {}
+        self._rotations += 1
 
     @classmethod
     def _shard_bytes(cls, sh) -> int:
-        return (len(sh["c"]) * cls.COL_ENTRY_BYTES
-                + len(sh["r"]) * cls.REL_ENTRY_BYTES)
+        return ((len(sh["c"]) + len(sh["c_old"])) * cls.COL_ENTRY_BYTES
+                + (len(sh["r"]) + len(sh["r_old"])) * cls.REL_ENTRY_BYTES)
+
+    @staticmethod
+    def _shard_entries(sh) -> int:
+        return (len(sh["c"]) + len(sh["c_old"])
+                + len(sh["r"]) + len(sh["r_old"]))
 
     def _publish_locked(self) -> None:
         self._m.set_gauge("cache.bytes", self._bytes)
@@ -356,33 +434,22 @@ class VerdictCache:
         if sh is None:
             self._m.inc("cache.misses", n)
             return None
-        c = sh["c"]
-        if isinstance(keys, np.ndarray) and not c.tuple_mode:
-            out = np.full(n, -1, np.int64)
-            ck, cv = c.snap  # ONE attribute read → never a torn pair
-            if ck.shape[0]:
-                pos = np.minimum(
-                    np.searchsorted(ck, keys), ck.shape[0] - 1
-                )
-                hit = ck[pos] == keys
-                out[hit] = cv[pos[hit]]
-            if c.extra:
-                miss = np.nonzero(out < 0)[0]
-                if miss.size:
-                    import itertools
-
-                    out[miss] = np.fromiter(
-                        map(c.extra.get, keys[miss].tolist(),
-                            itertools.repeat(-1)),
-                        np.int64, count=miss.size,
-                    )
-        else:
-            import itertools
-
-            out = np.fromiter(
-                map(c.extra.get, keys_list(keys), itertools.repeat(-1)),
-                np.int64, count=n,
-            )
+        out = sh["c"].probe(keys)
+        old = sh["c_old"]
+        if len(old):
+            miss = np.nonzero(out < 0)[0]
+            if miss.size:
+                got = old.probe(_take(keys, miss))
+                found = miss[got >= 0]
+                if found.size:
+                    out[found] = got[got >= 0]
+                    with self._lock:
+                        # promoted with the entry's own pinned now_us
+                        if self._revs.get(revision) is sh:
+                            self._put_cols_locked(
+                                sh, keys_list(_take(keys, found)),
+                                out[found].tolist(),
+                            )
         nh = int((out >= 0).sum())
         if nh:
             self._m.inc("cache.hits", nh)
@@ -396,16 +463,12 @@ class VerdictCache:
         sh = self._shard(revision, create=False)
         if sh is None:
             return None
-        c = sh["c"]
-        v = c.extra.get(key)
-        ck, cv = c.snap
-        if v is None and isinstance(key, int) and ck.shape[0]:
-            p = int(np.searchsorted(ck, key))
-            if p < ck.shape[0] and int(ck[p]) == key:
-                v = int(cv[p])
-        if v is None:
-            return None
-        return (bool(v & 1), v >> 1)
+        one = np.array([key], np.int64) if isinstance(key, int) else [key]
+        for c in (sh["c"], sh["c_old"]):
+            v = int(c.probe(one)[0])
+            if v >= 0:
+                return (bool(v & 1), v >> 1)
+        return None
 
     def _shard_for_insert_locked(self, revision: int):
         """Resolve-or-create the shard UNDER the already-held lock: a
@@ -414,11 +477,31 @@ class VerdictCache:
         into an orphan no eviction can ever reclaim."""
         sh = self._revs.get(revision)
         if sh is None:
-            sh = {"c": _ColShard(), "r": {}}
-            self._revs[revision] = sh
+            sh = self._revs[revision] = self._new_shard()
         else:
             self._revs.move_to_end(revision)
         return sh
+
+    def _put_cols_locked(self, sh, kl: list, encoded) -> int:
+        """``key → encoded entry`` into the shard's young generation, for
+        the keys it does not hold; the counts, the eviction it triggers
+        and the gauges.  Returns the entries that were new."""
+        c = sh["c"]
+        if kl and not isinstance(kl[0], int):
+            c.tuple_mode = True
+        d = c.extra
+        before = len(d)
+        for k, v in zip(kl, encoded):
+            if k not in d:
+                d[k] = v
+        new = len(d) - before
+        if new:
+            held = new - c.maybe_rebuild()
+            self._bytes += held * self.COL_ENTRY_BYTES
+            self._entries += held
+            self._evict_locked()
+            self._publish_locked()
+        return new
 
     def insert_cols(self, revision: int, keys, verdicts, now_us: int) -> None:
         """Insert verdicts for packed columnar keys (all cacheable: the
@@ -430,22 +513,12 @@ class VerdictCache:
         enc_t = (int(now_us) << 1) | 1
         enc_f = int(now_us) << 1
         with self._lock:
-            c = self._shard_for_insert_locked(revision)["c"]
-            if kl and not isinstance(kl[0], int):
-                c.tuple_mode = True
-            before = len(c.extra)
-            d = c.extra
-            for k, v in zip(kl, verdicts):
-                if k not in d:
-                    d[k] = enc_t if v else enc_f
-            new = len(d) - before
-            if new:
-                c.maybe_rebuild()
-                self._bytes += new * self.COL_ENTRY_BYTES
-                self._entries += new
-                self._m.inc("cache.puts", new)
-                self._evict_locked()
-                self._publish_locked()
+            new = self._put_cols_locked(
+                self._shard_for_insert_locked(revision), kl,
+                [enc_t if v else enc_f for v in verdicts],
+            )
+        if new:
+            self._m.inc("cache.puts", new)
 
     # -- relationship surface --------------------------------------------
     def lookup_rels(self, revision: int, keys: Sequence[Optional[tuple]]):
@@ -462,6 +535,20 @@ class VerdictCache:
             return [None] * len(keys)
         g = sh["r"].get
         vals = [None if k is None else g(k) for k in keys]
+        old = sh["r_old"]
+        if old:
+            g = old.get
+            found = []
+            for i, v in enumerate(vals):
+                if v is None and keys[i] is not None:
+                    v = g(keys[i])
+                    if v is not None:
+                        vals[i] = v
+                        found.append(keys[i])
+            if found:
+                with self._lock:
+                    if self._revs.get(revision) is sh:
+                        self._promote_rels_locked(sh, found)
         nh = sum(1 for v in vals if v is not None)
         if nh:
             self._m.inc("cache.hits", nh)
@@ -469,6 +556,22 @@ class VerdictCache:
         if miss:
             self._m.inc("cache.misses", miss)
         return vals
+
+    def _promote_rels_locked(self, sh, found: list) -> None:
+        """Move old-generation entries that were just read to the young
+        generation.  A key a rotation has dropped meanwhile is skipped;
+        one a racing insert also put into the young generation stops
+        counting twice."""
+        young, old = sh["r"], sh["r_old"]
+        for k in found:
+            v = old.pop(k, None)
+            if v is None:
+                continue
+            if k in young:
+                self._bytes -= self.REL_ENTRY_BYTES
+                self._entries -= 1
+            else:
+                young[k] = v
 
     def insert_rels(self, revision: int, items, now_us: int) -> None:
         """Insert (key, verdict) pairs; keys are ``rel_key`` tuples the
@@ -492,13 +595,14 @@ class VerdictCache:
     def peek_rel(self, revision: int, key) -> Optional[tuple]:
         """Metric-free single-key probe: the explain surface records
         whether a verdict WOULD have been cache-served (provenance)
-        without polluting hit/miss counters, firing the chaos site, or
-        refreshing the shard's LRU position."""
+        without polluting hit/miss counters, firing the chaos site,
+        refreshing the shard's LRU position or promoting the entry."""
         with self._lock:
             sh = self._revs.get(revision)
         if sh is None:
             return None
-        return sh["r"].get(key)
+        v = sh["r"].get(key)
+        return v if v is not None else sh["r_old"].get(key)
 
     # -- lifecycle / introspection ---------------------------------------
     def set_max_bytes(self, max_bytes: int) -> None:
@@ -507,7 +611,7 @@ class VerdictCache:
         the lock (LRU revision first, same path as insert pressure);
         growing just raises the ceiling and later inserts fill it.
         Concurrent readers are untouched either way — eviction drops
-        whole shard objects, never mutates one."""
+        whole shard and generation objects, never mutates one."""
         with self._lock:
             self.max_bytes = int(max_bytes)
             self._evict_locked()
@@ -534,7 +638,7 @@ class VerdictCache:
                 sh = self._revs.pop(revision, None)
                 if sh is not None:
                     self._bytes -= self._shard_bytes(sh)
-                    self._entries -= len(sh["c"]) + len(sh["r"])
+                    self._entries -= self._shard_entries(sh)
                     dropped += 1
             if dropped:
                 if dropped > 1:
@@ -582,6 +686,8 @@ class VerdictCache:
                 "misses": misses,
                 "bypass": m.counter("cache.bypass"),
                 "puts": m.counter("cache.puts"),
+                "evicted_entries": m.counter("cache.evicted_entries"),
+                "rotations": self._rotations,
                 "hit_rate": round(hits / (hits + misses), 4)
                 if (hits + misses) else 0.0,
             }

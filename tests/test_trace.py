@@ -426,6 +426,52 @@ def test_latency_stage_annotations_are_leaves(doc_client, tmp_path):
     assert_stages_are_leaves(events)
 
 
+@pytest.mark.parametrize("entry", ["check", "submit_columns"])
+def test_cache_layer_stages_are_leaves_around_the_direct_call(
+        batch_client, tmp_path, entry):
+    """The cache layer of a served batch is two stages around the direct
+    evaluation, enclosing none of its stages: ``client.cache_read`` on
+    every batch that enters the layer, ``client.cache_write`` only where
+    something was left to evaluate.  The cache-off, dedup-off path
+    (``Client.check`` above) has neither."""
+    import jax
+
+    from gochugaru_tpu.client import with_store
+    from gochugaru_tpu.engine import vcache
+
+    c, ctx, rs = batch_client
+    timers = ("client.cache_read_s", "client.cache_write_s")
+    t0 = _counts(*timers)
+    assert c.check(ctx, consistency.full(), *rs) == [True] * 8
+    assert _counts(*timers) == t0  # the early return: no cache stage
+    cached = new_tpu_evaluator(with_store(c.store))
+    snap = c.store.snapshot_for(consistency.full())
+    look = snap.interner.lookup
+    cols = (np.array([look("doc", r.resource_id) for r in rs], np.int32),
+            np.full(8, snap.compiled.slot_of_name["read"], np.int32),
+            np.array([look("user", r.subject_id) for r in rs], np.int32))
+    with jax.profiler.trace(str(tmp_path)):
+        with cached.with_serving(
+                cs=consistency.min_latency(),
+                cache=vcache.VerdictCache(registry=metrics.Metrics())) as h:
+            for _ in range(2):  # evaluated, then answered by the cache
+                got = (h.check(ctx, *rs) if entry == "check" else
+                       h.submit_columns(ctx, *cols).result(timeout=60.0))
+                assert list(got) == [True] * 8
+    t1 = _counts(*timers)
+    assert t1["client.cache_read_s"] == t0["client.cache_read_s"] + 2
+    assert t1["client.cache_write_s"] == t0["client.cache_write_s"] + 1
+    events = profiled_stage_events(tmp_path)
+    names = stage_names_on_thread_of(events, "gochugaru.client.cache_write")
+    mine = [n for n in names if n.startswith(
+        ("gochugaru.client.", "gochugaru.engine."))]
+    assert mine[0] == "gochugaru.client.cache_read"
+    assert mine[1] == "gochugaru.client.snapshot"
+    assert mine[-2:] == ["gochugaru.client.cache_write",
+                         "gochugaru.client.cache_read"], mine
+    assert_stages_are_leaves(events)
+
+
 def test_sampled_batch_path_stage_spans_equal_timer_samples(batch_client):
     """(c) A sampled request's stage child spans are built from the same
     two stamps as the timers: duration == timer sample, exactly."""
