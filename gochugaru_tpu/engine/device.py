@@ -33,6 +33,7 @@ XLA program, traced once per (schema, config, shape bucket).
 from __future__ import annotations
 
 from dataclasses import dataclass
+from itertools import repeat
 from typing import Any, Dict, List, Mapping, Optional, Sequence, Tuple
 
 import jax
@@ -46,7 +47,7 @@ from ..caveats.device import (
     encode_contexts,
     make_tri_fn,
 )
-from ..rel.relationship import Relationship, WILDCARD_ID
+from ..rel.relationship import Relationship
 from ..schema.compiler import CompiledSchema
 from ..store.snapshot import Snapshot
 import time as _time
@@ -85,6 +86,29 @@ def _pad_payload(a: np.ndarray, size: int, fill: int = 0) -> np.ndarray:
     out = np.full(size, fill, dtype=np.int32)
     out[: a.shape[0]] = a
     return out
+
+
+def subject_rows(
+    q_subj: np.ndarray, q_srel: np.ndarray, q_wc: np.ndarray, q_ctx: np.ndarray,
+) -> Tuple[np.ndarray, np.ndarray]:
+    """The unique (subject, subject relation, wildcard node, query
+    context) rows of a batch for Phase A, and each query's row in them —
+    context is part of the key because caveat gates make closures
+    context-dependent.  ``(uniq int32[U, 4], q_row int32[B])``: the rows
+    in lexicographic order and the inverse, as numpy's row-wise unique
+    over the stacked ``[B, 4]`` key returns them, by one ``np.lexsort``
+    and a neighbour difference.  Only the two-phase ``_fn`` programs
+    read it (the flat path has no Phase A), so their dispatch builds it
+    and the lowering does not."""
+    key = np.stack([q_subj, q_srel, q_wc, q_ctx], axis=1).astype(np.int32, copy=False)
+    B = key.shape[0]
+    order = np.lexsort(key.T[::-1])
+    rows = key[order]
+    first = np.ones(B, bool)
+    np.any(rows[1:] != rows[:-1], axis=1, out=first[1:])
+    q_row = np.empty(B, np.int32)
+    q_row[order] = np.cumsum(first, dtype=np.int32) - 1
+    return rows[first], q_row
 
 
 # ---------------------------------------------------------------------------
@@ -1030,12 +1054,14 @@ class DeviceEngine:
         self, snap: Snapshot, rels: Sequence[Relationship],
         strings: Optional[Dict[str, int]] = None,
         span=_trace.NOOP,
-    ) -> Tuple[Dict[str, np.ndarray], np.ndarray, Dict[str, np.ndarray]]:
+    ) -> Tuple[Dict[str, np.ndarray], Dict[str, np.ndarray]]:
         """Host lowering of one batch, Relationship objects to interned
-        int32 query columns, as one ``engine.lower`` stage (wall, and
-        thread CPU while it records).  The batch's node ids come from
-        one ``interner.lookup_pairs`` call: ``engine.intern_s`` is
-        observed around it, ``intern.lookups`` counts its 2·B keys and
+        int32 query columns and the request-context tables, as one
+        ``engine.lower`` stage (wall, and thread CPU while it records).
+        Column operations only: nothing but the pulls of the six fields
+        runs once per row.  The batch's node ids come from one
+        ``interner.lookup_pairs`` call: ``engine.intern_s`` is observed
+        around it, ``intern.lookups`` counts its 2·B keys and
         ``intern.batch_calls`` the call."""
         with _trace.stage("engine.lower", span, cpu=True) as st:
             return self._lower(snap, rels, strings, st)
@@ -1046,11 +1072,7 @@ class DeviceEngine:
         slot_of = self.compiled.slot_of_name
         wc_of = snap.wildcard_node_of_type
 
-        q_perm = np.full(B, -1, np.int32)
-        q_srel = np.full(B, -1, np.int32)
-        q_wc = np.full(B, -1, np.int32)
         q_ctx = np.full(B, -1, np.int32)
-        q_self = np.zeros(B, bool)
 
         # dedup request contexts (the caveat_context of the query
         # relationship IS the request context, client/client.go:241-259)
@@ -1067,58 +1089,102 @@ class DeviceEngine:
                         ctx_rows.append(r.caveat_context)
                     q_ctx[i] = at
 
-        # every node id of the batch in ONE interner call (2·B keys):
-        # the loop below never reaches the interner
         res_type = [r.resource_type for r in rels]
         res_id = [r.resource_id for r in rels]
+        res_rel = [r.resource_relation for r in rels]
         subj_type = [r.subject_type for r in rels]
         subj_id = [r.subject_id for r in rels]
+        subj_rel = [r.subject_relation for r in rels]
+        # every node id of the batch in ONE interner call (2·B keys)
         t0 = _time.perf_counter()
         nodes, type_ids = interner.lookup_pairs(
             res_type + subj_type, res_id + subj_id)
         intern_s = _time.perf_counter() - t0
         q_res, q_subj = nodes[:B], nodes[B:]
+        # the wildcard node of the subject's type, unless the subject is
+        # that node: wc_of holds lookup(type, "*"), so q_subj equals it
+        # exactly where subj_id is the wildcard id
         stid = type_ids[B:]
-        has_wc = (stid >= 0) & (stid < wc_of.shape[0]) & np.fromiter(
-            map(WILDCARD_ID.__ne__, subj_id), bool, B)
-        q_wc[has_wc] = wc_of[stid[has_wc]]
+        typed = (stid >= 0) & (stid < wc_of.shape[0])
+        wc = wc_of[np.where(typed, stid, 0)]
+        q_wc = np.where(typed & (wc != q_subj), wc, np.int32(-1))
 
-        for i, r in enumerate(rels):
-            q_perm[i] = slot_of.get(r.resource_relation, -1)
-            if r.subject_relation:
-                srel = slot_of.get(r.subject_relation)
-                if srel is None:
-                    # unknown subject relation can never be granted; -1
-                    # would alias "direct subject", so force the query false
-                    q_res[i] = -1
-                    q_srel[i] = -1
-                else:
-                    q_srel[i] = srel
-            else:
-                q_srel[i] = -1
+        q_perm = np.fromiter(
+            map(slot_of.get, res_rel, repeat(-1)), np.int32, B)
+        # an empty subject relation is the direct subject, -1; a non-empty
+        # one with no slot reads -2 until the rows are forced false below
+        q_srel = np.fromiter(
+            map({**slot_of, "": -1}.get, subj_rel, repeat(-2)), np.int32, B)
+        no_slot = q_srel == -2
+        # reflexive userset identity: the same (type, id) on both sides and
+        # the same non-empty relation.  A name has one slot and a known
+        # node one (type, id), so equal known slots and equal known nodes
+        # prove it; where the relation has no slot or the nodes are both
+        # unknown (-1 == -1 proves nothing) the row's strings decide
+        same = (q_res == q_subj) & (
+            ((q_srel >= 0) & (q_srel == q_perm)) | (no_slot & (q_perm < 0)))
+        q_self = same & (q_srel >= 0) & (q_subj >= 0)
+        for i in np.flatnonzero(same & ~q_self).tolist():
             q_self[i] = (
-                r.resource_type == r.subject_type
-                and r.resource_id == r.subject_id
-                and r.subject_relation == r.resource_relation
-                and r.subject_relation != ""
+                res_type[i] == subj_type[i] and res_id[i] == subj_id[i]
+                and res_rel[i] == subj_rel[i]
             )
+        # an unknown subject relation can never be granted; -1 would alias
+        # "direct subject", so force the query false
+        q_res[no_slot] = -1
+        q_srel[no_slot] = -1
 
         m = metrics.default
         m.inc("intern.lookups", 2 * B)
         m.inc("intern.batch_calls")
         m.observe("engine.intern_s", intern_s)
         st.note(batch=B, intern_s=round(intern_s, 6))
-        # unique (subject, query-context) rows for Phase A — context is part
-        # of the key because caveat gates make closures context-dependent
-        subj_key = np.stack([q_subj, q_srel, q_wc, q_ctx], axis=1)
-        uniq, q_row = np.unique(subj_key, axis=0, return_inverse=True)
         queries = {
             "q_res": q_res, "q_perm": q_perm, "q_subj": q_subj,
             "q_srel": q_srel, "q_wc": q_wc, "q_ctx": q_ctx,
-            "q_row": q_row.astype(np.int32), "q_self": q_self,
+            "q_self": q_self,
         }
-        qctx_tables = self._encode_query_contexts(ctx_rows, strings)
-        return queries, uniq.astype(np.int32), qctx_tables
+        return queries, self._encode_query_contexts(ctx_rows, strings)
+
+    def _two_phase_call(
+        self, dsnap: DeviceSnapshot, queries: Dict[str, np.ndarray],
+        qctx: Dict[str, np.ndarray], now_us: Optional[int], BP: int, span,
+    ):
+        """Enqueue the two-phase ``_fn`` program over one batch padded to
+        ``BP``, as an ``engine.enqueue`` stage: the one reader of
+        ``subject_rows``, built here and padded to its own pow2 bucket
+        with -1 rows.  Counted in ``engine.subject_rows``, once a batch
+        — no flat dispatch comes here.  Returns the padded device
+        planes."""
+        with _trace.stage("engine.enqueue", span) as st:
+            st.note(legacy=True)
+            metrics.default.inc("engine.subject_rows")
+            uniq, q_row = subject_rows(
+                queries["q_subj"], queries["q_srel"], queries["q_wc"],
+                queries["q_ctx"],
+            )
+            U = uniq.shape[0]
+            u = np.full(
+                (_ceil_pow2(U, self.config.batch_bucket_min), 4), -1, np.int32)
+            u[:U] = uniq
+            B = q_row.shape[0]
+
+            def padq(a, fill):
+                out = np.full(BP, fill, a.dtype)
+                out[:B] = a
+                return jnp.asarray(out)
+
+            return self._fn(
+                self._legacy_arrays(dsnap), dsnap.tid_map,
+                jnp.int32(dsnap.snapshot.now_rel32(now_us)),
+                jnp.asarray(u[:, 0]), jnp.asarray(u[:, 1]), jnp.asarray(u[:, 2]),
+                jnp.asarray(u[:, 3]),
+                padq(queries["q_res"], -1), padq(queries["q_perm"], -1),
+                padq(queries["q_subj"], -1), padq(queries["q_srel"], -1),
+                padq(queries["q_wc"], -1), padq(q_row, 0),
+                padq(queries["q_self"], False), padq(queries["q_ctx"], -1),
+                self._qctx_device(qctx),
+            )
 
     def _encode_query_contexts(
         self, ctx_rows: List[Mapping], strings: Optional[Dict[str, int]]
@@ -1393,7 +1459,7 @@ class DeviceEngine:
         if meta is None or meta.sharded:
             return None
         snap = dsnap.snapshot
-        queries, _uniq, qctx = self._lower_queries(snap, rels, dsnap.strings)
+        queries, qctx = self._lower_queries(snap, rels, dsnap.strings)
         B = len(rels)
         got = self.flat_fn_and_args(
             dsnap, queries, qctx, jnp.int32(snap.now_rel32(now_us)), B,
@@ -1447,7 +1513,7 @@ class DeviceEngine:
         dsp = span.child("device.check_batch", t=t_lower, batch=len(rels))
         try:
             snap = dsnap.snapshot
-            queries, uniq, qctx = self._lower_queries(
+            queries, qctx = self._lower_queries(
                 snap, rels, dsnap.strings, span=dsp
             )
             B = len(rels)
@@ -1493,37 +1559,10 @@ class DeviceEngine:
                 with _trace.stage("engine.fetch", dsp):
                     d, p, ovf = jax.device_get(out)
                 return d[:B], p[:B], ovf[:B]
-            with _trace.stage("engine.enqueue", dsp) as st:
-                st.note(legacy=True)
-                BP = _ceil_pow2(B, self.config.batch_bucket_min)
-                U = uniq.shape[0]
-                UP = _ceil_pow2(U, self.config.batch_bucket_min)
-
-                def padq(a, fill):
-                    out = np.full(BP, fill, a.dtype)
-                    out[:B] = a
-                    return jnp.asarray(out)
-
-                u_subj = np.full(UP, -1, np.int32)
-                u_srel = np.full(UP, -1, np.int32)
-                u_wc = np.full(UP, -1, np.int32)
-                u_qctx = np.full(UP, -1, np.int32)
-                u_subj[:U] = uniq[:, 0]
-                u_srel[:U] = uniq[:, 1]
-                u_wc[:U] = uniq[:, 2]
-                u_qctx[:U] = uniq[:, 3]
-
-                now = jnp.int32(snap.now_rel32(now_us))
-                d, p, ovf = self._fn(
-                    self._legacy_arrays(dsnap), dsnap.tid_map, now,
-                    jnp.asarray(u_subj), jnp.asarray(u_srel), jnp.asarray(u_wc),
-                    jnp.asarray(u_qctx),
-                    padq(queries["q_res"], -1), padq(queries["q_perm"], -1),
-                    padq(queries["q_subj"], -1), padq(queries["q_srel"], -1),
-                    padq(queries["q_wc"], -1), padq(queries["q_row"], 0),
-                    padq(queries["q_self"], False), padq(queries["q_ctx"], -1),
-                    self._qctx_device(qctx),
-                )
+            d, p, ovf = self._two_phase_call(
+                dsnap, queries, qctx, now_us,
+                _ceil_pow2(B, self.config.batch_bucket_min), dsp,
+            )
             # one device→host fetch for all three planes: separate np.asarray
             # calls round-trip the dispatch boundary once each, which dominates
             # small-batch latency on remote-attached TPUs
@@ -1670,35 +1709,7 @@ class DeviceEngine:
             with _trace.stage("engine.fetch", span):
                 d, p, ovf = jax.device_get(out)
             return d[:B], p[:B], ovf[:B]
-        with _trace.stage("engine.enqueue", span) as st:
-            st.note(legacy=True)
-            q_res, q_perm, q_subj = queries["q_res"], queries["q_perm"], queries["q_subj"]
-            q_srel, q_wc, q_ctx = queries["q_srel"], queries["q_wc"], queries["q_ctx"]
-            q_self = queries["q_self"]
-
-            subj_key = np.stack([q_subj, q_srel, q_wc, q_ctx], axis=1)
-            uniq, q_row = np.unique(subj_key, axis=0, return_inverse=True)
-            U = uniq.shape[0]
-            UP = _ceil_pow2(U, self.config.batch_bucket_min)
-            u = np.full((UP, 4), -1, np.int32)
-            u[:U] = uniq
-
-            def padq(a, fill):
-                out = np.full(BP, fill, np.asarray(a).dtype)
-                out[:B] = a
-                return jnp.asarray(out)
-
-            now = jnp.int32(snap.now_rel32(now_us))
-            d, p, ovf = self._fn(
-                self._legacy_arrays(dsnap), dsnap.tid_map, now,
-                jnp.asarray(u[:, 0]), jnp.asarray(u[:, 1]), jnp.asarray(u[:, 2]),
-                jnp.asarray(u[:, 3]),
-                padq(q_res, -1), padq(q_perm, -1), padq(q_subj, -1),
-                padq(q_srel, -1), padq(q_wc, -1),
-                padq(q_row.astype(np.int32), 0),
-                padq(q_self, False), padq(q_ctx, -1),
-                self._qctx_device(qctx),
-            )
+        d, p, ovf = self._two_phase_call(dsnap, queries, qctx, now_us, BP, span)
         if not fetch:
             return d, p, ovf
         with _trace.stage("engine.fetch", span):

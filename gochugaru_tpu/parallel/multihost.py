@@ -156,7 +156,7 @@ def _worker_main() -> None:
     cs, snap, oracle, checks = ge._world(n_checks=32)
     engine = ShardedEngine(cs, mesh)
     dsnap = engine.prepare(snap)
-    queries, _, qctx = engine._lower_queries(snap, checks, dsnap.strings)
+    queries, qctx = engine._lower_queries(snap, checks, dsnap.strings)
     d, p, ovf = engine._dispatch_columns(
         dsnap, queries, qctx, ge.NOW_US, fetch=False
     )

@@ -34,13 +34,14 @@ from ..engine.device import (
     _ceil_pow2,
     _make_check_fn,
     _pad_payload,
+    subject_rows,
 )
 from ..engine.flat import build_qm
 from ..engine.plan import EngineConfig
 from ..rel.relationship import Relationship
 from ..schema.compiler import CompiledSchema
 from ..store.snapshot import Snapshot
-from ..utils import faults
+from ..utils import faults, metrics
 from ..utils import trace as _trace
 from .mesh import DATA_AXIS, MODEL_AXIS
 
@@ -644,34 +645,25 @@ class ShardedEngine(DeviceEngine):
             q = {
                 k: np.full(BP, -1 if v.dtype != bool else 0, v.dtype)
                 for k, v in queries.items()
-                if k != "q_row"
             }
             for k in q:
                 q[k][:B] = queries[k]
             # per-data-shard unique subjects (each shard computes closures only
             # for its own slice of the batch)
-            subj_key = np.stack(
-                [q["q_subj"], q["q_srel"], q["q_wc"], q["q_ctx"]], axis=1
-            )
+            metrics.default.inc("engine.subject_rows")
             ulists = []
             rows = np.zeros(BP, np.int32)
             for s in range(D):
                 blk = slice(s * per, (s + 1) * per)
-                uniq, inv = np.unique(subj_key[blk], axis=0, return_inverse=True)
+                uniq, rows[blk] = subject_rows(
+                    q["q_subj"][blk], q["q_srel"][blk], q["q_wc"][blk],
+                    q["q_ctx"][blk],
+                )
                 ulists.append(uniq)
-                rows[blk] = inv.astype(np.int32)
             UP = _ceil_pow2(max(u.shape[0] for u in ulists), self.config.batch_bucket_min)
-            u_subj = np.full(D * UP, -1, np.int32)
-            u_srel = np.full(D * UP, -1, np.int32)
-            u_wc = np.full(D * UP, -1, np.int32)
-            u_qctx = np.full(D * UP, -1, np.int32)
+            u = np.full((D * UP, 4), -1, np.int32)
             for s, uniq in enumerate(ulists):
-                n = uniq.shape[0]
-                u_subj[s * UP : s * UP + n] = uniq[:, 0]
-                u_srel[s * UP : s * UP + n] = uniq[:, 1]
-                u_wc[s * UP : s * UP + n] = uniq[:, 2]
-                u_qctx[s * UP : s * UP + n] = uniq[:, 3]
-            q["q_row"] = rows
+                u[s * UP : s * UP + uniq.shape[0]] = uniq
             ssp.event("stage.partition")
 
             faults.fire("sharded.collective")
@@ -685,9 +677,9 @@ class ShardedEngine(DeviceEngine):
             with _trace.stage("engine.enqueue", ssp):
                 d, p, ovf = self._fn(
                     dsnap.arrays, dsnap.tid_map, now,
-                    put(u_subj), put(u_srel), put(u_wc), put(u_qctx),
+                    put(u[:, 0]), put(u[:, 1]), put(u[:, 2]), put(u[:, 3]),
                     put(q["q_res"]), put(q["q_perm"]), put(q["q_subj"]),
-                    put(q["q_srel"]), put(q["q_wc"]), put(q["q_row"]), put(q["q_self"]),
+                    put(q["q_srel"]), put(q["q_wc"]), put(rows), put(q["q_self"]),
                     put(q["q_ctx"]),
                     {k: jax.device_put(v, rep) for k, v in qctx.items()},
                 )
@@ -712,7 +704,7 @@ class ShardedEngine(DeviceEngine):
         if not rels:
             z = np.zeros(0, bool)
             return z, z, z
-        queries, _, qctx = self._lower_queries(
+        queries, qctx = self._lower_queries(
             dsnap.snapshot, rels, dsnap.strings, span=span
         )
         return self._dispatch_columns(dsnap, queries, qctx, now_us, span=span)

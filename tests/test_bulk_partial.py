@@ -154,7 +154,7 @@ def test_pipelined_subbatch_matches_monolithic():
     assert np.array_equal(np.asarray(o0), np.asarray(o1))
 
     # the generator form: per-sub-batch windows in order, same planes
-    queries, _, _qc = eng_p._lower_queries(snap, checks, dp.strings)
+    queries, _qc = eng_p._lower_queries(snap, checks, dp.strings)
     got = list(eng_p.check_columns_pipelined(
         dp, queries["q_res"], queries["q_perm"], queries["q_subj"],
         now_us=NOW, sub_batch=16,

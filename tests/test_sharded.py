@@ -11,10 +11,12 @@ import pytest
 from gochugaru_tpu import rel
 from gochugaru_tpu.engine.device import DeviceEngine
 from gochugaru_tpu.engine.oracle import T, U, Oracle
+from gochugaru_tpu.engine.plan import EngineConfig
 from gochugaru_tpu.parallel import ShardedEngine, make_mesh
 from gochugaru_tpu.schema import compile_schema, parse_schema
 from gochugaru_tpu.store.interner import Interner
 from gochugaru_tpu.store.snapshot import build_snapshot
+from gochugaru_tpu.utils import metrics
 
 pytestmark = pytest.mark.skipif(
     len(jax.devices()) < 8, reason="needs 8 virtual devices"
@@ -172,6 +174,23 @@ def test_sharded_check_columns_reflexive_self():
         now_us=1_700_000_000_000_000,
     )
     assert bool(np.asarray(d)[0])
+
+
+def test_sharded_two_phase_builds_subject_rows_per_block():
+    """Without the flat kernel the shard_mapped two-phase program reads
+    the subject rows of each data shard's block: built at its dispatch
+    (``engine.subject_rows`` moves once a batch), answers as the oracle."""
+    cs, snap, oracle, queries = build_world(seed=11)
+    eng = ShardedEngine(
+        cs, make_mesh(4, 2), EngineConfig.for_schema(cs, use_flat=False))
+    dsnap = eng.prepare(snap)
+    assert dsnap.flat_meta is None
+    before = metrics.default.counter("engine.subject_rows")
+    d, p, ovf = eng.check_batch(dsnap, queries, now_us=1_700_000_000_000_000)
+    assert metrics.default.counter("engine.subject_rows") - before == 1
+    assert not ovf.any()
+    assert [bool(x) for x in d] == [
+        oracle.check_relationship(q) == T for q in queries]
 
 
 def test_sharded_flat_slot_chunking():
