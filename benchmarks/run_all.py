@@ -227,13 +227,6 @@ def main() -> int:
         [py, "benchmarks/bench12_writes.py"] + (["--quick"] if q else []),
         900,
     ))
-    configs.append((
-        "25 — pallas smoke (fused-probe interpret parity through"
-        " throughput/latency/packed, zero warm retraces, ledger"
-        " bytes-delta bar)",
-        ["bash", "scripts/pallas_smoke.sh"],
-        600,
-    ))
     if not q:
         # Leopard-scale CPU proxy (VERDICT r04 item 3): the same Watch
         # re-index loop at a 100M-edge base — BASELINE config 5's

@@ -295,7 +295,7 @@ class Smoke:
             "latency.compiles", "latency.dispatches", "latency.retraces",
             "lookups.frontier", "lookups.fused", "lookups.walker",
             "spmm.dispatches", "spmm.fallbacks", "lookup.dispatches",
-            "pallas.kernel_traces", "serve.batches",
+            "serve.batches",
         )}
 
     def timed(self, call, key: str = "first_call_wall_s"):

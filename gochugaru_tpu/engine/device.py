@@ -58,7 +58,6 @@ from ..utils import trace as _trace
 from ..utils.context import background as _background
 from ..utils.errors import classify_dispatch_exception
 from ..utils.retry import retry_retriable_errors
-from . import pallas as _pallas
 from .plan import DevicePlan, EngineConfig, build_plan
 
 #: edge-count floor for the prepare-time lookup-index prewarm thread:
@@ -901,11 +900,6 @@ class DeviceEngine:
         # per-table) for this snapshot — the roofline numerator rides
         # /metrics and incident bundles from the moment of prepare
         _perf.publish_model(dsnap)
-        if _pallas.resolve(self.config):
-            # Pallas backend armed: publish what its kernels keep
-            # VMEM-resident and the modeled one-pass bytes delta
-            _pallas.publish_vmem(arrays)
-            _perf.publish_pallas_model(dsnap)
         return dsnap
 
     @staticmethod
@@ -1504,11 +1498,6 @@ class DeviceEngine:
             z = np.zeros(0, bool)
             return z, z, z
         faults.fire("device.dispatch")
-        if _pallas.resolve(self.config):
-            # pallas-path failures classify through the SAME retry
-            # envelope as any dispatch: the chaos soak arms this site to
-            # prove the fused-kernel path reroutes like the XLA one
-            faults.fire("pallas.dispatch")
         t_lower = _time.perf_counter()
         dsp = span.child("device.check_batch", t=t_lower, batch=len(rels))
         try:
@@ -1689,8 +1678,6 @@ class DeviceEngine:
         subsequent dispatch on remote-attached platforms.
         """
         faults.fire("device.dispatch")
-        if _pallas.resolve(self.config):
-            faults.fire("pallas.dispatch")
         snap = dsnap.snapshot
         B = q_res.shape[0]
         BP = _ceil_pow2(B, max(bucket_min, self.config.batch_bucket_min))

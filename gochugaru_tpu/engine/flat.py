@@ -3195,15 +3195,6 @@ def make_flat_fn(
     tri = make_tri_fn(caveat_plan) if caveat_plan is not None else None
     SH = axis is not None
     PART = bool(meta.part_serve)
-    # Pallas fused probe backend (engine/pallas.py): unsharded blockslice
-    # probes route through the hand-fused kernel when the knob resolves
-    # on.  Sharded/part-serve/routed layouts keep the XLA chain — their
-    # probes carry ownership masks and collectives the kernel doesn't
-    # model; the resolve is deterministic per process+config, so pinned
-    # latency tiers keep the no-retrace contract
-    from . import pallas as _pallas
-
-    PLS = (not SH) and _pallas.resolve(cfg)
     # under sharding the delta overlay tables are REPLICATED (they are
     # small): delta probe sites use plain unsharded probes whose results
     # are identical on every shard, composed after the base sites'
@@ -3436,46 +3427,6 @@ def make_flat_fn(
 
         ALD = {k: (w, caps) for (k, w, caps) in meta.aligned}
 
-        def psite(off_key: str, tbl_key: str, cap: int, q_cols,
-                  mode: str = "block",
-                  gate3: Tuple[bool, bool, bool] = (False, False, False),
-                  lay: Optional[Dict[str, int]] = None,
-                  need_now: bool = False):
-            """Route one unsharded blockslice probe through the Pallas
-            fused kernel (engine/pallas.py); None = keep the XLA chain
-            (knob off or sharded layout).  Offset arrays too big for the
-            VMEM-resident plan are an error, not a fallback: the knob is
-            on only by demand.  The kernel replicates
-            mix32 / the slice clamp / decode_block verbatim, so ``block``
-            mode is bitwise the XLA block and the reduced modes are
-            bitwise its downstream folds."""
-            if not PLS:
-                return None
-            spec = PK.get(tbl_key)
-            nw = now if need_now else None
-            al = ALD.get(tbl_key)
-            if al is not None and tbl_key + "_al" in arrs:
-                w_, caps = al
-                sw = w_ if spec is None else spec[1]
-                tbls = [
-                    arrs[_al_key(tbl_key, lvl)]
-                    for lvl in range(len(caps))
-                    if _al_key(tbl_key, lvl) in arrs
-                ]
-                return _pallas.fused_probe_aligned(
-                    q_cols, tbls, caps[: len(tbls)], sw, spec=spec,
-                    mode=mode, now=nw, gate=gate3, lay=lay,
-                )
-            A = PKO.get(off_key)
-            off = arrs[off_key]
-            off_a = arrs[off_key + "_a"] if A is not None else None
-            _pallas.require_vmem(off_key, off, off_a)
-            return _pallas.fused_probe(
-                q_cols, off, arrs[tbl_key], cap=cap, spec=spec,
-                off_a=off_a, ashift=A, mode=mode, now=nw, gate=gate3,
-                lay=lay,
-            )
-
         def pblock(off_key: str, tbl_key: str, cap: int, q_cols):
             """Layout-dispatched bucket probe: (blk, mine) — the block
             already DECODED to logical int32 columns when the table is
@@ -3487,9 +3438,6 @@ def make_flat_fn(
             tables derive bpd from the LOCAL off length (shapes inside
             shard_map are per-shard)."""
             if not SH:
-                pb = psite(off_key, tbl_key, cap, q_cols, mode="block")
-                if pb is not None:
-                    return pb, None
                 al = ALD.get(tbl_key)
                 if al is not None and tbl_key + "_al" in arrs:
                     w_, caps = al
@@ -3595,10 +3543,6 @@ def make_flat_fn(
                 )
                 return z, z
             if BS:
-                pr = psite("clh_off", "clx", meta.cl_cap, (srck, gk),
-                           mode="until2", need_now=True)
-                if pr is not None:
-                    return pr
                 blk, mine = pblock(
                     "clh_off", "clx", meta.cl_cap, (srck, gk)
                 )
@@ -3974,46 +3918,17 @@ def make_flat_fn(
                     tombstones carry full primary identities."""
                     hd = hp = jnp.zeros(nodes.shape, bool)
                     if run_e:
-                        pg = psite(
-                            "eh_off", "ehx", meta.e_cap, (k1, k2q),
-                            mode="gate",
-                            gate3=(meta.e_hasexp, meta.e_hascav,
-                                   meta.e_hascav and tri is not None),
-                            lay=eL, need_now=meta.e_hasexp,
+                        blk, mine = pblock(
+                            "eh_off", "ehx", meta.e_cap,
+                            (k1, k2q),
                         )
-                        if pg is not None:
-                            # expiry gate fused in-kernel; the CEL tri VM
-                            # runs on the compact cav/ctx lanes.  exists
-                            # is lane-constant: ANDing it after the
-                            # kernel's hit/live masks commutes (dead
-                            # lanes' cav/ctx feed tri but live kills
-                            # them), so parity with gate2_blk is exact
-                            live = pg[1] & exists[..., None]
-                            if not meta.e_hascav:
-                                bd = bp = live
-                            elif tri is None:
-                                bd, bp = live & (pg[2] == 0), live
-                            else:
-                                qb = jnp.broadcast_to(
-                                    bq(q_ctx, pg[2].ndim), pg[2].shape
-                                )
-                                tv = tri(pg[2], pg[3], qb, tables)
-                                bd = live & (tv == 2)
-                                bp = live & (tv >= 1)
-                            hd = jnp.any(bd, axis=-1)
-                            hp = jnp.any(bp, axis=-1)
-                        else:
-                            blk, mine = pblock(
-                                "eh_off", "ehx", meta.e_cap,
-                                (k1, k2q),
-                            )
-                            hit = (
-                                blk_hit(blk, (k1, k2q), mine)
-                                & exists[..., None]
-                            )
-                            bd, bp = gate2_blk("e", blk, eL, hit)
-                            hd = por_m(jnp.any(bd, axis=-1), mine)
-                            hp = por_m(jnp.any(bp, axis=-1), mine)
+                        hit = (
+                            blk_hit(blk, (k1, k2q), mine)
+                            & exists[..., None]
+                        )
+                        bd, bp = gate2_blk("e", blk, eL, hit)
+                        hd = por_m(jnp.any(bd, axis=-1), mine)
+                        hp = por_m(jnp.any(bp, axis=-1), mine)
                         if dm is not None and dm.has_tombs:
                             tb = probe_block(
                                 arrs["dl_tb_off"], arrs["dl_tbx"],
@@ -4067,12 +3982,6 @@ def make_flat_fn(
             if use_t:
                 def t_site(k2q):
                     if BS:
-                        pr = psite("th_off", "tx", meta.t_cap, (k1, k2q),
-                                   mode="until2", need_now=True)
-                        if pr is not None:
-                            # exists is lane-constant, so ANDing it after
-                            # the in-kernel OR-reduce is exact
-                            return pr[0] & exists, pr[1] & exists
                         blk, mine = pblock(
                             "th_off", "tx", meta.t_cap, (k1, k2q)
                         )
@@ -4182,18 +4091,13 @@ def make_flat_fn(
                         if meta.us_hasperm
                         else jnp.zeros(valid.shape, bool)
                     )
-                    pa = psite("push_off", "pusx", meta.pus_cap, (gk,),
-                               mode="any")
-                    if pa is not None:
-                        in_pus = pa
-                    else:
-                        pblk, pmine = pblock(
-                            "push_off", "pusx", meta.pus_cap, (gk,)
-                        )
-                        in_pus = por_m(
-                            jnp.any(blk_hit(pblk, (gk,), pmine), axis=-1),
-                            pmine,
-                        )
+                    pblk, pmine = pblock(
+                        "push_off", "pusx", meta.pus_cap, (gk,)
+                    )
+                    in_pus = por_m(
+                        jnp.any(blk_hit(pblk, (gk,), pmine), axis=-1),
+                        pmine,
+                    )
                     in_d = (in_d | refl) & ~permf
                     in_p = in_p | refl | in_pus | permf
                 else:
@@ -4565,10 +4469,6 @@ def make_flat_fn(
         else:
             def ovf_probe(k):
                 if BS:
-                    oa = psite("ovfh_off", "ovfx", meta.ovf_cap, (k,),
-                               mode="any")
-                    if oa is not None:
-                        return oa
                     oblk, omine = pblock(
                         "ovfh_off", "ovfx", meta.ovf_cap, (k,)
                     )

@@ -30,10 +30,6 @@ This path removes every per-dispatch variable cost it can:
   refilled in place (engine/flat.py fill_qm) — steady-state dispatch
   allocates no host arrays; the context-free qctx device singleton is
   reused from the engine cache.
-- **buffer donation**: on TPU the query-matrix device buffer is donated
-  to the executable (EngineConfig.latency_donate, auto), letting XLA
-  alias it for outputs instead of allocating; off on CPU where the
-  runtime cannot use the donation and warns.
 - **budget breakdown**: every dispatch is timed in four stages — host
   lowering (query packing), H2D (staging transfer), kernel (blocked
   execution), D2H (result fetch) — published through utils/metrics.py
@@ -62,7 +58,6 @@ from ..utils import faults
 from ..utils import metrics as _metrics
 from ..utils import perf as _perf
 from ..utils import trace as _trace
-from . import pallas as _pallas
 from .flat import QM_ROWS, fill_qm
 
 
@@ -208,8 +203,6 @@ class LatencyPath:
         local-first, then the engine-wide cache, then a real compile.
         Witness-armed dispatches pin the witness kernel variant under a
         distinct key; disarmed keys are exactly the pre-witness ones."""
-        import jax
-
         wit = self.witness_armed
         key = (slots, tier, qctx_key) if not wit else (
             slots, tier, qctx_key, "wit"
@@ -226,28 +219,12 @@ class LatencyPath:
                 fn = self.engine._latency_pins.get(full_key)
             fresh = fn is None
             if fresh:
-                if self.engine.config.donate_on():
-                    from .flat import make_flat_fn
-
-                    jfn = jax.jit(
-                        make_flat_fn(
-                            self.engine.compiled, self.engine.plan,
-                            self.engine.config, self.dsnap.flat_meta, slots,
-                            caveat_plan=self.engine.caveat_plan, jit=False,
-                            witness=wit,
-                        ),
-                        # donate the query matrix: its device buffer is
-                        # re-uploaded fresh every dispatch, so XLA may
-                        # alias it for the output planes
-                        donate_argnums=(3,),
-                    )
-                else:
-                    # share the engine's jit cache with the throughput
-                    # path: the trace is reused, only the AOT compile
-                    # at the tier's shape is new
-                    jfn = self.engine._flat_fn_for(
-                        slots, self.dsnap.flat_meta, witness=wit
-                    )
+                # the engine's jit, the one the throughput path calls:
+                # the trace is reused, only the AOT compile at the
+                # tier's shape is new
+                jfn = self.engine._flat_fn_for(
+                    slots, self.dsnap.flat_meta, witness=wit
+                )
                 fn = jfn.lower(*args).compile()
                 self.compile_count += 1
                 self._m.inc("latency.compiles")
@@ -318,22 +295,12 @@ class LatencyPath:
         # injection site AFTER the availability checks: a batch this path
         # would decline falls back without ever reaching the fault
         faults.fire("latency.dispatch")
-        if _pallas.resolve(self.engine.config):
-            # the pinned kernels run the fused Pallas probes when the
-            # knob resolves on — a pallas-path fault here classifies and
-            # reroutes exactly like a latency-path one (breaker re-form)
-            faults.fire("pallas.dispatch")
 
         # ---- stage 1: host lowering (pack into the staging buffer) -----
         # the staging buffer is shared per tier: hold the path lock from
         # fill through upload so concurrent checkers can't corrupt it
         # (concurrent serving shards by path/thread; the lock only
         # covers the host-side window, not kernel execution)
-        # fence between budget stages?  Exact per-stage times on TPU; on
-        # CPU the fences themselves cost ~0.3 ms per dispatch, so the
-        # auto default folds the (synchronous) H2D remainder into the
-        # kernel stage instead
-        staged = self.engine.config.staged_timing_on()
         # each budget stage is also a leaf on the profiler's clock
         # (``gochugaru.engine.latency.*``, utils/trace.py); the budget's
         # own t0 reaches back over the caller's lowering, the ``fill``
@@ -364,12 +331,14 @@ class LatencyPath:
                 else:
                     now_dev = jax.device_put(np.int32(now))
                     self._now_cache = (int(now), now_dev)
-                if staged or jax.default_backend() != "cpu":
-                    # the fence is load-bearing off-CPU regardless of the
-                    # timing knob: the shared staging buffer must not be
-                    # refilled (lock released) while an async H2D still
-                    # reads it.  On CPU device_put copies synchronously, so
-                    # only there may the knob elide the fence
+                if jax.default_backend() != "cpu":
+                    # the fence is load-bearing off-CPU: the shared
+                    # staging buffer must not be refilled (lock released)
+                    # while an async H2D still reads it.  On CPU
+                    # device_put copies synchronously and the fence
+                    # itself costs ~0.3 ms a dispatch, so only there is
+                    # it elided and the H2D remainder folds into the
+                    # kernel stage
                     jax.block_until_ready((qm_dev, now_dev))
         t2 = time.perf_counter()
 

@@ -245,20 +245,6 @@ class EngineConfig:
     #: 4096) and the no-retrace contract holds because pins are keyed
     #: by the tier value itself, not its log2
     latency_tiers: Tuple[int, ...] = (256, 1024, 4096)
-    #: donate the query-matrix device buffer to the pinned executable
-    #: (XLA aliases it for outputs — zero per-dispatch device
-    #: allocation).  None = auto: on for TPU, off on CPU where the
-    #: runtime cannot use the donation and warns per compile
-    latency_donate: Optional[bool] = None
-    #: fence between budget stages (block after H2D, after kernel) so
-    #: each stage's time is exact.  None = auto: on for TPU (the H2D
-    #: genuinely overlaps and must be fenced to be measured), off on
-    #: CPU (device_put is a synchronous copy; the extra fences cost
-    #: ~0.3 ms per dispatch and the kernel stage absorbs any queued
-    #: transfer remainder).  Timing-only: off-CPU the H2D fence is kept
-    #: regardless (the shared staging buffer must not be refilled while
-    #: an async transfer still reads it)
-    latency_staged_timing: Optional[bool] = None
     # -- unified masked-SpMM sparse core (engine/spmm.py) ----------------
     #: serve multi-hop lookups through the fused K-hop SpMM program (the
     #: whole reverse/forward frontier fixpoint in ONE pinned dispatch,
@@ -282,31 +268,11 @@ class EngineConfig:
     #: candidate-buffer capacity of one fused dispatch; answers larger
     #: than this overflow to the looped (streaming) path
     spmm_candidates: int = 8_192
-    # -- Pallas fused probe backend (engine/pallas.py) -------------------
-    #: serve the bucket probes (check direct/T/closure/userset sites and
-    #: the frontier run probes) through the hand-fused Pallas kernel:
-    #: hash → offset → double-buffered bucket DMA → packed decode → gate
-    #: → reduce in ONE HBM pass per table, offsets/ladders VMEM-resident.
-    #: None and False are both OFF on every platform — the XLA gather
-    #: chain is the main path, because Mosaic refuses these kernels on a
-    #: v5e (CHANGES.md, PR 21).  True demands the kernels: on a TPU they
-    #: compile with Mosaic or the dispatch raises; on the CPU backend
-    #: (tests) they run in Pallas INTERPRET mode — correctness, not
-    #: speed.  Nothing degrades one to the other
-    pallas: Optional[bool] = None
 
     # -- the backend-keyed choices, resolved in ONE place -----------------
     def aligned_on(self) -> bool:
         """Resolved flat_aligned (None = auto: on for TPU)."""
         return _auto(self.flat_aligned)
-
-    def donate_on(self) -> bool:
-        """Resolved latency_donate (None = auto: on for TPU)."""
-        return _auto(self.latency_donate)
-
-    def staged_timing_on(self) -> bool:
-        """Resolved latency_staged_timing (None = auto: on for TPU)."""
-        return _auto(self.latency_staged_timing)
 
     def pipeline_batch(self) -> int:
         """Resolved flat_pipeline_batch (None = auto: 32768 on TPU, 0 —
@@ -318,14 +284,9 @@ class EngineConfig:
     def resolved(self) -> Dict[str, object]:
         """What every None = auto knob resolves to in this process —
         the record an entry script prints next to its results."""
-        from . import pallas as _pallas
-
         return {
-            "pallas": _pallas.resolve(self),
             "flat_aligned": self.aligned_on(),
             "flat_packed": self.packed_on(),
-            "latency_donate": self.donate_on(),
-            "latency_staged_timing": self.staged_timing_on(),
             "flat_pipeline_batch": self.pipeline_batch(),
         }
 

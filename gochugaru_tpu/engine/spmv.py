@@ -223,16 +223,6 @@ class FrontierKernels:
         self.F_min = int(config.lookup_frontier_min)
         self._pk = dict(meta.packed)
         self._pko = dict(meta.packed_off)
-        # Pallas fused probe backend (engine/pallas.py): the point-run
-        # probes route through the ``runs`` kernel when the knob
-        # resolves on and the layout is single-shard — the sharded
-        # engine shard_maps the raw bodies, where the XLA chain must
-        # stay verbatim.  Per-call the offsets must also fit the
-        # VMEM-resident plan (an error otherwise, never a quiet
-        # return to the XLA bisect).
-        from . import pallas as _pallas
-
-        self._pls = (not meta.sharded) and _pallas.resolve(config)
         e_gates = (["cav", "ctx"] if meta.e_hascav else []) + (
             ["exp"] if meta.e_hasexp else []
         )
@@ -327,23 +317,10 @@ class FrontierKernels:
 
         steps = max(int(cap).bit_length(), 1)
         spec = self._pk.get(tbl_key)
-        shift = self._pko.get(off_key)
         col0 = _field0_reader(spec, w)
         offr = self._off_reader(off_key)
-        use_pls = self._pls
 
         def fn(off, off_a, tbl, keys):
-            if use_pls:
-                from . import pallas as _pallas
-
-                _pallas.require_vmem(
-                    off_key, off, off_a if shift is not None else None
-                )
-                return _pallas.fused_probe(
-                    (keys,), off, tbl, cap=cap, spec=spec,
-                    off_a=off_a if shift is not None else None,
-                    ashift=shift, mode="runs",
-                )
             size = (off.shape[0] - 1)  # single-shard layout (M=1)
             h = (mix32([keys], jnp) & jnp.uint32(size - 1)).astype(jnp.int32)
             start = offr(off, off_a, h)

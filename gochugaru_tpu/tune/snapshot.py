@@ -19,8 +19,6 @@ rules that read them):
 - ``pad``      — pinned-tier pad-waste ledger (utils/perf.py)
 - ``cost``     — per-tier expected dispatch cost (utils/admission.py)
 - ``bytes``    — gathered-bytes model + device-table placement split
-- ``pallas``   — fused-probe backend evidence: the one-pass byte-model
-  gauges prepare publishes (utils/perf.py ``publish_pallas_model``)
 - ``wall``     — last closed wall-ledger window's bucket fractions
 - ``chain``    — write-path delta-chain depth (store/group.py gauges:
   overlay rows, chain length in revisions, background compactions,
@@ -31,7 +29,6 @@ from __future__ import annotations
 
 from typing import Any, Dict, Optional
 
-from ..engine import pallas as _pallas
 from ..utils import metrics as _metrics
 from ..utils import perf as _perf
 
@@ -92,8 +89,6 @@ def collect_snapshot(
         cfg["latency_tiers"] = [int(t) for t in engine_config.latency_tiers]
         cfg["flat_packed"] = engine_config.flat_packed
         cfg["flat_packed_resolved"] = bool(engine_config.packed_on())
-        cfg["pallas"] = engine_config.pallas
-        cfg["pallas_resolved"] = bool(_pallas.resolve(engine_config))
         cfg["lsm_compact_min"] = int(engine_config.lsm_compact_min)
     if serve_config is not None:
         cfg["hold_max_s"] = float(serve_config.hold_max_s)
@@ -127,12 +122,6 @@ def collect_snapshot(
         snap["cache"] = c
 
     snap["pad"] = _perf.pad_stats(m)
-    snap["pallas"] = {
-        "bytes_per_check": float(m.gauge("perf.pallas.bytes_per_check")),
-        "bytes_saved_per_check": float(
-            m.gauge("perf.pallas.bytes_saved_per_check")
-        ),
-    }
     if cost is not None:
         snap["cost"] = cost.state()
 

@@ -29,11 +29,6 @@ Rules and their inputs:
 - ``flat_packed``    ← offline A/B byte models only (a live snapshot
   sees one layout; the counterfactual comes from scripts/tune.py's
   dual prepare, or the rule stays silent).
-- ``pallas``         ← the fused-probe one-pass byte model prepare
-  publishes (utils/perf.py ``publish_pallas_model``) against the XLA
-  chain's modeled traffic, vetoed by the feature probe and the
-  runtime degrade counter — the rule never proposes a backend the
-  engine cannot serve.
 - ``placement``      ← device-table placement split (engine/flat.py
   ``placement_split``) against the HBM budget.
 """
@@ -45,7 +40,6 @@ import math
 from dataclasses import dataclass, field, replace
 from typing import Any, Dict, List, Mapping, Optional, Tuple
 
-from ..engine import pallas as _pallas
 from ..engine.plan import EngineConfig
 from ..serve.batcher import ServeConfig
 
@@ -76,10 +70,6 @@ DEDUP_ON_FRAC = 0.05
 DEDUP_OFF_FRAC = 0.005
 #: pack-layout A/B margin: the cheaper layout must win by this much
 PACKED_MARGIN = 0.10
-#: fused-probe margin: the modeled one-pass saving must be at least
-#: this fraction of the XLA chain's bytes/check before the backend
-#: switch is worth proposing
-PALLAS_MARGIN = 0.10
 #: default per-device HBM budget the placement rule compares against
 HBM_BUDGET_BYTES = 4 << 30
 #: chain-depth rule: clamp for the host LSM materialization floor
@@ -491,36 +481,6 @@ def _rule_packed(snap):
     return desired, evidence, {"bytes_per_check_frac": round(rel, 4)}
 
 
-def _rule_pallas(snap):
-    """Propose the fused Pallas probe backend from the one-pass byte
-    model prepare publishes (utils/perf.publish_pallas_model gauges):
-    fused HBM bytes/check against the XLA chain's gather + decode
-    traffic.  The gauges exist only after a prepare with the knob on,
-    so a default (off) deployment never hears from this rule."""
-    pl = snap.get("pallas")
-    if not pl:
-        return None
-    fused = float(pl.get("bytes_per_check") or 0.0)
-    saved = float(pl.get("bytes_saved_per_check") or 0.0)
-    if fused <= 0:
-        return None  # no fused prepare measured this window: stay silent
-    xla = fused + saved
-    if xla <= 0:
-        return None
-    frac = saved / xla
-    if frac >= PALLAS_MARGIN:
-        desired, rel = True, -frac
-    elif frac <= 0.0:
-        desired, rel = False, 0.0
-    else:
-        return None  # within margin: not worth a backend change
-    evidence = (
-        f"one-pass byte model: fused {fused:.0f} vs XLA {xla:.0f}"
-        f" bytes/check ({frac:.0%} saved) — pallas={desired}"
-    )
-    return desired, evidence, {"bytes_per_check_frac": round(rel, 4)}
-
-
 def _rule_lsm_compact(snap):
     """Move the host LSM materialization floor off chain-depth
     telemetry (store/group.py ChainCompactor gauges): merge churn means
@@ -617,8 +577,6 @@ def _current_of(snap: Mapping[str, Any], target: Optional[TuneTarget],
             return tuple(int(t) for t in v) if v is not None else None
         if knob == "flat_packed":
             return cfg.get("flat_packed_resolved")
-        if knob == "pallas":
-            return cfg.get("pallas_resolved")
         if knob == "cache_max_bytes":
             return cfg.get("cache_max_bytes")
         if knob == "placement":
@@ -628,8 +586,6 @@ def _current_of(snap: Mapping[str, Any], target: Optional[TuneTarget],
         return tuple(target.engine.latency_tiers)
     if knob == "flat_packed":
         return bool(target.engine.packed_on())
-    if knob == "pallas":
-        return bool(_pallas.resolve(target.engine))
     if knob == "hold_max_s":
         return float(target.serve.hold_max_s)
     if knob == "dedup":
@@ -655,7 +611,6 @@ def propose(
     rules = (
         ("latency_tiers", "engine", lambda: _rule_tiers(snapshot)),
         ("flat_packed", "engine", lambda: _rule_packed(snapshot)),
-        ("pallas", "engine", lambda: _rule_pallas(snapshot)),
         ("lsm_compact_min", "engine", lambda: _rule_lsm_compact(snapshot)),
         ("hold_max_s", "serve", lambda: _rule_hold(snapshot)),
         ("dedup", "serve", lambda: _rule_dedup(snapshot)),
@@ -692,8 +647,6 @@ def apply_diff(target: TuneTarget, diff: TuneDiff) -> TuneTarget:
             )
         elif k.knob == "flat_packed":
             engine = replace(engine, flat_packed=bool(k.proposed))
-        elif k.knob == "pallas":
-            engine = replace(engine, pallas=bool(k.proposed))
         elif k.knob == "lsm_compact_min":
             engine = replace(engine, lsm_compact_min=int(k.proposed))
         elif k.knob == "hold_max_s":

@@ -60,14 +60,6 @@ Injection sites threaded through the tree (grep ``faults.fire``):
                              it IS one — so coverage armed on either
                              site reaches it)
     latency.dispatch         pinned small-batch dispatch (engine/latency.py)
-    pallas.dispatch          Pallas fused-probe dispatch (engine/device.py
-                             check paths + engine/latency.py pinned path;
-                             fires ONLY when EngineConfig.pallas resolves
-                             on, right after the site's own dispatch fault
-                             — a fused-kernel failure classifies through
-                             the same retry envelope and reroutes exactly
-                             like a latency-path one, which the breaker
-                             re-form chaos test proves)
     sharded.dispatch         sharded query partition (parallel/sharded.py)
     sharded.collective       shard_map kernel launch (parallel/sharded.py)
     watch.stream             per-update watch delivery (client.py)

@@ -491,109 +491,6 @@ def last_model() -> Optional[BytesModel]:
 
 
 # ---------------------------------------------------------------------------
-# Pallas one-pass delta model (engine/pallas.py fused probe backend)
-# ---------------------------------------------------------------------------
-
-#: block tables the fused kernel serves (pblock/psite sites in
-#: engine/flat.py) and the bucket-offset arrays it pins VMEM-resident.
-#: Tables outside this set (emission rows, csr slices, delta overlays)
-#: keep the XLA path and honestly show saved == 0
-_PALLAS_BLOCK_TBLS = frozenset(
-    {"ehx", "ehx_al", "tx", "clx", "pusx", "ovfx", "pfx", "usgx", "argx"}
-)
-_PALLAS_OFF_TBLS = frozenset(
-    {"eh_off", "th_off", "clh_off", "push_off", "ovfh_off", "pfh_off",
-     "usr_off", "arr_off"}
-)
-
-
-def pallas_bytes_model(dsnap) -> Dict[str, Dict[str, float]]:
-    """Per-table bytes-accessed before/after for the Pallas fused probe:
-    ``{table: {"xla": b, "pallas": b', "saved": b - b'}}``.
-
-    The model, stated so the tests can assert its structure (its
-    effect on a device is not measured):
-
-    - the XLA chain charges the gathered source bytes
-      (:func:`gathered_bytes_model`) PLUS one write+read of the decoded
-      int32 block per probed block table — the gather-boundary
-      intermediate XLA materializes between the block gather and the
-      compare/gate consumers (packed tables inflate it by the
-      int32-width/packed-lane ratio; that materialization is exactly
-      what "one HBM pass" removes);
-    - the fused kernel charges the raw block bytes ONCE (the bucket DMA)
-      and zero per-probe bytes for VMEM-resident bucket offsets/anchors
-      (``engine.pallas.vmem_plan``); offsets too big for the plan keep
-      their XLA charge (the kernel declines those sites);
-    - tables the kernel does not serve keep identical charges.
-    """
-    from ..engine.pallas import vmem_plan
-
-    base = gathered_bytes_model(dsnap)
-    meta = dsnap.flat_meta
-    arrs = dsnap.arrays
-    pk = dict(meta.packed) if meta is not None else {}
-    rc_off = {f"rc{ts}_off" for ts, _c, _f in getattr(meta, "rc_slots", ())}
-    rc_gx = {f"rc{ts}gx" for ts, _c, _f in getattr(meta, "rc_slots", ())}
-    resident = set(vmem_plan(arrs))
-    out: Dict[str, Dict[str, float]] = {}
-    for t, b in base.per_table.items():
-        if t in (_PALLAS_OFF_TBLS | rc_off):
-            # the anchor rides the off charge; resident iff both fit
-            ok = t in resident and (
-                t + "_a" not in arrs or t + "_a" in resident
-            )
-            saved = b if ok else 0.0
-            out[t] = {"xla": b, "pallas": b - saved, "saved": saved}
-            continue
-        if t in (_PALLAS_BLOCK_TBLS | rc_gx):
-            a = arrs.get(t)
-            spec = pk.get(t[:-3] if t.endswith("_al") else t)
-            if a is None:
-                out[t] = {"xla": b, "pallas": b, "saved": 0.0}
-                continue
-            if spec is not None:
-                w_log = int(spec[0])
-                lanes = spec[1]
-                isz = int(np.dtype(a.dtype).itemsize)
-                factor = (4.0 * w_log) / float(lanes * isz)
-            else:
-                factor = 1.0
-            inter = 2.0 * b * factor  # decoded block: one write + read
-            out[t] = {"xla": b + inter, "pallas": b, "saved": inter}
-            continue
-        out[t] = {"xla": b, "pallas": b, "saved": 0.0}
-    return out
-
-
-def publish_pallas_model(
-    dsnap, registry: Optional[_metrics.Metrics] = None
-) -> Optional[Dict[str, Dict[str, float]]]:
-    """Publish the fused-probe delta next to the base model:
-    ``perf.pallas.bytes_per_check`` / ``.bytes_saved_per_check`` totals
-    + per-table ``perf.pallas.saved.<table>`` gauges.  Called at prepare
-    when ``EngineConfig.pallas`` resolves on; never fails the prepare."""
-    try:
-        model = pallas_bytes_model(dsnap)
-    except Exception:
-        return None
-    m = registry or _metrics.default
-    m.clear_gauges("perf.pallas.")
-    m.set_gauge(
-        "perf.pallas.bytes_per_check",
-        sum(v["pallas"] for v in model.values()),
-    )
-    m.set_gauge(
-        "perf.pallas.bytes_saved_per_check",
-        sum(v["saved"] for v in model.values()),
-    )
-    for t, v in model.items():
-        if v["saved"]:
-            m.set_gauge(f"perf.pallas.saved.{t}", v["saved"])
-    return model
-
-
-# ---------------------------------------------------------------------------
 # pad-waste accounting (live vs padded lanes per pinned-tier dispatch)
 # ---------------------------------------------------------------------------
 
@@ -788,34 +685,23 @@ def roofline_columns(
     DeviceSnapshot (model computed here) or a precomputed
     bytes_per_check.
 
-    ``bytes_accessed_per_check`` is the ACTIVE backend's modeled HBM
-    traffic: when the prepare that produced ``dsnap`` resolved the
-    Pallas fused probe on, :func:`publish_pallas_model` left the fused
-    per-check bytes in the ``perf.pallas.bytes_per_check`` gauge and
-    the row carries that (plus the before/after delta in
-    ``pallas_bytes_saved_per_check``); otherwise it equals the XLA
-    gather model ``bytes_per_check`` and the delta column is absent —
-    so one bench emits the A and the B rows of the same model."""
+    ``bytes_accessed_per_check`` repeats ``bytes_per_check``: there is
+    one probe backend and one byte model."""
     if bytes_per_check is None:
         bytes_per_check = est_bytes_per_check(dsnap) if dsnap is not None else 0.0
     bw = measure_bandwidth(registry=registry)
     m = registry or _metrics.default
-    fused = m.gauge("perf.pallas.bytes_per_check")
-    saved = m.gauge("perf.pallas.bytes_saved_per_check")
-    eff = fused if fused > 0 else float(bytes_per_check)
-    achieved = eff * max(rate, 0.0) / 1e9
+    bpc = float(bytes_per_check)
+    achieved = bpc * max(rate, 0.0) / 1e9
     ceiling = float(bw.get("gbps") or 0.0)
     m.set_gauge("perf.achieved_gbps", achieved)
-    out = {
-        "bytes_per_check": round(float(bytes_per_check), 1),
-        "bytes_accessed_per_check": round(eff, 1),
+    return {
+        "bytes_per_check": round(bpc, 1),
+        "bytes_accessed_per_check": round(bpc, 1),
         "achieved_gbps": round(achieved, 3),
         "roofline_gbps": round(ceiling, 2),
         "roofline_frac": round(achieved / ceiling, 4) if ceiling else 0.0,
     }
-    if fused > 0:
-        out["pallas_bytes_saved_per_check"] = round(saved, 1)
-    return out
 
 
 # ---------------------------------------------------------------------------

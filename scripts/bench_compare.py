@@ -60,17 +60,11 @@ _LOWER_BETTER_UNITS = {"ms", "s", "seconds", "mb", "mib", "bytes", "gb"}
 #: ``probe_depth_after_compaction``: bench12's residual delta-chain
 #: overlay rows with the background compactor on — growth means the
 #: compactor stopped keeping probe depth bounded and writers are headed
-#: back toward the synchronous O(E) merge;
-#: ``bytes_accessed_per_check``: the perf ledger's modeled HBM traffic
-#: per check — the pallas fused probe exists to shrink it, so growth
-#: means a table fell out of the one-pass plan.  NOTE it must be listed
-#: here by full name: ``vmem_resident_bytes`` below must NOT inherit
-#: the generic ``_bytes`` lower-better reading)
+#: back toward the synchronous O(E) merge)
 _LOWER_BETTER_SUFFIXES = (
     "_ms", "_s", "_latency", "_bytes", "_rss_mb", "pad_fraction",
     "explain_overhead_frac", "decisions_dropped", "dispatches_per_lookup",
     "pad_waste_frac", "probe_depth_after_compaction",
-    "bytes_accessed_per_check",
 )
 #: suffixes that are HIGHER-better regardless of unit — checked FIRST,
 #: so the perf columns can't be misread by a unit heuristic
@@ -96,16 +90,10 @@ _LOWER_BETTER_SUFFIXES = (
 #: it as a latency; ``group_size_p50`` is bench12's achieved
 #: writes-per-group median — shrinking groups mean the committer
 #: stopped coalescing and every revision pays its machinery alone)
-#: (``vmem_resident_bytes`` is the pallas residency plan — MORE of the
-#: hot offset/anchor/ladder state pinned in VMEM is the win, and its
-#: raw "_bytes" suffix must not read as lower-better;
-#: ``bytes_saved_frac`` is the smoke's modeled one-pass saving as a
-#: fraction of the XLA pass — shrinking means fused coverage regressed)
 _HIGHER_BETTER_SUFFIXES = (
     "achieved_gbps", "roofline_frac", "hit_rate", "dedup_frac",
     "cache_speedup", "mixed_users_rate", "fleet_goodput_scaling",
     "tuned_vs_best_preset_goodput", "writes_per_s", "group_size_p50",
-    "vmem_resident_bytes", "bytes_saved_frac",
 )
 #: extra fields of a metric line promoted to their own comparison rows
 #: (the perf-attribution columns ride headline rows as extra fields —
@@ -117,7 +105,6 @@ _PROMOTED_FIELDS = (
     "true_rate", "p99_ms", "achieved_gbps", "roofline_frac", "pad_fraction",
     "cache_hit_rate", "explain_overhead_frac", "decisions_dropped",
     "mixed_users_rate", "dispatches_per_lookup", "failover_p99_ms",
-    "bytes_accessed_per_check", "vmem_resident_bytes",
 )
 #: boolean/one-shot rows that carry no trajectory signal
 _SKIP_UNITS = {"ok", "capture", "keys"}

@@ -43,7 +43,9 @@ def test_cpu_rehearsal_passes_and_says_cpu_on_every_line():
     last = lines[-2]
     assert last["event"] == "report" and last["device"] == device
     assert last["native_available"] is True
-    assert last["resolved"]["pallas"] is False
+    assert set(last["resolved"]) == {
+        "flat_aligned", "flat_packed", "flat_pipeline_batch",
+    }
     labels = [s["label"] for s in last["sections"]]
     assert labels == ["one-device", "mesh-1x4", "mesh-1x4-partitioned"]
     for sec in last["sections"]:
@@ -132,19 +134,15 @@ def test_engine_config_resolves_backend_keyed_choices(monkeypatch):
     from gochugaru_tpu.engine.plan import EngineConfig
 
     assert EngineConfig().resolved() == {
-        "pallas": False, "flat_aligned": False, "flat_packed": True,
-        "latency_donate": False, "latency_staged_timing": False,
+        "flat_aligned": False, "flat_packed": True,
         "flat_pipeline_batch": 0,
     }
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     assert EngineConfig().resolved() == {
-        "pallas": False, "flat_aligned": True, "flat_packed": True,
-        "latency_donate": True, "latency_staged_timing": True,
+        "flat_aligned": True, "flat_packed": True,
         "flat_pipeline_batch": 32_768,
     }
-    forced = EngineConfig(flat_aligned=False, latency_donate=False,
-                          flat_pipeline_batch=0, pallas=True)
-    assert forced.resolved()["pallas"] is True
+    forced = EngineConfig(flat_aligned=False, flat_pipeline_batch=0)
     assert forced.resolved()["flat_aligned"] is False
     assert forced.resolved()["flat_pipeline_batch"] == 0
 

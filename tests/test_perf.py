@@ -274,15 +274,11 @@ def test_roofline_columns_math(tmp_path, monkeypatch):
     p = tmp_path / "roofline.json"
     monkeypatch.setattr(perf, "ROOFLINE_CACHE_PATH", str(p))
     perf.measure_bandwidth(size_mb=2, reps=2)
-    # fresh registry: the pallas byte-model gauges are process-global,
-    # and an earlier test's fused prepare would otherwise override the
-    # XLA bytes_per_check as the "active backend" traffic
     cols = perf.roofline_columns(
         2_000_000.0, bytes_per_check=100.0, registry=metrics.Metrics()
     )
     assert cols["bytes_per_check"] == 100.0
     assert cols["bytes_accessed_per_check"] == 100.0
-    assert "pallas_bytes_saved_per_check" not in cols
     assert cols["achieved_gbps"] == round(100.0 * 2e6 / 1e9, 3)
     assert cols["roofline_frac"] == round(
         cols["achieved_gbps"] / cols["roofline_gbps"], 4
@@ -637,20 +633,3 @@ def test_bench_compare_host_bound_escape():
         mets = bc.metrics_of(p)
     assert mets["m"]["roofline_frac"] == 0.97
     assert mets["m.true_rate"]["roofline_frac"] == 0.97
-
-
-def test_bench_compare_pallas_column_directions():
-    """The pallas ledger columns are direction-aware from round one:
-    modeled HBM bytes per check shrinking is the fused kernel's whole
-    point, while MORE VMEM-resident hot state is the win — its raw
-    ``_bytes`` suffix must not fall into the lower-better unit bucket."""
-    bc = _bench_compare()
-    assert bc.lower_is_better(
-        "rbac_2hop_bulk_check_throughput.bytes_accessed_per_check", ""
-    )
-    assert not bc.lower_is_better("vmem_resident_bytes", "bytes")
-    assert not bc.lower_is_better(
-        "pallas_smoke_bytes_saved_frac", "fraction of XLA bytes/check"
-    )
-    for fld in ("bytes_accessed_per_check", "vmem_resident_bytes"):
-        assert fld in bc._PROMOTED_FIELDS

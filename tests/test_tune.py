@@ -102,47 +102,6 @@ def test_propose_quiet_on_thin_evidence():
     )
 
 
-def test_pallas_rule_proposes_from_byte_model_and_fixed_point():
-    """The pallas knob follows the flat_packed discipline: evidence is
-    the one-pass byte model prepare publishes (gauges in the measured
-    registry), the proposal carries the saved fraction, and applying it
-    reaches the fixed point (re-propose is empty because the tuned
-    target resolves to the proposed backend)."""
-    m = metrics.Metrics()
-    m.set_gauge("perf.pallas.bytes_per_check", 300.0)
-    m.set_gauge("perf.pallas.bytes_saved_per_check", 900.0)  # 75% saved
-    eng = EngineConfig(pallas=False)
-    snap = collect_snapshot(m, engine_config=eng, serve_config=ServeConfig())
-    assert snap["config"]["pallas_resolved"] is False
-    target = TuneTarget(engine=eng, serve=ServeConfig(), cache_bytes=None)
-    diff = propose(snap, target)
-    kd = next(k for k in diff.knobs if k.knob == "pallas")
-    assert kd.layer == "engine" and kd.proposed is True
-    assert "byte model" in kd.evidence
-    assert kd.predicted["bytes_per_check_frac"] == pytest.approx(-0.75)
-    tuned = apply_diff(target, diff)
-    assert tuned.engine.pallas is True
-    assert not propose(snap, tuned), "re-propose after apply must be empty"
-
-
-def test_pallas_rule_silent_without_model():
-    """With no fused prepare measured (gauges unset — every default
-    deployment, since pallas=None resolves off) the rule stays silent
-    rather than guessing."""
-    m2 = metrics.Metrics()
-    snap2 = collect_snapshot(
-        m2, engine_config=EngineConfig(), serve_config=ServeConfig()
-    )
-    assert not any(
-        k.knob == "pallas"
-        for k in propose(
-            snap2,
-            TuneTarget(engine=EngineConfig(), serve=ServeConfig(),
-                       cache_bytes=None),
-        ).knobs
-    )
-
-
 def test_tiers_rule_emits_non_pow2():
     """The ladder rule quantizes to 64-lane multiples, not powers of
     two: a tier whose p90 occupancy is 131 proposes 320 (p90 × 2.0
