@@ -10,20 +10,34 @@ of the AOT-pinned latency ladder (engine/latency.py), so the device
 always sees one of the shapes it already has a pinned executable for —
 no retrace by construction, whatever the traffic does.
 
-Two daemon threads per batcher, so batch FORMATION overlaps in-flight
-device DISPATCH (form tier N+1 while N runs):
+One daemon thread per batcher, the **dispatcher**, and one rule for the
+moment a batch is formed: WHEN THE DISPATCHER CAN TAKE IT.  A formed
+batch is frozen — nothing that arrives later can join it — so nothing
+is formed ahead of the dispatch that will run it: while a batch
+dispatches, submissions stay in the client queues, where later arrivals
+still coalesce with them, and the instant the dispatcher comes back it
+forms ONE batch from everything queued and runs it.  (Formation is
+Python under the interpreter lock: on a thread of its own it would
+overlap nothing but the dispatcher's device wait, and every batch it
+froze behind the running one would cost each request one more cycle —
+measured, PERF.md §6 PR 30.)
 
-- the **former** watches the submission queues and flushes a batch when
-  (a) the target tier slot fills, (b) the deadline-aware hold-back says
-  waiting longer would miss the earliest queued deadline (expected cost
-  per tier from the SHARED ``utils/admission.CostModel`` — the same
-  estimate the deadline shed uses, no duplicated EWMA), or (c) the
-  max-hold timer expires.  Formation drains per-client FIFO queues
-  round-robin — **per-client fair admission**: one bulk caller cannot
-  starve interactive clients out of a formed batch, because every
-  client with pending work gets a turn per rotation.
-- the **dispatcher** pops formed batches from a depth-1 queue and runs
-  them through the injected dispatch callables (the client's
+- **formation** (``form_batch``, the top of the dispatcher's loop)
+  flushes when (a) the target tier slot fills, (b) the deadline-aware
+  hold-back says waiting longer would miss the earliest queued deadline
+  (expected cost per tier from the SHARED
+  ``utils/admission.CostModel`` — the same estimate the deadline shed
+  uses, no duplicated EWMA), or (c) the max-hold timer expires.  An
+  idle dispatcher blocks there until one of them holds, so a lone
+  request at light load goes out on the hold-back's clock; a dispatcher
+  that returns to a queue whose decision is already true never waits
+  (``serve.formed_backlogged`` counts those batches — the dispatcher,
+  not the hold-back, chose the moment).  Formation drains per-client
+  FIFO queues round-robin — **per-client fair admission**: one bulk
+  caller cannot starve interactive clients out of a formed batch,
+  because every client with pending work gets a turn per rotation.
+- **dispatch** (``dispatch_batch``) runs the batch through the injected
+  dispatch callables (the client's
   ``_evaluate_rels``/``_evaluate_columns`` — breaker-gated, classified
   failures, host-oracle resolution), then slices verdicts back onto
   each submission's future.
@@ -33,7 +47,7 @@ the pending-check depth past ``queue_max`` raises ``ShedError`` (an
 ``UnavailableError``, so the caller's retry envelope backs off — the
 same contract the admission gate states), and a submission whose
 deadline cannot cover the expected queue+dispatch cost sheds before it
-ever queues.  When the latency-path CircuitBreaker is OPEN, the former
+ever queues.  When the latency-path CircuitBreaker is OPEN, formation
 RE-FORMS for the batch path: target sizing switches from the pinned
 tier ladder to ``batch_path_max`` (re-tier, don't replay the pinned
 shapes), and the client evaluation reroutes onto the throughput path —
@@ -42,7 +56,7 @@ resolves exactly once; rejected futures re-submit through the caller's
 envelope).
 
 Fault sites ``batcher.form`` (fires BEFORE any dequeue — a form fault
-leaves the queue intact and the former retries) and
+leaves the queue intact and formation retries) and
 ``batcher.dispatch`` (classified onto the batch's futures) ride the
 chaos registry (utils/faults.py).
 """
@@ -50,7 +64,6 @@ chaos registry (utils/faults.py).
 from __future__ import annotations
 
 import heapq
-import queue as _queue
 import threading
 import time
 from collections import OrderedDict, deque
@@ -94,9 +107,6 @@ class ServeConfig:
     #: ask the client evaluation for the pinned latency path (engines
     #: whose latency path declines still serve on the throughput path)
     use_latency: bool = True
-    #: formed batches buffered between former and dispatcher: 1 means
-    #: one batch forms while one dispatches (the overlap)
-    form_queue_depth: int = 1
     #: seconds close() waits for the drain before rejecting leftovers
     drain_timeout_s: float = 10.0
     #: check deduplication (engine/vcache.py): identical checks in one
@@ -105,9 +115,8 @@ class ServeConfig:
     #: in flight parks on that batch's resolution (no queue slot, no
     #: tier lane), and the residual unique misses land on the SMALLEST
     #: covering pinned tier — effective tier occupancy counts unique
-    #: work and padding shrinks with it, while the former keeps forming
-    #: the next batch from the queue in parallel.  False restores the
-    #: pre-dedup former byte-for-byte (the bench A/B baseline lever)
+    #: work and padding shrinks with it.  False restores the pre-dedup
+    #: formation byte-for-byte (the bench A/B baseline lever)
     dedup: bool = True
 
 
@@ -256,10 +265,11 @@ REQUEST_LATENCY_BUCKETS = (
 
 
 class MicroBatcher:
-    """The former/dispatcher pair.  Dispatch is injected so the batcher
-    serves any engine shape (single-chip, latency-mode, partitioned
-    mesh) and unit tests can drive formation deterministically
-    (``start=False`` + ``form_batch``/``dispatch_batch``).
+    """Formation plus the dispatcher thread that runs it.  Dispatch is
+    injected so the batcher serves any engine shape (single-chip,
+    latency-mode, partitioned mesh) and unit tests can drive formation
+    deterministically (``start=False`` + ``form_batch``/
+    ``dispatch_batch``).
 
     ``cost`` is the SHARED ``utils/admission.CostModel`` (the client's
     ``AdmissionController.cost``): the hold-back reads per-tier
@@ -330,24 +340,13 @@ class MicroBatcher:
         self._dl_heap: List[Tuple[float, int, _Submission]] = []
         self._dl_seq = 0
         self._closed = False
-        self._form_q: "_queue.Queue" = _queue.Queue(
-            maxsize=max(1, self.config.form_queue_depth)
-        )
-        self._threads: List[threading.Thread] = []
-        self._former_t: Optional[threading.Thread] = None
         self._disp_t: Optional[threading.Thread] = None
         if start:
-            self._former_t = threading.Thread(
-                target=self._former_loop,
-                name="gochugaru-serve-former", daemon=True,
-            )
             self._disp_t = threading.Thread(
                 target=self._dispatcher_loop,
                 name="gochugaru-serve-dispatcher", daemon=True,
             )
-            self._threads = [self._former_t, self._disp_t]
-            for t in self._threads:
-                t.start()
+            self._disp_t.start()
 
     # -- submission ------------------------------------------------------
     @property
@@ -441,13 +440,14 @@ class MicroBatcher:
                     heapq.heappush(
                         self._dl_heap, (deadline, self._dl_seq, sub)
                     )
-                # wake the former only when this submission can CHANGE
-                # its decision: first work after idle, a full target
-                # tier, or a new deadline that may tighten the
-                # hold-back.  Every other submission rides the former's
-                # own timed wait — at tens of thousands of
-                # submissions/s, notify-per-submit is the front-end's
-                # biggest avoidable cost
+                # wake a dispatcher blocked in form_batch only when this
+                # submission can CHANGE its decision: first work after
+                # idle, a full target tier, or a new deadline that may
+                # tighten the hold-back.  Every other submission rides
+                # its own timed wait (and while it dispatches nobody
+                # waits) — at tens of thousands of submissions/s,
+                # notify-per-submit is the front-end's biggest avoidable
+                # cost
                 if (
                     was_empty or deadline is not None
                     or self._depth >= self._top
@@ -457,7 +457,7 @@ class MicroBatcher:
             # shed bookkeeping OUTSIDE the condition lock: the spike-
             # threshold-crossing note() spawns an incident capture
             # thread, and that spawn must not serialize submitters and
-            # the former/dispatcher loops on the hottest lock at peak
+            # the dispatcher's formation on the hottest lock at peak
             # load (same hoist as the admission gate's shed path)
             _trace.note_anomaly("shed")
             span.event(
@@ -524,27 +524,39 @@ class MicroBatcher:
 
     def form_batch(self) -> Optional[_FormedBatch]:
         """Block until a batch is due, then form and return it (None
-        when closed and drained).  The former thread's body; tests call
-        it directly for deterministic formation."""
+        when closed and drained).  The top of the dispatcher's loop —
+        called only when the caller can dispatch what it returns, so no
+        batch is ever frozen behind a running one; tests call it
+        directly for deterministic formation."""
+        # never blocked below = the flush decision was already true when
+        # the dispatcher came back for work: backpressure, not the
+        # hold-back, chose this batch's moment
+        backlogged = True
         with self._cond:
             while True:
                 if self._depth == 0:
                     if self._closed:
                         return None
-                    self._cond.wait(0.05)
+                    backlogged = False
+                    # nothing queued: the dispatcher has nothing to run
+                    with _trace.stage("serve.idle", registry=self._m):
+                        self._cond.wait(0.05)
                     continue
                 now = time.perf_counter()
                 flush, reason, wait_s = self._flush_decision_locked(now)
                 if not flush:
+                    backlogged = False
                     # hold-back with work queued: the wall ledger calls
-                    # this queue-wait (submissions sit while the former
-                    # deliberately holds) — reported around the wait so
-                    # the 21× question shows up as a bucket, not idle
-                    self._cond.wait(wait_s)
-                    _perf.report_wall("queue_wait", now, time.perf_counter())
+                    # this queue-wait (submissions sit while an idle
+                    # dispatcher deliberately holds) — reported around
+                    # the wait so the 21× question shows up as a bucket,
+                    # not idle
+                    with _trace.stage("serve.idle", wall="queue_wait",
+                                      registry=self._m):
+                        self._cond.wait(wait_s)
                     continue
                 # the injection point sits BEFORE any dequeue: a form
-                # fault leaves every submission queued — the former
+                # fault leaves every submission queued — formation
                 # pauses and retries, zero requests lost
                 try:
                     faults.fire("batcher.form")
@@ -557,7 +569,11 @@ class MicroBatcher:
                     continue
                 with _trace.stage("serve.form", wall="form",
                                   registry=self._m):
-                    return self._form_locked(reason, now)
+                    batch = self._form_locked(reason, now)
+                break
+        if backlogged and batch.subs:
+            self._m.inc("serve.formed_backlogged")
+        return batch
 
     def _form_locked(self, reason: str, now: float) -> _FormedBatch:
         cfg = self.config
@@ -668,9 +684,11 @@ class MicroBatcher:
         if not batch.subs:
             return
         t0 = time.perf_counter()
-        # formed→dispatch-start is the formed batch waiting for this
-        # thread (``queue_wait`` to the wall ledger; timer only, no child
-        # span: it precedes the root below).  The dispatch window itself
+        # formation-start→dispatch-start: the hand-over from formation to
+        # dispatch — the forming itself and the loop's step between the
+        # two, one thread, so no batch waits here for another's dispatch
+        # (``queue_wait`` to the wall ledger; timer only, no child span:
+        # it precedes the root below).  The dispatch window itself
         # reports as ``filter`` (host concat/slice/settle) with the
         # device stages — reported by the latency path from the same
         # stamps its budget uses — overlaying it at higher priority, so
@@ -835,67 +853,15 @@ class MicroBatcher:
             sp.end()
 
     # -- threads ---------------------------------------------------------
-    def _reject_batch(self, batch: _FormedBatch, err: BaseException) -> None:
-        now = time.perf_counter()
-        for s in batch.subs:
-            if not s.future.done():
-                s.future._reject(err, now)
-
-    def _former_loop(self) -> None:
+    def _dispatcher_loop(self) -> None:
         try:
             while True:
                 batch = self.form_batch()
                 if batch is None:
-                    break
-                # hand off without blocking forever: if the dispatcher
-                # died, this thread — not close(), which can't reach an
-                # in-hand batch — must settle the batch's futures.  The
-                # handoff wait (former blocked behind a busy dispatcher)
-                # is attributed to the ``form`` wall bucket: it is a
-                # formation stall, and leaving it to the idle residual
-                # would make the tuner read dispatch backpressure as
-                # headroom
-                t_h0 = time.perf_counter()
-                while True:
-                    try:
-                        self._form_q.put(batch, timeout=0.25)
-                        break
-                    except _queue.Full:
-                        d = self._disp_t
-                        if d is not None and not d.is_alive():
-                            self._reject_batch(batch, UnavailableError(
-                                "serve dispatcher thread died"
-                            ))
-                            break
-                _perf.report_wall("form", t_h0, time.perf_counter())
-        except BaseException:  # never leave submitters hanging on a
-            self._emergency_stop()  # dead former — close() rejects them
-            raise
-        finally:
-            try:  # drain sentinel; a full queue is fine — the
-                self._form_q.put_nowait(None)  # dispatcher also polls
-            except _queue.Full:  # _closed + former-dead as its exit
-                pass
-
-    def _dispatcher_loop(self) -> None:
-        try:
-            while True:
-                try:
-                    # nothing formed yet: the dispatcher has nothing to run
-                    with _trace.stage("serve.idle", registry=self._m):
-                        batch = self._form_q.get(timeout=0.25)
-                except _queue.Empty:
-                    # sentinel-less exit: a dead/finished former sends
-                    # nothing more, so closed + empty queue = done
-                    f = self._former_t
-                    if self._closed and (f is None or not f.is_alive()):
-                        return
-                    continue
-                if batch is None:
                     return
                 self.dispatch_batch(batch)
-        except BaseException:
-            self._emergency_stop()
+        except BaseException:  # never leave submitters hanging on a
+            self._emergency_stop()  # dead dispatcher — close() rejects
             raise
 
     def _emergency_stop(self) -> None:
@@ -907,10 +873,11 @@ class MicroBatcher:
         """Swap the serve config atomically (the online tuner's apply
         path).  ServeConfig is frozen and ``self.config`` is read fresh
         at every decision point, so a single attribute store is the
-        whole transaction; the former is woken so a SHORTER hold-back
-        takes effect on the batch it is currently holding rather than
-        one hold later.  Dedup toggles the same way: the singleflight
-        window object persists, ``config.dedup`` gates its use."""
+        whole transaction; a dispatcher blocked in formation is woken so
+        a SHORTER hold-back takes effect on the batch it is currently
+        holding rather than one hold later.  Dedup toggles the same way:
+        the singleflight window object persists, ``config.dedup`` gates
+        its use."""
         if config.batch_path_max < self._top:
             raise ValueError("batch_path_max must cover the top tier")
         self.config = config
@@ -918,7 +885,7 @@ class MicroBatcher:
             self._cond.notify_all()
 
     def close(self) -> None:
-        """Drain: flush everything queued, stop both threads, reject
+        """Drain: flush everything queued, stop the dispatcher, reject
         any straggler futures (classified, so callers back off rather
         than hang)."""
         with self._cond:
@@ -926,16 +893,9 @@ class MicroBatcher:
                 return
             self._closed = True
             self._cond.notify_all()
-        for t in self._threads:
-            t.join(timeout=self.config.drain_timeout_s)
+        if self._disp_t is not None:
+            self._disp_t.join(timeout=self.config.drain_timeout_s)
         leftovers: List[_Submission] = []
-        while True:  # formed-but-undispatched batches (a dead dispatcher)
-            try:
-                b = self._form_q.get_nowait()
-            except _queue.Empty:
-                break
-            if b is not None:
-                leftovers.extend(s for s in b.subs if not s.future.done())
         if self._sf is not None:
             # a window left open by a killed dispatcher: fail its parked
             # futures closed instead of stranding them

@@ -30,7 +30,8 @@ class ServingHandle:
     """One continuous-batching front-end over one Client, pinned to one
     consistency strategy (every formed batch evaluates at a single
     snapshot).  Context-manager friendly: closing drains the queue and
-    stops the former/dispatcher threads."""
+    stops the dispatcher thread (which forms each batch itself, at the
+    moment it can run it)."""
 
     def __init__(
         self, client, cs, config: Optional[ServeConfig] = None,

@@ -65,7 +65,8 @@ Injection sites threaded through the tree (grep ``faults.fire``):
     watch.stream             per-update watch delivery (client.py)
     batcher.form             micro-batch formation (serve/batcher.py; a
                              form fault leaves the queue INTACT — the
-                             former retries, zero requests lost)
+                             dispatcher retries the formation, zero
+                             requests lost)
     batcher.dispatch         formed-batch dispatch (serve/batcher.py;
                              classified onto the futures, so the
                              submitters' retry envelopes re-submit)

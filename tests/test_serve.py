@@ -314,6 +314,259 @@ def test_close_rejects_undispatched():
 
 
 # ---------------------------------------------------------------------------
+# the moment a batch is formed: when the dispatcher can take it
+# ---------------------------------------------------------------------------
+
+_FLUSHES = ("full", "deadline", "maxhold", "drain")
+
+
+class _HeldDispatch:
+    """A ``dispatch_cols`` that records the resource column of every
+    call and holds each of its first ``hold`` calls until ``step()``
+    lets one through (``die`` makes the first held call raise a
+    BaseException on its way out — a dispatcher crash)."""
+
+    def __init__(self, hold=0, die=False):
+        self.calls = []
+        self.hold = hold
+        self.die = die
+        self._gate = threading.Semaphore(0)
+
+    def __call__(self, q_res, q_perm, q_subj, latency, span):
+        self.calls.append(q_res.tolist())
+        if len(self.calls) <= self.hold:
+            assert self._gate.acquire(timeout=10.0), "never released"
+            if self.die:
+                raise SystemExit("simulated dispatcher death")
+        return np.zeros(q_res.shape[0], bool)
+
+    def step(self):
+        self._gate.release()
+
+    def wait_calls(self, n):
+        deadline = time.perf_counter() + 10.0
+        while len(self.calls) < n:
+            assert time.perf_counter() < deadline, (n, self.calls)
+            time.sleep(0.001)
+
+
+def _held_batcher(reg, held, **cfg):
+    return MicroBatcher(
+        tiers=(256, 1024), cost=CostModel(), registry=reg,
+        config=ServeConfig(**cfg), dispatch_cols=held,
+    )
+
+
+def _submit_ids(b, ids, ctx=None):
+    """One single-check submission an id, each from a client of its own
+    (distinct rows: nothing parks on the in-flight singleflight)."""
+    out = []
+    for i in ids:
+        col = np.full(1, i, np.int32)
+        out.append(b.submit_columns(f"c{i}", col, col, col, ctx=ctx))
+    return out
+
+
+def _formed(reg):
+    return sum(reg.counter(f"serve.flush_{r}") for r in _FLUSHES)
+
+
+def test_backpressure_coalesces_queued_waves():
+    """While a dispatch runs, later arrivals keep coalescing in the
+    queues: wave one is dispatching and held, waves two and three
+    arrive a hold-back apart behind it, and the batch formed when the
+    dispatcher comes back holds waves two AND three (a hold-back-driven
+    former froze wave two alone, and wave three behind it)."""
+    reg = metrics.Metrics()
+    held = _HeldDispatch(hold=1)
+    b = _held_batcher(reg, held, hold_max_s=0.002)
+    try:
+        futs = _submit_ids(b, [1])
+        held.wait_calls(1)
+        for wave in (2, 3):
+            futs += _submit_ids(b, range(wave * 10, wave * 10 + 3))
+            # far past the hold-back: a former that flushed on its own
+            # clock would have frozen this wave by now
+            time.sleep(0.02)
+            # nothing is formed behind the running batch — the wave is
+            # still in the client queues, where the next one joins it
+            assert _formed(reg) == len(held.calls) == 1
+            assert b.depth == 3 * (wave - 1)
+        held.step()
+        for f in futs:
+            assert f.result(timeout=10.0).shape == (1,)
+    finally:
+        b.close()
+    assert len(held.calls) == 2, held.calls
+    assert held.calls[0] == [1]
+    assert sorted(held.calls[1]) == [20, 21, 22, 30, 31, 32]
+    assert _formed(reg) == reg.counter("serve.batches") == 2
+    assert reg.counter("serve.checks") == 7
+    # batch one went out on an idle dispatcher's hold-back, batch two
+    # the moment the dispatcher came back
+    assert reg.counter("serve.formed_backlogged") == 1
+
+
+def test_formed_backlogged_share_under_backpressure():
+    """``serve.formed_backlogged`` counts the batches whose moment the
+    dispatcher chose (their flush decision was already true when it
+    came back): every batch behind a busy dispatcher, none that an idle
+    one waited for — n busy cycles read (n - 1) / n."""
+    reg = metrics.Metrics()
+    held = _HeldDispatch(hold=4)
+    b = _held_batcher(reg, held, hold_max_s=0.002)
+    try:
+        futs = _submit_ids(b, [1])
+        held.wait_calls(1)
+        for cycle in (2, 3, 4):
+            futs += _submit_ids(b, [cycle])
+            time.sleep(0.02)  # due, and waiting for the dispatcher
+            held.step()
+            held.wait_calls(cycle)
+        held.step()
+        for f in futs:
+            f.result(timeout=10.0)
+    finally:
+        b.close()
+    assert held.calls == [[1], [2], [3], [4]]
+    assert reg.counter("serve.batches") == 4
+    assert reg.counter("serve.formed_backlogged") == 3
+
+
+@pytest.mark.parametrize("n, hold_max_s, reason", [
+    (1, 0.05, "maxhold"),  # a lone request goes out on the hold-back
+    (256, 2.0, "full"),    # a full tier goes out at once
+])
+def test_light_load_dispatch_moment_unchanged(n, hold_max_s, reason):
+    """An idle dispatcher changes nothing: the flush decision alone
+    picks the moment a partial batch goes out, and no batch counts as
+    backlogged."""
+    reg = metrics.Metrics()
+    held = _HeldDispatch()
+    b = MicroBatcher(
+        tiers=(256,), cost=CostModel(), registry=reg,
+        config=ServeConfig(hold_max_s=hold_max_s), dispatch_cols=held,
+    )
+    try:
+        time.sleep(0.1)  # the dispatcher is idle, blocked in formation
+        cols = np.arange(n, dtype=np.int32)
+        t0 = time.perf_counter()
+        fut = b.submit_columns("c", cols, cols, cols)
+        assert fut.result(timeout=10.0).shape == (n,)
+        took = time.perf_counter() - t0
+    finally:
+        b.close()
+    if reason == "maxhold":
+        assert hold_max_s <= took < hold_max_s + 0.25, took
+    else:
+        assert took < 0.5, f"a full tier waited {took:.3f}s"
+    assert reg.counter(f"serve.flush_{reason}") == _formed(reg) == 1
+    assert reg.counter("serve.batches") == 1
+    assert reg.counter("serve.formed_backlogged") == 0
+    snap = reg.snapshot()
+    assert snap["serve.formed_wait_s.count"] == 1  # one sample a batch
+    assert snap["serve.idle_s.count"] >= 1
+
+
+def test_deadline_passing_behind_busy_dispatcher_rejects_at_formation():
+    """A deadline that passes while the dispatcher is busy is found at
+    formation, when the dispatcher comes back: rejected there, and no
+    lane of the next batch is spent on it."""
+    reg = metrics.Metrics()
+    held = _HeldDispatch(hold=1)
+    b = _held_batcher(reg, held)
+    try:
+        first = _submit_ids(b, [1])
+        held.wait_calls(1)
+        dead = _submit_ids(b, [7], ctx=background().with_timeout(0.03))
+        live = _submit_ids(b, [8])
+        time.sleep(0.1)
+        assert not dead[0].done()  # still queued: nobody formed it
+        held.step()
+        for f in first + live:
+            f.result(timeout=10.0)
+        with pytest.raises(DeadlineExceededError):
+            dead[0].result(timeout=10.0)
+    finally:
+        b.close()
+    assert held.calls == [[1], [8]]
+    assert reg.counter("serve.deadline_expired") == 1
+    assert reg.counter("serve.checks") == 2
+
+
+@pytest.mark.filterwarnings(
+    "ignore::pytest.PytestUnhandledThreadExceptionWarning"
+)
+@pytest.mark.parametrize("how", ["drains", "rejects", "dispatcher_dies"])
+def test_close_behind_held_dispatch_strands_no_future(how):
+    """close() while a dispatch is held, with work queued behind it: a
+    dispatch that finishes inside ``drain_timeout_s`` lets the queue
+    drain; one that does not gets its queue rejected at the timeout;
+    and a dispatcher that dies on the way out rejects its own batch and
+    the queue behind it.  No future is left unsettled."""
+    reg = metrics.Metrics()
+    held = _HeldDispatch(hold=1, die=how == "dispatcher_dies")
+    b = _held_batcher(reg, held, drain_timeout_s=0.3 if how == "rejects"
+                      else 10.0)
+    first = _submit_ids(b, [1])
+    held.wait_calls(1)
+    queued = _submit_ids(b, [2, 3, 4])
+    assert _formed(reg) == 1 and b.depth == 3
+    t0 = time.perf_counter()
+    if how == "rejects":
+        b.close()  # the held dispatch outlasts the drain timeout
+        took = time.perf_counter() - t0
+        assert 0.3 <= took < 0.3 + 1.0, took
+        for f in queued:
+            with pytest.raises(UnavailableError):
+                f.result(timeout=1.0)
+        held.step()
+        first[0].result(timeout=10.0)  # the dispatch itself completes
+        assert len(held.calls) == 1
+    elif how == "drains":
+        closer = threading.Thread(target=b.close)
+        closer.start()
+        time.sleep(0.05)
+        held.step()
+        closer.join(timeout=10.0)
+        assert not closer.is_alive()
+        for f in first + queued:
+            f.result(timeout=1.0)
+        assert sorted(held.calls[1]) == [2, 3, 4]
+        assert reg.counter("serve.flush_drain") == 1
+    else:
+        held.step()
+        for f in first + queued:
+            with pytest.raises(UnavailableError):
+                f.result(timeout=10.0)
+        assert reg.counter("serve.thread_crashes") == 1
+        assert len(held.calls) == 1
+    with pytest.raises(UnavailableError):
+        _submit_ids(b, [9])
+    assert all(f.done() for f in first + queued)
+    b.close()
+
+
+def test_form_fault_retried_with_no_request_lost():
+    """``batcher.form`` fires before any dequeue: the dispatcher's
+    formation pauses and retries, and every submission is dispatched
+    exactly once."""
+    reg = metrics.Metrics()
+    held = _HeldDispatch()
+    b = _held_batcher(reg, held)
+    try:
+        with faults.default.armed("batcher.form", times=3):
+            futs = _submit_ids(b, range(40, 52))
+            for f in futs:
+                assert f.result(timeout=10.0).shape == (1,)
+    finally:
+        b.close()
+    assert reg.counter("serve.form_faults") == 3
+    assert sorted(i for c in held.calls for i in c) == list(range(40, 52))
+    assert reg.counter("serve.checks") == 12
+
+
+# ---------------------------------------------------------------------------
 # no-retrace across formed batches (the pin-reuse harness)
 # ---------------------------------------------------------------------------
 
