@@ -2,9 +2,10 @@
 """Check BENCHMARK.json against the rules that refuse a manifest before
 any run: the characters and lengths of names, layers, units and one-line
 texts, that every cell of a per-layer metric reports the end-to-end metric
-it moves, and that every file a cell names exists.  Run before every chip
-call: ``python3 chipbench/check_manifest.py`` exits non-zero with the
-faults listed.
+it moves, and that every file a cell names exists (its configuration, its
+traffic mix, the mix's entry point under entries/, each metric's reader).
+Run before every chip call: ``python3 chipbench/check_manifest.py`` exits
+non-zero with the faults listed.
 """
 
 from __future__ import annotations
@@ -29,6 +30,20 @@ KEYS = {
 
 def one_line(s) -> bool:
     return isinstance(s, str) and 1 <= len(s) <= 200 and not re.search(r"[\n\r\t]", s)
+
+
+def find(paths: list, kind: str, file: str):
+    """The first <path>/<kind>/<file> that exists, or None."""
+    for p in paths:
+        path = os.path.join(ROOT, p, kind, file)
+        if os.path.isfile(path):
+            return path
+    return None
+
+
+def entry_of(mix: str) -> str:
+    with open(mix) as f:
+        return str(json.load(f).get("entry", ""))
 
 
 def faults(m: dict) -> list:
@@ -70,10 +85,16 @@ def faults(m: dict) -> list:
     for w in m["workloads"]:
         if w["config"] not in configs:
             bad(f"cell {w['name']} config", w["config"])
-        if not NAME.match(w["traffic"]) or not any(
-                os.path.isfile(os.path.join(ROOT, p, "traffic", w["traffic"] + ".json"))
-                for p in m["paths"]):
+        mix = NAME.match(w["traffic"]) and find(
+            m["paths"], "traffic", w["traffic"] + ".json")
+        if not mix:
             bad(f"cell {w['name']} traffic file", w["traffic"])
+        else:
+            entry = entry_of(mix)
+            if not NAME.match(entry) or entry.startswith("_") or not find(
+                    m["paths"], "entries", entry + ".py"):
+                bad(f"cell {w['name']} traffic {w['traffic']} entry file",
+                    f"entries/{entry}.py")
         if w["chips"] not in (1, 4) or not one_line(w["why"]):
             bad(f"cell {w['name']} chips/why", (w["chips"], w["why"]))
     for name in set(configs) - {w["config"] for w in m["workloads"]}:
@@ -105,8 +126,7 @@ def faults(m: dict) -> list:
         for cell in e.get("workloads", cells):
             if not reported(e2e[e["moves"]], cell):
                 bad(f"{e['name']} moves {e['moves']}, which is not reported in", cell)
-        if not any(os.path.isfile(os.path.join(ROOT, p, "layers", e["name"] + ".py"))
-                   for p in m["paths"]):
+        if not find(m["paths"], "layers", e["name"] + ".py"):
             bad(f"{e['name']} reader file", f"layers/{e['name']}.py")
     for cell in cells:
         if not any(reported(e, cell) for e in m["end_to_end"] if e["name"] != "setup_s"):

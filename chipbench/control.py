@@ -7,7 +7,9 @@ revision, never stale): ``stale`` puts the plain reference in the program's
 place, reading a world that lacks the newest 1 % of the edges imported last.
 ``flipped`` and ``short`` are the faults a cell of this benchmark can have,
 planted under the harness: one answer of one request altered where it is
-produced, and half of a request's answers left out.
+produced, and half of a request's answers left out.  All three go through
+the cell's entry point (``entries/<entry>.py``): its ``reference`` answers a
+request of its type, its ``flipped`` / ``short`` alter an answer of its type.
 
     python3 chipbench/control.py --control stale --workload <cell> --seed <n> --seconds <s>
 
@@ -34,27 +36,24 @@ class StaleReference:
         res, subj = w[mod.NEWEST]
         kept = res.shape[0] - max(int(res.shape[0] * STALE_SHARE), 1)
         stale = {**w, mod.NEWEST: (res[:kept], subj[:kept])}
-        self.check = mod.reference(stale, cell["sizes"])
+        self.entry = cell["entry"].reference(cell, stale)
         say("control", kind="stale", hidden_edges=int(res.shape[0] - kept),
             of=mod.NEWEST)
 
     def first_answer(self, rels) -> float:
         return 0.0
 
-    def entry(self, req):
-        return self.check(req.res, req.subj).tolist()
-
     def close(self) -> None:
         pass
 
 
-def broken(alter):
-    """The program, with ``alter(answers)`` applied to the answers of the
-    pool's first request wherever the window returns them."""
+def broken(fault: str):
+    """The program, with the entry's ``fault`` applied to the answer of the
+    pool's first request wherever the window returns it."""
 
     def make(cell, w, say):
         program = run.Program(cell, w, say)
-        entry = program.entry
+        entry, alter = program.entry, getattr(cell["entry"], fault)
         program.entry = lambda req: (
             alter(entry(req)) if req.index == 0 else entry(req))
         return program
@@ -62,16 +61,8 @@ def broken(alter):
     return make
 
 
-def flip_one(answers):
-    return [not answers[0]] + list(answers[1:])
-
-
-def drop_half(answers):
-    return list(answers[:len(answers) // 2])
-
-
-CONTROLS = {"stale": StaleReference, "flipped": broken(flip_one),
-            "short": broken(drop_half)}
+CONTROLS = {"stale": StaleReference, "flipped": broken("flipped"),
+            "short": broken("short")}
 
 
 if __name__ == "__main__":

@@ -4,15 +4,16 @@ client's side of the served path.
 
     python3 chipbench/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
-One process.  It finds the cell's configuration, traffic mix and per-layer
-readers by name (configs/<config>.json, worlds/<world>.py,
-traffic/<mix>.json, layers/<metric>.py), makes the world and the requests
-from ``--seed``, loads the program through its public client, warms the
-cell's own shapes, drives the window, compares every answer the window
-returned with the world's plain reference, and prints the contract's
-result line last.  Anything but a TPU with enough chips exits non-zero
-with no result; ``--rehearse-cpu`` is the only way onto the CPU (1 % of
-the scale, every line says ``platform: "cpu"``, never a result).
+One process.  It finds the cell's configuration, traffic mix, entry point
+and per-layer readers by name (configs/<config>.json, worlds/<world>.py,
+traffic/<mix>.json, entries/<entry>.py, layers/<metric>.py), makes the world
+and the requests from ``--seed``, loads the program through its public
+client, warms the cell's own shapes, drives the window, has the entry
+compare every answer the window returned with the world's plain reference,
+and prints the contract's result line last.  Anything but a TPU with enough
+chips exits non-zero with no result; ``--rehearse-cpu`` is the only way onto
+the CPU (1 % of the scale, every line says ``platform: "cpu"``, never a
+result).
 """
 
 from __future__ import annotations
@@ -36,10 +37,11 @@ import numpy as np
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 TRACE_DIR = os.path.join(ROOT, ".chipbench_trace")
-for _p in (ROOT, os.path.join(HERE, "layers"), HERE):
+for _p in (ROOT, os.path.join(HERE, "layers"), os.path.join(HERE, "entries"), HERE):
     if _p not in sys.path:
         sys.path.insert(0, _p)
 
+import _checks  # entries/_checks.py: the first answer of every cell is a check
 import trace_reduce
 
 REHEARSAL_PEAK = "TPU v5 lite"  # a CPU rehearsal borrows this row; it reports nothing
@@ -68,8 +70,20 @@ def load_module(kind: str, name: str):
     return mod
 
 
+def load_entry(name: str):
+    """chipbench/entries/<name>.py: what differs between entry points (the
+    requests, the one timed call, what an answer counts for, the judging)."""
+    found = sorted(f[:-3] for f in os.listdir(os.path.join(HERE, "entries"))
+                   if f.endswith(".py") and not f.startswith("_"))
+    if name not in found:
+        raise SystemExit(f"chipbench: no entry point {name!r}; chipbench/entries/"
+                         f" has {found}")
+    return load_module("entries", name)
+
+
 def load_cell(workload: str, rehearse: bool) -> dict:
-    """The cell with its configuration, traffic mix and metric lists."""
+    """The cell with its configuration, traffic mix, entry point and metric
+    lists."""
     manifest = load_json(ROOT, "BENCHMARK.json")
     cells = {c["name"]: c for c in manifest["workloads"]}
     if workload not in cells:
@@ -84,7 +98,7 @@ def load_cell(workload: str, rehearse: bool) -> dict:
     reports = lambda m: workload in m.get("workloads", [workload])
     return {
         "name": workload, "chips": cell["chips"], "config": config,
-        "traffic": traffic,
+        "traffic": traffic, "entry": load_entry(traffic["entry"]),
         "sizes": config["rehearsal_sizes" if rehearse else "sizes"],
         "world": load_module("worlds", config["world"]),
         "end_to_end": [m for m in manifest["end_to_end"] if reports(m)],
@@ -93,48 +107,17 @@ def load_cell(workload: str, rehearse: bool) -> dict:
 
 
 # ---------------------------------------------------------------------------
-# traffic: one generator for every mix
+# the loop: closed, for every mix
 # ---------------------------------------------------------------------------
 
 
-class Request:
-    __slots__ = ("index", "res", "subj", "rels")
-
-    def __init__(self, index, res, subj, rels):
-        self.index, self.res, self.subj, self.rels = index, res, subj, rels
-
-
-def to_rels(probe: dict, res, subj) -> list:
-    from gochugaru_tpu import rel
-
-    (rt, rp), perm, (st, sp) = probe["resource"], probe["permission"], probe["subject"]
-    mk = rel.must_from_triple
-    return [mk(f"{rt}:{rp}{r}", perm, f"{st}:{sp}{s}")
-            for r, s in zip(res.tolist(), subj.tolist())]
-
-
-def build_pool(cell: dict, w: dict, seed: int) -> list:
-    """The distinct requests the callers cycle through.  Every seed gives
-    the same multiset of request sizes, in another order, with other
-    probes."""
-    traffic, mod = cell["traffic"], cell["world"]
-    rng = np.random.default_rng([seed, 1])
-    sizes = traffic["request_checks"]
-    counts = np.array([sizes[i % len(sizes)]
-                       for i in range(traffic["pool_requests"])])
-    rng.shuffle(counts)
-    res, subj = mod.make_probes(w, cell["sizes"], rng, int(counts.sum()))
-    rels = to_rels(mod.PROBE, res, subj)
-    ends = np.cumsum(counts)
-    return [Request(i, res[e - n:e], subj[e - n:e], rels[e - n:e])
-            for i, (n, e) in enumerate(zip(counts.tolist(), ends.tolist()))]
-
-
-def drive(entry, pool: list, callers: int, seconds: float, annotate: bool):
+def drive(entry, pool: list, callers: int, seconds: float, annotate: bool,
+          passes: int = 0):
     """The closed loop: each caller sends its next request when the last
-    has returned, and starts none after ``seconds``.  Returns
-    (window start, per-request (pool index, sent, answered, answer or
-    exception))."""
+    has returned, and starts none after ``seconds`` — or, where ``passes``
+    is given (a warm-up), once it has sent its share of the pool that many
+    times.  Returns (window start, per-request (pool index, sent, answered,
+    answer or exception), callers still hung)."""
     logs = [[] for _ in range(callers)]
     gate = threading.Barrier(callers + 1)
     t_end = [0.0]
@@ -151,7 +134,7 @@ def drive(entry, pool: list, callers: int, seconds: float, annotate: bool):
         stop = t_end[0]
         while True:
             sent = time.perf_counter()
-            if sent >= stop:
+            if sent >= stop or (passes and i >= passes * len(mine)):
                 return
             req = mine[i % len(mine)]
             i += 1
@@ -166,6 +149,8 @@ def drive(entry, pool: list, callers: int, seconds: float, annotate: bool):
                for c in range(callers)]
     for t in threads:
         t.start()
+    if passes:
+        seconds = len(pool) * MIN_DEADLINE_S  # the count ends it, not the clock
     t_start = time.perf_counter()
     t_end[0] = t_start + seconds
     gate.wait()
@@ -212,6 +197,7 @@ class Program:
         from gochugaru_tpu.utils.context import background
 
         traffic, mod, sizes = cell["traffic"], cell["world"], cell["sizes"]
+        self.world = mod
         #: the program's counters, gauges and timers, as the readers get them
         self.snapshot = metrics.default.snapshot
         self._background = background
@@ -238,14 +224,8 @@ class Program:
                                  f" configuration states {sizes['edges']}")
         say("loaded", edges=edges, native_ingest=native.available(),
             intern_s=t1 - t0, import_s=time.perf_counter() - t1)
-        self.handle = None
-        if traffic["entry"] == "serving.check":
-            self.handle = self.client.with_serving(cs=self.cs)
-            self._call = lambda ctx, rels: self.handle.check(ctx, *rels)
-        elif traffic["entry"] == "client.check":
-            self._call = lambda ctx, rels: self.client.check(ctx, self.cs, *rels)
-        else:
-            raise ValueError(f"unknown entry point {traffic['entry']!r}")
+        self.handle = None  # an entry that serves through a handle opens it
+        self._call = cell["entry"].bind(self)
 
     def ctx(self, seconds: float = 0.0):
         return self._background().with_timeout(seconds or self.deadline_s)
@@ -260,8 +240,8 @@ class Program:
         self.deadline_s = max(self.deadline_s, 2 * took)
         return took
 
-    def entry(self, req: Request):
-        return self._call(self.ctx(), req.rels)
+    def entry(self, req):
+        return self._call(self.ctx(), req)
 
     def close(self) -> None:
         if self.handle is not None:
@@ -284,42 +264,6 @@ def resident_bytes(devices) -> int:
 # ---------------------------------------------------------------------------
 # deciding ``correct``
 # ---------------------------------------------------------------------------
-
-
-def judge(cell: dict, w: dict, pool: list, log: list, hung: int) -> dict:
-    """Every answer the window returned against the plain reference, which
-    runs once over each distinct request that was sent.  Exact: both
-    limits are 0."""
-    mod = cell["world"]
-    used = sorted({e[0] for e in log})
-    expected = {}
-    if used:
-        want = mod.reference(w, cell["sizes"])(
-            np.concatenate([pool[i].res for i in used]),
-            np.concatenate([pool[i].subj for i in used]))
-        at = 0
-        for i in used:
-            n = pool[i].res.shape[0]
-            expected[i] = want[at:at + n]
-            at += n
-    wrong = compared = 0
-    unanswered = hung
-    for index, _sent, _answered, out in log:
-        if isinstance(out, Exception):
-            unanswered += 1
-            continue
-        exp = expected[index]
-        got = np.fromiter(out, bool, len(out))
-        if got.shape != exp.shape:
-            wrong += exp.shape[0]
-        else:
-            wrong += int((got != exp).sum())
-        compared += exp.shape[0]
-    return {
-        "wrong_answers": {"value": wrong, "limit": 0},
-        "unanswered_requests": {"value": unanswered, "limit": 0},
-        "answers_compared": {"value": compared, "at_least": 1},
-    }
 
 
 def is_correct(checked: dict) -> bool:
@@ -393,18 +337,22 @@ def run_cell(args, make_program=Program) -> int:
     t = stage("world_s", t)
     program = make_program(cell, w, say)
     t = stage("load_s", t)
-    pool = build_pool(cell, w, args.seed)
+    entry = cell["entry"]
+    pool = entry.requests(cell, w, np.random.default_rng([args.seed, 1]))
     t = stage("requests_s", t)
     rng = np.random.default_rng([args.seed, 2])
-    warm = [Request(-1, r, s, to_rels(cell["world"].PROBE, r, s))
-            for r, s in (cell["world"].make_probes(w, cell["sizes"], rng, n)
-                         for n in [4] + traffic["warm_request_checks"])]
-    program.first_answer(warm[0].rels)
+    first = _checks.to_rels(cell["world"].PROBE, *cell["world"].make_probes(
+        w, cell["sizes"], rng, 4))  # whatever the entry: a check of four probes
+    warm = entry.warm_requests(cell, w, rng)
+    program.first_answer(first)
     t = stage("first_answer_s", t)
-    for req in warm[1:]:
+    for req in warm:
         program.entry(req)
+    # the warm loop: by the clock (``warm_loop_s``), or the whole pool
+    # ``warm_passes`` times where each request has shapes of its own
     _, warm_log, _ = drive(program.entry, pool, traffic["callers"],
-                           traffic["warm_loop_s"], False)
+                           traffic.get("warm_loop_s", 0.0), False,
+                           traffic.get("warm_passes", 0))
     for _i, _s, _a, out in warm_log:
         if isinstance(out, Exception):
             raise out
@@ -449,15 +397,19 @@ def run_cell(args, make_program=Program) -> int:
 
     # -- numbers -----------------------------------------------------------------
     done = [e for e in log if not isinstance(e[3], Exception)]
-    checks = sum(len(e[3]) for e in done)
+    counts = entry.tally([e[3] for e in done])
     latency_ms = np.array([1000.0 * (e[2] - e[1]) for e in done])
-    say("window", requests=len(log), answered=len(done), checks=checks,
+    say("window", requests=len(log), answered=len(done), **counts,
         window_s=window_s, asked_s=seconds,
         request_p50_ms=float(np.median(latency_ms)) if len(done) else None,
         request_max_ms=float(latency_ms.max()) if len(done) else None,
         window_compiles=window_compiles, bytes_in_use=stats.get("bytes_in_use"),
         errors=sorted({repr(e[3])[:200] for e in log
                        if isinstance(e[3], Exception)})[:5])
+    if hasattr(entry, "counters"):  # events an entry adds; no metric reads them
+        say("counters", **entry.counters(before, after))
+    if hasattr(entry, "describe"):
+        say("answers", **entry.describe(pool, done))
     if not done:
         print("chipbench: no request was answered in the window",
               file=sys.stderr)
@@ -481,10 +433,11 @@ def run_cell(args, make_program=Program) -> int:
             return 3
         context = {"config": cell["config"], "traffic": traffic,
                    "peak": peaks[kind],
-                   "window": {"checks": checks, "requests": len(done),
+                   "window": {**counts, "requests": len(done),
                               "seconds": window_s,
-                              "request_p99_ms": float(
-                                  np.percentile(latency_ms, 99)),
+                              **{f"request_p{q}_ms": float(
+                                  np.percentile(latency_ms, q))
+                                 for q in (50, 95, 99)},
                               "compile_requests": window_compiles}}
         metrics_out = {}
         for m in cell["per_layer"]:
@@ -496,7 +449,7 @@ def run_cell(args, make_program=Program) -> int:
                                "idle_gaps": trace["idle_gaps"]}
     else:
         values = {
-            "checks_per_s": checks / window_s,
+            **{m: counts[k] / window_s for m, k in entry.RATES.items()},
             "request_p95_ms": float(np.percentile(latency_ms, 95)),
             "device_bytes_per_edge": held / cell["sizes"]["edges"],
             "setup_s": setup_s,
@@ -506,7 +459,7 @@ def run_cell(args, make_program=Program) -> int:
 
     # -- correct: after the window, the peak reading and the close -----------
     t0 = time.perf_counter()
-    checked = judge(cell, w, pool, log, hung)
+    checked = entry.judge(cell, w, pool, log, hung)
     say("judged", reference_s=time.perf_counter() - t0,
         total_s=time.perf_counter() - T_START)
     line = {"correct": is_correct(checked), **result, "metrics": metrics_out,

@@ -49,7 +49,7 @@ def test_reference_agrees_with_the_programs_oracle(world):
     res, subj = cell["world"].make_probes(
         w, cell["sizes"], np.random.default_rng(3), 1600)
     want = cell["world"].reference(w, cell["sizes"])(res, subj)
-    rels = run.to_rels(cell["world"].PROBE, res, subj)
+    rels = run._checks.to_rels(cell["world"].PROBE, res, subj)
     got = np.array([oracle.check_relationship(r) == T for r in rels])
     assert np.array_equal(got, want)
     # every kind of probe is there: most of the drawn grants hold, most

@@ -2,6 +2,9 @@
 rewrite.  World, probes (copied from chip_smoke.py, which ran them on the
 chip at full scale) and the plain reference of ``document#view``.
 
+Since PR 31 also the lookups of ``document#view`` (LookupResources of a
+user, LookupSubjects of a document): their strata and their plain reference.
+
 Index space throughout: object i of a type is ``<prefix><i>``.
 """
 
@@ -187,3 +190,126 @@ def reference(w, size: dict):
         return out
 
     return check
+
+
+# -- lookups (PR 31): LookupResources of a user, LookupSubjects of a document ----
+
+RESOURCES, SUBJECTS = 0, 1  # the two kinds of lookup
+#: stratum -> (kind, folder level or None).  A folder stratum looks up a
+#: user who views, or is a member (half of them through nesting) of the
+#: group that views, a folder that many steps below the root (level 1:
+#: indices 1-16, level 2: 17-272); the others draw their key uniformly.
+#: ``make_lookups`` draws the strata in this order.
+LOOKUP_STRATA = {
+    "users": (RESOURCES, None),
+    "level2_viewers": (RESOURCES, 2),
+    "level1_viewers": (RESOURCES, 1),
+    "documents": (SUBJECTS, None),
+}
+
+
+def folders_of_level(level: int, F: int):
+    """[lo, hi) of the folder indices ``level`` steps below the root."""
+    lo = (FOLDER_ARITY ** level - 1) // (FOLDER_ARITY - 1)
+    hi = (FOLDER_ARITY ** (level + 1) - 1) // (FOLDER_ARITY - 1)
+    return min(lo, F), min(hi, F)
+
+
+def viewer_of(ix, rng, folders):
+    """One user per folder who views it: its viewer, or a member (half of
+    them through nesting) of the group that is its viewer."""
+    by_group = ix["fv_group"][folders] >= 0
+    u = np.where(by_group, 0, ix["fv_user"][folders])
+    u[by_group] = member_of(ix, rng, ix["fv_group"][folders][by_group])
+    return u
+
+
+def make_lookups(w, size: dict, rng, strata: dict):
+    """``sum(strata.values())`` lookups as (kinds, keys, stratum names): the
+    key of a RESOURCES lookup is a user index, of a SUBJECTS lookup a
+    document index.  Every seed gives the same strata, with other keys
+    (distinct inside a stratum's draw), in another order."""
+    unknown = set(strata) - set(LOOKUP_STRATA)
+    if unknown:
+        raise ValueError(f"no lookup stratum {sorted(unknown)}; this world has"
+                         f" {sorted(LOOKUP_STRATA)}")
+    ix = _probe_index(w, size)
+    kinds, keys, names = [], [], []
+    for name, (kind, level) in LOOKUP_STRATA.items():
+        n = int(strata.get(name, 0))
+        if level is None:
+            domain = size["users" if kind == RESOURCES else "docs"]
+            key = rng.choice(domain, n, replace=False)
+        else:
+            lo, hi = folders_of_level(level, size["folders"])
+            key = viewer_of(ix, rng, lo + rng.choice(hi - lo, n, replace=False))
+        kinds.append(np.full(n, kind, np.int8))
+        keys.append(np.asarray(key, np.int64))
+        names += [name] * n
+    order = rng.permutation(len(names))
+    return (np.concatenate(kinds)[order], np.concatenate(keys)[order],
+            np.array(names)[order])
+
+
+def _by_row(rows, ids, n: int) -> list:
+    """The distinct ids of each row, sorted."""
+    keys = pair_keys(rows, ids)
+    cut = np.searchsorted(keys >> 32, np.arange(n + 1))
+    return [keys[a:b] & 0xFFFFFFFF for a, b in zip(cut[:-1], cut[1:])]
+
+
+def lookup_reference(w, size: dict):
+    """``answer(kind, keys)`` -> for each key its whole answer as a sorted
+    array of indices, from the edge lists alone.  Documents of a user: the
+    ones it views directly, those of every group it is a member of (nested
+    to any depth), and the documents in every folder at or below a folder
+    that it, or such a group, views.  Users of a document: the mirror, up
+    the folder chain."""
+    U, G, F, D = size["users"], size["groups"], size["folders"], size["docs"]
+    members = member_closure(pair_keys(*w["group_user"]),
+                             *w["group_group"], G)
+    m_group, m_user = members >> 32, members & 0xFFFFFFFF
+    (gd, gg), (ud, uu) = w["doc_group"], w["doc_user"]
+    (gf, fg), (uf, fu) = w["folder_group"], w["folder_user"]
+    (child, par), (fd, ff) = w["folder_parent"], w["doc_folder"]
+
+    groups_of, members_of = CSR(m_user, m_group, U), CSR(m_group, m_user, G)
+    docs_of_user, users_of_doc = CSR(uu, ud, U), CSR(ud, uu, D)
+    docs_of_group, groups_of_doc = CSR(gg, gd, G), CSR(gd, gg, D)
+    folders_of_user, users_of_folder = CSR(fu, uf, U), CSR(uf, fu, F)
+    folders_of_group, groups_of_folder = CSR(fg, gf, G), CSR(gf, fg, F)
+    children, parent = CSR(par, child, F), CSR(child, par, F)
+    docs_in, folder_of = CSR(ff, fd, F), CSR(fd, ff, D)
+    both = lambda pairs: tuple(np.concatenate(x) for x in zip(*pairs))
+
+    def resources(users):
+        rows = np.arange(users.shape[0])
+        g_rows, groups = groups_of.expand(rows, users)
+        found = [docs_of_user.expand(rows, users),
+                 docs_of_group.expand(g_rows, groups)]
+        f_rows, folders = both([folders_of_user.expand(rows, users),
+                                folders_of_group.expand(g_rows, groups)])
+        while f_rows.shape[0]:
+            found.append(docs_in.expand(f_rows, folders))
+            f_rows, folders = children.expand(f_rows, folders)
+        return _by_row(*both(found), users.shape[0])
+
+    def subjects(docs):
+        rows = np.arange(docs.shape[0])
+        found = [users_of_doc.expand(rows, docs)]
+        via = [groups_of_doc.expand(rows, docs)]
+        f_rows, folders = folder_of.expand(rows, docs)
+        while f_rows.shape[0]:
+            found.append(users_of_folder.expand(f_rows, folders))
+            via.append(groups_of_folder.expand(f_rows, folders))
+            f_rows, folders = parent.expand(f_rows, folders)
+        found.append(members_of.expand(*both(via)))
+        return _by_row(*both(found), docs.shape[0])
+
+    def answer(kind: int, keys) -> list:
+        keys = np.asarray(keys, np.int64)
+        if not keys.shape[0]:
+            return []
+        return (resources if kind == RESOURCES else subjects)(keys)
+
+    return answer
