@@ -62,6 +62,7 @@ from .hash import (
 )
 from .packed import decode_block as _pk_decode
 from .plan import DevicePlan, EngineConfig, ExprIR, _eval_cyclic_pairs
+from .rev import REV_TABLES
 
 
 # ---------------------------------------------------------------------------
@@ -272,6 +273,9 @@ class FlatMeta:
     #: k2 — reverse reachability), ``rax``/``ra_off`` (arrow rows keyed
     #: by child — reverse tupleset traversal), and ``fwx``/``fw_off``
     #: (all edges keyed by k1 — forward enumeration for LookupSubjects).
+    #: The three ``*x`` tables (REV_TABLES) ship FLAT — one dimension,
+    #: row r at lanes [r·w, r·w + w), int32 or packed uint16 alike — the
+    #: form the lookup kernels index, so no program relays a table out.
     #: Caps are pow2 max bucket occupancies — the frontier kernel's
     #: in-bucket bisect depth, not probe unroll counts
     has_rev: bool = False
@@ -1226,20 +1230,29 @@ def _pack_flat(
             for k in tgt:
                 a = out[k]
                 if k == name:
-                    if len(a.shape) != 2 or a.shape[1] != w:
-                        ok = False
+                    # the enumeration tables ship flat (engine/rev.py):
+                    # n·w lanes in, n·lanes out, rows packed like the rest
+                    flat = name in REV_TABLES
+                    ok = (
+                        len(a.shape) == 1 and a.shape[0] % w == 0
+                        if flat else
+                        len(a.shape) == 2 and a.shape[1] == w
+                    )
+                    if not ok:
                         break
+
+                    def pack(b):
+                        rows = pk.pack_rows(b.reshape(-1, w), spec)
+                        return rows.reshape(-1) if flat else rows
+
                     if hasattr(a, "map_blocks"):  # multihost ShardSlices
                         # a PackError here must FAIL LOUDLY: each process
                         # validates only its owned blocks, and a silent
                         # local despec would diverge FlatMeta across the
                         # processes of one collective program
-                        packed_arrays[k] = a.map_blocks(
-                            lambda b: pk.pack_rows(b, spec), lanes,
-                            np.uint16,
-                        )
+                        packed_arrays[k] = a.map_blocks(pack, np.uint16)
                     else:
-                        packed_arrays[k] = pk.pack_rows(a, spec)
+                        packed_arrays[k] = pack(a)
                 else:
                     # aligned level: rows are cap*w int32 → cap*lanes
                     size, roww = a.shape

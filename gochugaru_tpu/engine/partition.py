@@ -295,15 +295,20 @@ class ShardSlices:
             out[s * self.per : (s + 1) * self.per] = self.blocks[s]
         return out
 
-    def map_blocks(self, fn, w: int, dtype) -> "ShardSlices":
+    def map_blocks(self, fn, dtype) -> "ShardSlices":
         """A new ShardSlices with every owned block transformed (the
         HBM-lean pack applies per block — each process packs only the
-        slices it owns, with the globally-agreed spec)."""
+        slices it owns, with the globally-agreed spec).  The new shape
+        is read off the transformed blocks, so a ``[R_pad, w]`` block
+        and a flat ``R_pad·w``-lane one (engine/rev.py) map alike."""
+        blocks = {s: fn(b) for s, b in self.blocks.items()}
+        one = next(iter(blocks.values()))  # a process owns >= 1 shard
+        M = self.shape[0] // self.per
         return ShardSlices(
-            shape=(self.shape[0], w) if len(self.shape) > 1 else self.shape,
+            shape=(M * one.shape[0],) + one.shape[1:],
             dtype=np.dtype(dtype),
-            per=self.per,
-            blocks={s: fn(b) for s, b in self.blocks.items()},
+            per=one.shape[0],
+            blocks=blocks,
         )
 
     @property

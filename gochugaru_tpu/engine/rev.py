@@ -26,8 +26,23 @@ order.  That identity-sort canonicalization is what makes the
 partition-first build (owner shard from the bucket's high bits, each
 shard sorted independently — O(E/M) scratch, engine/partition.py
 discipline) BITWISE-identical to the build-full-then-stack oracle
-``build_rev_full`` (tests/test_rev_index.py), the same contract the
-fold derivations adopted in round 12.
+``build_rev_full`` (tests/test_prepare_parity.py
+``test_partition_first_equals_build_full_then_stack``,
+tests/test_rev_layout.py), the same contract the fold derivations
+adopted in round 12.
+
+Shipped layout: every table is ONE dimension of ``n·w`` lanes, row
+``r`` at lanes ``[r·w, r·w + w)`` (``w`` = 2 + gate columns; the packed
+form, engine/flat.py ``_pack_flat``, is ``n·lanes`` uint16 lanes laid
+out the same way) — the form the kernels of engine/spmv.py index
+directly.  A ``[n, w]`` operand this narrow is kept column-tiled on the
+TPU, and flattening it inside a program is a physical copy of the whole
+table, every round of the fused loop and every looped hop; the host
+flattens it once here, for nothing (the rows are filled row-major).  A
+shard's block is the contiguous ``R_pad·w`` lanes of its rows, so the
+stacked M-way form slices on the one axis like every other table
+(tests/test_rev_layout.py holds the layout and that no lookup program
+reshapes, transposes or converts a whole table).
 
 Bucket sizing always uses the frozen lean geometry (``REV_HK``): fans
 are unbounded by design (a popular userset IS the workload), so chasing
@@ -43,6 +58,16 @@ import numpy as np
 
 from .hash import _ceil_pow2
 from .partition import ColsAt, PointGeom, ShardSlices, point_geom, shard_order
+
+#: the three enumeration tables, the ones shipped flat (module docstring)
+REV_TABLES = ("rvx", "fwx", "rax")
+
+
+def row_lanes(spec, w: int) -> int:
+    """Lanes a row of a flat enumeration table takes: its ``w`` int32
+    columns, or the uint16 lanes of its packed spec (engine/packed.py)."""
+    return w if spec is None else spec[1]
+
 
 #: geometry kwargs of every reverse-index bucket table: pow2(n) buckets,
 #: growth frozen (max_factor=1) — the bisect absorbs deep buckets, an 8x
@@ -105,7 +130,8 @@ def build_rev_shards(
     owned: Optional[Sequence[int]] = None,
 ):
     """Shard-at-a-time reverse-index build: (off int32[M·(bpd+1)],
-    tbl int32[M·R_pad, w]).  ``shard_h(s)`` returns shard s's row hashes
+    tbl int32[M·R_pad·w], row-major flat — the module docstring's
+    shipped layout).  ``shard_h(s)`` returns shard s's row hashes
     (any order — the identity sort canonicalizes); ``shard_cols(s, perm)``
     the row columns gathered at shard-local positions ``perm``.  The
     returned permutation applied is (local bucket, full row identity) —
@@ -114,9 +140,10 @@ def build_rev_shards(
     M, bpd, R_pad = geom.M, geom.bpd, geom.R_pad
     full = owned is None
     shards = range(M) if full else sorted(owned)
+    per = R_pad * w
     if full:
         off = np.empty(M * (bpd + 1), np.int32)
-        tbl = np.full((M * R_pad, w), -1, np.int32)
+        tbl = np.full(M * per, -1, np.int32)
     else:
         off_b: Dict[int, np.ndarray] = {}
         tbl_b: Dict[int, np.ndarray] = {}
@@ -131,18 +158,18 @@ def build_rev_shards(
         cols = [np.ascontiguousarray(c[perm], np.int32) for c in cols0]
         if full:
             off[s * (bpd + 1) : (s + 1) * (bpd + 1)] = _shard_off(lb, bpd)
-            blk = tbl[s * R_pad : (s + 1) * R_pad]
+            blk = tbl[s * per : (s + 1) * per]
         else:
             off_b[s] = _shard_off(lb, bpd)
-            blk = np.full((R_pad, w), -1, np.int32)
+            blk = np.full(per, -1, np.int32)
             tbl_b[s] = blk
         if cols and cols[0].shape[0]:
-            _fill_shard(blk, cols)
+            _fill_shard(blk.reshape(R_pad, w), cols)
     if full:
         return off, tbl
     return (
         ShardSlices((M * (bpd + 1),), np.dtype(np.int32), bpd + 1, off_b),
-        ShardSlices((M * R_pad, w), np.dtype(np.int32), R_pad, tbl_b),
+        ShardSlices((M * per,), np.dtype(np.int32), per, tbl_b),
     )
 
 
@@ -178,7 +205,7 @@ def build_rev_full(
     """Build-full-then-stack reference: ONE global sort by (bucket, row
     identity), then per-shard slices — the parity oracle the partitioned
     build is asserted bitwise-equal to, and the single-chip (M=1) build
-    path."""
+    path.  Same (off, flat tbl) as ``build_rev_shards``."""
     M, bpd, R_pad = geom.M, geom.bpd, geom.R_pad
     size = geom.size
     cc = [np.ascontiguousarray(c, np.int32) for c in cols]
@@ -191,14 +218,15 @@ def build_rev_full(
     lb = bs & np.int64(bpd - 1)
     scols = [c[perm] for c in cc]
     off = np.empty(M * (bpd + 1), np.int32)
-    tbl = np.full((M * R_pad, w), -1, np.int32)
+    tbl = np.full(M * R_pad * w, -1, np.int32)
+    rows = tbl.reshape(M * R_pad, w)  # a view: the fill is row-major
     starts = np.zeros(M + 1, np.int64)
     np.cumsum(np.bincount(owner, minlength=M), out=starts[1:])
     for s in range(M):
         lo, hi = int(starts[s]), int(starts[s + 1])
         off[s * (bpd + 1) : (s + 1) * (bpd + 1)] = _shard_off(lb[lo:hi], bpd)
         _fill_shard(
-            tbl[s * R_pad : (s + 1) * R_pad], [c[lo:hi] for c in scols]
+            rows[s * R_pad : (s + 1) * R_pad], [c[lo:hi] for c in scols]
         )
     return off, tbl
 

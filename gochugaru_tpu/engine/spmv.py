@@ -51,6 +51,7 @@ import numpy as np
 
 from ..utils import faults, metrics
 from .hash import _ceil_pow2, mix32, take_in_bounds
+from .rev import REV_TABLES, row_lanes
 
 _mt = metrics.default
 
@@ -169,29 +170,32 @@ _SEEN_BUDGET_BYTES = 1 << 27
 
 
 def _field0_reader(spec, w: int):
-    """Reader of column 0 at flat row indices (the bisect compare):
-    packed specs decode just the lanes field 0 lives in — same shift/
-    mask decode the Check kernel fuses into its gathers."""
+    """Reader of column 0 at row indices (the bisect compare): packed
+    specs decode just the lanes field 0 lives in — same shift/mask
+    decode the Check kernel fuses into its gathers.
+
+    It never reshapes the table: the enumeration tables arrive flat
+    (engine/rev.py's shipped layout, row r at lanes [r·stride, …)), so
+    row r's field 0 is a gather at ``r·stride``.  Flattening an
+    ``[n, w]`` operand here instead would ask the TPU for a physical
+    copy of the whole table — once a round inside the fused loop
+    (engine/spmm.py), once a looped hop."""
     import jax.numpy as jnp
 
+    stride = row_lanes(spec, w)
     if spec is None:
+        return lambda tbl, idx: take_in_bounds(tbl, idx * stride)
 
-        def rd(tbl, idx):
-            return take_in_bounds(tbl.reshape(-1), idx * w)
-
-        return rd
-
-    lanes = spec[1]
     bits, base, delta_of, dict_id, off_bit = spec[2][0]
     assert off_bit == 0 and delta_of < 0 and dict_id < 0, (
         "reverse-index key columns are plain ranges at bit 0"
     )
 
     def rd(tbl, idx):
-        flat = tbl.reshape(-1)
-        v = take_in_bounds(flat, idx * lanes).astype(jnp.int32)
+        at = idx * stride
+        v = take_in_bounds(tbl, at).astype(jnp.int32)
         if bits > 16:
-            v = v | (take_in_bounds(flat, idx * lanes + 1).astype(jnp.int32) << 16)
+            v = v | (take_in_bounds(tbl, at + 1).astype(jnp.int32) << 16)
         if bits < 32:
             v = v & jnp.int32((1 << bits) - 1)
         return v + jnp.int32(base) if base else v
@@ -199,9 +203,26 @@ def _field0_reader(spec, w: int):
     return rd
 
 
-def _decoder(spec):
+def _rows_reader(spec, w: int, flat: bool):
+    """Reader of whole decoded rows (int32[..., w]) at row indices.  A
+    flat enumeration table gathers each row's lanes by flat index and
+    stacks the small block; a ``[n, w]`` table (``arx``, which the
+    Check kernel shares) gathers rows."""
     import jax.numpy as jnp
 
+    dec = _decoder(spec)
+    if not flat:
+        return lambda tbl, ridx: dec(take_in_bounds(tbl, ridx))
+    stride = row_lanes(spec, w)
+
+    def rd(tbl, ridx):
+        lanes = ridx[..., None] * stride + jnp.arange(stride, dtype=jnp.int32)
+        return dec(take_in_bounds(tbl, lanes))
+
+    return rd
+
+
+def _decoder(spec):
     if spec is None:
         return lambda blk: blk
 
@@ -318,6 +339,7 @@ class FrontierKernels:
         steps = max(int(cap).bit_length(), 1)
         spec = self._pk.get(tbl_key)
         col0 = _field0_reader(spec, w)
+        stride = row_lanes(spec, w)
         offr = self._off_reader(off_key)
 
         def fn(off, off_a, tbl, keys):
@@ -325,7 +347,7 @@ class FrontierKernels:
             h = (mix32([keys], jnp) & jnp.uint32(size - 1)).astype(jnp.int32)
             start = offr(off, off_a, h)
             end = offr(off, off_a, h + 1)
-            last = tbl.shape[0] - 1
+            last = tbl.shape[0] // stride - 1  # rows of the flat table
 
             def bisect(left: bool):
                 lo = start
@@ -396,7 +418,9 @@ class FrontierKernels:
         import jax.numpy as jnp
         from jax import lax
 
-        dec = _decoder(self._pk.get(tbl_key))
+        rows_at = _rows_reader(
+            self._pk.get(tbl_key), w, tbl_key in REV_TABLES
+        )
 
         def fn(tbl, lo, ln, chunk0, now, CH: int):
             chunk0 = jnp.asarray(chunk0).reshape(-1)[0]
@@ -427,7 +451,7 @@ class FrontierKernels:
                 cumstart, kic
             )
             ridx = jnp.where(ok, ridx, 0)
-            rows = dec(take_in_bounds(tbl, ridx))
+            rows = rows_at(tbl, ridx)
             live = ok
             if hasexp:
                 exp = rows[..., gate_at + (2 if hascav else 0)]
