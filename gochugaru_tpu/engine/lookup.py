@@ -222,11 +222,11 @@ def _exact_filter(
         now_us=now_us, bucket_min=LOOKUP_BUCKET_MIN,
     )
     needs_host = ovf | (p & ~d)
-    granted = list(cand[d & ~needs_host])
-    for i in np.nonzero(needs_host)[0]:
-        if oracle_check(int(cand[i])):
-            granted.append(int(cand[i]))
-    return np.asarray(granted, np.int64)
+    granted = cand[d & ~needs_host].astype(np.int64, copy=False)
+    host = [n for n in cand[needs_host].tolist() if oracle_check(n)]
+    if host:
+        granted = np.concatenate([granted, np.asarray(host, np.int64)])
+    return granted
 
 
 def _resolve_resources(dsnap, resource_type, permission, subject_type,
@@ -487,9 +487,11 @@ def _walk_subject_candidates(
 
 
 def _res_filter(engine, dsnap, resolved, names, now_us, oracle_factory):
-    """(filter_fn, id_of) of one LookupResources query — exact device
-    forward check over a candidate block, oracle re-checks for
-    overflow/possible (shared by the frontier and walker streams)."""
+    """(filter_fn, ids_of) of one LookupResources query, both a block
+    at a time: the exact device forward check over a candidate block
+    (oracle re-checks for overflow/possible), and the granted block's
+    node numbers → id strings in one interner call (shared by the
+    frontier and walker streams)."""
     rtid, perm_slot, srel_slot, subj_node, wc_node = resolved
     resource_type, permission, subject_type, subject_id, subject_relation = names
     interner = dsnap.snapshot.interner
@@ -522,10 +524,13 @@ def _res_filter(engine, dsnap, resolved, names, now_us, oracle_factory):
             oracle_check=oracle_check,
         )
 
-    return filt, (lambda n: interner.key_of(n)[1])
+    return filt, (lambda nodes: interner.keys_columns(nodes)[1])
 
 
 def _subj_filter(engine, dsnap, resolved, names, now_us, oracle_factory):
+    """(filter_fn, ids_of) of one LookupSubjects query — the mirror of
+    ``_res_filter``: a candidate block of subject nodes in, the granted
+    block out, its ids decoded in one interner call."""
     res_node, perm_slot, srel_slot, stid, wc_node = resolved
     resource_type, resource_id, permission, subject_type, subject_relation = names
     interner = dsnap.snapshot.interner
@@ -561,7 +566,7 @@ def _subj_filter(engine, dsnap, resolved, names, now_us, oracle_factory):
             oracle_check=oracle_check,
         )
 
-    return filt, (lambda n: interner.key_of(n)[1])
+    return filt, (lambda nodes: interner.keys_columns(nodes)[1])
 
 
 def _one_block(cand: np.ndarray):
@@ -612,7 +617,7 @@ def lookup_resources_page(
     if resolved is None:
         return [], None
     rtid, perm_slot, srel_slot, subj_node, wc_node = resolved
-    filt, id_of = _res_filter(
+    filt, ids_of = _res_filter(
         engine, dsnap, resolved, names, now_us, oracle_factory
     )
     snap = dsnap.snapshot
@@ -639,7 +644,7 @@ def lookup_resources_page(
             )
             cands = _one_block(seen[snap.node_type[seen] == rtid])
             cost = 1 << 20
-        return spmv._ResultStream(cands, filt, id_of, cost_bytes=cost)
+        return spmv._ResultStream(cands, filt, ids_of, cost_bytes=cost)
 
     return spmv.paginate(
         dsnap, token, make_stream, page_size, cursor, now_us
@@ -672,7 +677,7 @@ def lookup_subjects_page(
     if resolved is None:
         return [], None
     res_node, perm_slot, srel_slot, stid, wc_node = resolved
-    filt, id_of = _subj_filter(
+    filt, ids_of = _subj_filter(
         engine, dsnap, resolved, names, now_us, oracle_factory
     )
     snap = dsnap.snapshot
@@ -697,7 +702,7 @@ def lookup_subjects_page(
                 snap, res_node, stid, srel_slot, wc_node
             ))
             cost = 1 << 20
-        return spmv._ResultStream(cands, filt, id_of, cost_bytes=cost)
+        return spmv._ResultStream(cands, filt, ids_of, cost_bytes=cost)
 
     return spmv.paginate(
         dsnap, token, make_stream, page_size, cursor, now_us

@@ -1095,15 +1095,19 @@ def state_for(engine, dsnap) -> FrontierState:
 
 class _ResultStream:
     """A lookup's granted-result stream: candidate blocks → exact filter
-    → result ids, with the emitted-count bookkeeping cursors resume on."""
+    → result ids, with the emitted-count bookkeeping cursors resume on.
+    A granted block becomes its id strings in ONE ``ids_of`` call (the
+    interner's columnar decode), in the block's order — never a call an
+    id: four callers turn a per-id foreign call into a hand-over of the
+    interpreter lock an id."""
 
     def __init__(self, cand_iter: Iterator[np.ndarray],
-                 filter_fn: Callable[[np.ndarray], List[int]],
-                 id_of: Callable[[int], str],
+                 filter_fn: Callable[[np.ndarray], np.ndarray],
+                 ids_of: Callable[[np.ndarray], List[str]],
                  cost_bytes: int = 1 << 20) -> None:
         self._cands = cand_iter
         self._filter = filter_fn
-        self._id_of = id_of
+        self._ids_of = ids_of
         self._pending: List[str] = []
         self.emitted = 0
         self.exhausted = False
@@ -1126,7 +1130,11 @@ class _ResultStream:
             if block.size == 0:
                 continue
             granted = self._filter(block)
-            self._pending.extend(self._id_of(int(g)) for g in granted)
+            if granted.size == 0:
+                continue
+            self._pending.extend(self._ids_of(granted))
+            _mt.inc("lookup.id_blocks")
+            _mt.inc("lookup.ids", int(granted.size))
         self.emitted += len(out)
         return out
 

@@ -308,7 +308,7 @@ class VerdictCache:
     Thread-safety: mutation is locked; bulk lookups PROBE lock-free
     (arrays are replaced wholesale, never mutated; CPython dict gets are
     safe against concurrent inserts; eviction drops whole generation
-    objects) — the same discipline as ``Interner.keys_batch`` — and take
+    objects) — the same discipline as ``Interner.keys_columns`` — and take
     the lock once more, briefly, only when a call has old-generation
     hits to promote.  A probe racing a promotion or a rotation can see a
     spurious miss (the row re-dispatches), never a wrong hit."""

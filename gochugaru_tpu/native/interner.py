@@ -135,8 +135,8 @@ class NativeInterner:
         return int(self._lib.gi_size(self._h))
 
     def _keys_raw(self, nodes):
-        """The shared native fetch behind both key-decode paths:
-        (node array, raw id bytes, byte offsets list, type-id list).
+        """The one native call behind ``keys_columns``: (node array,
+        raw id bytes, byte offsets list, type-id list).
         Under the lock: concurrent interning may reallocate the C++
         entry/arena vectors mid-copy (the Python Interner's lock-free
         read contract does not transfer to std::vector)."""
@@ -162,32 +162,23 @@ class NativeInterner:
                 cap = total
         return nn, buf.raw, offs.tolist(), types.tolist()
 
-    def keys_batch(self, nodes) -> List[Tuple[str, str]]:
-        """(type, id) pairs for an int array of nodes in ONE native call
-        (plus a retry when the id bytes outgrow the buffer guess) — the
-        batched decode path behind snapshot exports."""
-        nn, raw, o, tl = self._keys_raw(nodes)
-        tn = self._type_names
-        out = []
-        for i in range(len(tl)):
-            t = tl[i]
-            if t < 0:  # C++ invalid-node sentinel — match key_of's raise
-                raise IndexError(f"unknown node {int(nn[i])}")
-            out.append((tn[t], raw[o[i] : o[i + 1]].decode("utf-8")))
-        return out
-
     def keys_columns(self, nodes) -> Tuple[List[str], List[str]]:
-        """(type_names, ids) as two parallel LISTS — the columnar decode
-        path (snapshot exports): one whole-buffer utf-8 decode plus
-        C-speed str slicing when the ids are ASCII, instead of a per-row
-        bytes slice + decode + tuple."""
+        """(type_names, ids) of a block of nodes as two parallel LISTS,
+        in the block's order — the columnar decode of a lookup's granted
+        block (engine/lookup.py) and of the snapshot exports: ONE native
+        call a block (plus a retry when the id bytes outgrow the buffer
+        guess), one whole-buffer utf-8 decode and C-speed str slicing
+        when the ids are ASCII.  Never ``key_of`` an id in a loop: each
+        is a foreign call that lets go of the interpreter lock, and
+        under contending threads taking it back costs 44–140 µs an id
+        (PERF.md §6, PR 34).  ``IndexError`` on an unknown node."""
         nn, raw, o, tl = self._keys_raw(nodes)
         n = len(tl)
         if n == 0:
             return [], []
         if min(tl) < 0:
-            # any negative type id is the invalid-node sentinel (mirror
-            # keys_batch's t < 0 tolerance, not an exact -1 match)
+            # any negative type id is the C++ invalid-node sentinel —
+            # match key_of's raise
             bad = next(i for i, t in enumerate(tl) if t < 0)
             raise IndexError(f"unknown node {int(nn[bad])}")
         text = raw[: o[n]].decode("utf-8")

@@ -90,12 +90,18 @@ class Interner:
     def key_of(self, node: int) -> Tuple[str, str]:
         return self._keys[node]
 
-    def keys_batch(self, nodes) -> List[Tuple[str, str]]:
-        """(type, id) pairs for an int array of nodes — the batched
-        decode path (snapshot exports).  Reads race-safely without the
-        lock: the list is append-only and CPython appends are atomic."""
+    def keys_columns(self, nodes) -> Tuple[List[str], List[str]]:
+        """(type_names, ids) of a block of nodes as two parallel LISTS,
+        in the block's order — the columnar decode of a lookup's granted
+        block and of the snapshot exports (NativeInterner's mirror).
+        ``IndexError`` on an unknown node.  Reads race-safely without
+        the lock: the list is append-only and CPython appends are
+        atomic."""
+        nl = np.asarray(nodes, np.int64).tolist()
+        if nl and min(nl) < 0:  # a list index would wrap around
+            raise IndexError(f"unknown node {min(nl)}")
         k = self._keys
-        return [k[n] for n in np.asarray(nodes).tolist()]
+        return [k[n][0] for n in nl], [k[n][1] for n in nl]
 
     def __len__(self) -> int:
         return len(self._keys)

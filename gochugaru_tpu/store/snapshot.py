@@ -228,19 +228,13 @@ class Snapshot:
         slot_names = self._slot_names()
         caveat_names = self._caveat_names()
         contexts = self.contexts
-        cols_of = getattr(self.interner, "keys_columns", None)
+        cols_of = self.interner.keys_columns
         at = 0
         while at < rows.shape[0]:
             blk = rows[at : at + chunk]
             at += chunk
-            if cols_of is not None:
-                rtypes, rids = cols_of(self.e_res[blk])
-                stypes, sids = cols_of(self.e_subj[blk])
-            else:
-                rkeys = self.interner.keys_batch(self.e_res[blk])
-                skeys = self.interner.keys_batch(self.e_subj[blk])
-                rtypes, rids = map(list, zip(*rkeys)) if rkeys else ([], [])
-                stypes, sids = map(list, zip(*skeys)) if skeys else ([], [])
+            rtypes, rids = cols_of(self.e_res[blk])
+            stypes, sids = cols_of(self.e_subj[blk])
             srel1 = self.e_srel1[blk].tolist()
             cav = self.e_caveat[blk].tolist()
             ctx_i = self.e_ctx[blk].tolist()

@@ -1107,15 +1107,8 @@ class Store:
         dict_hits: List[_Key] = []
         if self._live and len(self._live) > B:
             name_of_slot = self._require_schema().name_of_slot
-            cols_of = getattr(self.interner, "keys_columns", None)
-            if cols_of is not None:
-                rtypes, rids = cols_of(cols["res"])
-                stypes, sids = cols_of(cols["subj"])
-            else:
-                rk = self.interner.keys_batch(cols["res"])
-                sk = self.interner.keys_batch(cols["subj"])
-                rtypes, rids = map(list, zip(*rk)) if rk else ([], [])
-                stypes, sids = map(list, zip(*sk)) if sk else ([], [])
+            rtypes, rids = self.interner.keys_columns(cols["res"])
+            stypes, sids = self.interner.keys_columns(cols["subj"])
             rel_l = cols["rel"].tolist()
             srel1_l = cols["srel1"].tolist()
             live_get = self._live.get
