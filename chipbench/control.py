@@ -33,12 +33,12 @@ class StaleReference:
 
     def __init__(self, cell, w, say) -> None:
         mod = cell["world"]
-        res, subj = w[mod.NEWEST]
-        kept = res.shape[0] - max(int(res.shape[0] * STALE_SHARE), 1)
-        stale = {**w, mod.NEWEST: (res[:kept], subj[:kept])}
+        newest = w[mod.NEWEST]  # columns of any width: (res, subj, ...)
+        n = newest[0].shape[0]
+        kept = n - max(int(n * STALE_SHARE), 1)
+        stale = {**w, mod.NEWEST: tuple(c[:kept] for c in newest)}
         self.entry = cell["entry"].reference(cell, stale)
-        say("control", kind="stale", hidden_edges=int(res.shape[0] - kept),
-            of=mod.NEWEST)
+        say("control", kind="stale", hidden_edges=n - kept, of=mod.NEWEST)
 
     def first_answer(self, rels) -> float:
         return 0.0

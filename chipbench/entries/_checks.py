@@ -1,6 +1,9 @@
-"""What the two check entries share: a request is a list of (resource,
-subject) probes of the world's ``PROBE``, the answer one boolean a probe.
-Only ``bind`` — which call of the client is timed — differs between them.
+"""What the two check entries share: a request is a list of probes of the
+world's ``PROBE``, the answer one boolean a probe.  A probe is a row of the
+columns ``make_probes`` returns: the first two are (resource, subject) in
+index space, a world may add more (a caller's tenant, ...), which its
+``reference`` takes and its ``probe_rels`` turns into request context.  Only
+``bind`` — which call of the client is timed — differs between the entries.
 
 Traffic parameters read here: ``request_checks`` (the sizes the pool cycles
 through), ``pool_requests``, ``warm_request_checks``.
@@ -17,10 +20,13 @@ RATES = {"checks_per_s": "checks"}
 
 
 class Request:
-    __slots__ = ("index", "res", "subj", "rels")
+    """``columns`` is what the reference takes, ``rels`` what the client
+    takes: the same probes, row for row."""
 
-    def __init__(self, index, res, subj, rels):
-        self.index, self.res, self.subj, self.rels = index, res, subj, rels
+    __slots__ = ("index", "columns", "rels")
+
+    def __init__(self, index, columns, rels):
+        self.index, self.columns, self.rels = index, columns, rels
 
 
 def to_rels(probe: dict, res, subj) -> list:
@@ -32,6 +38,15 @@ def to_rels(probe: dict, res, subj) -> list:
             for r, s in zip(res.tolist(), subj.tolist())]
 
 
+def probe_rels(mod, columns) -> list:
+    """The client's ``Relationship`` of each probe: the world's own
+    ``probe_rels(*columns)`` where it has one (the triple plus the request's
+    caveat context), else the bare triples of its ``PROBE``."""
+    if hasattr(mod, "probe_rels"):
+        return mod.probe_rels(*columns)
+    return to_rels(mod.PROBE, *columns)
+
+
 def requests(cell: dict, w: dict, rng) -> list:
     """The distinct requests the callers cycle through.  Every seed gives
     the same multiset of request sizes, in another order, with other
@@ -41,10 +56,10 @@ def requests(cell: dict, w: dict, rng) -> list:
     counts = np.array([sizes[i % len(sizes)]
                        for i in range(traffic["pool_requests"])])
     rng.shuffle(counts)
-    res, subj = mod.make_probes(w, cell["sizes"], rng, int(counts.sum()))
-    rels = to_rels(mod.PROBE, res, subj)
+    columns = mod.make_probes(w, cell["sizes"], rng, int(counts.sum()))
+    rels = probe_rels(mod, columns)
     ends = np.cumsum(counts)
-    return [Request(i, res[e - n:e], subj[e - n:e], rels[e - n:e])
+    return [Request(i, tuple(c[e - n:e] for c in columns), rels[e - n:e])
             for i, (n, e) in enumerate(zip(counts.tolist(), ends.tolist()))]
 
 
@@ -52,9 +67,9 @@ def warm_requests(cell: dict, w: dict, rng) -> list:
     """One request of each size the mix warms, sent once before the warm
     loop."""
     mod = cell["world"]
-    return [Request(-1, r, s, to_rels(mod.PROBE, r, s))
-            for r, s in (mod.make_probes(w, cell["sizes"], rng, n)
-                         for n in cell["traffic"]["warm_request_checks"])]
+    return [Request(-1, columns, probe_rels(mod, columns))
+            for columns in (mod.make_probes(w, cell["sizes"], rng, n)
+                            for n in cell["traffic"]["warm_request_checks"])]
 
 
 def tally(answers: list) -> dict:
@@ -71,11 +86,10 @@ def judge(cell: dict, w: dict, pool: list, log: list, hung: int) -> dict:
     expected = {}
     if used:
         want = mod.reference(w, cell["sizes"])(
-            np.concatenate([pool[i].res for i in used]),
-            np.concatenate([pool[i].subj for i in used]))
+            *(np.concatenate(c) for c in zip(*(pool[i].columns for i in used))))
         at = 0
         for i in used:
-            n = pool[i].res.shape[0]
+            n = pool[i].columns[0].shape[0]
             expected[i] = want[at:at + n]
             at += n
     wrong = compared = 0
@@ -100,7 +114,7 @@ def judge(cell: dict, w: dict, pool: list, log: list, hung: int) -> dict:
 def reference(cell: dict, w: dict):
     """``answer(request)`` from the world's plain reference over ``w``."""
     check = cell["world"].reference(w, cell["sizes"])
-    return lambda req: check(req.res, req.subj).tolist()
+    return lambda req: check(*req.columns).tolist()
 
 
 def flipped(answer: list) -> list:

@@ -97,11 +97,12 @@ def _prefixes(mod) -> dict:
 
 
 def _indices(ids: list, prefix: str) -> np.ndarray:
-    """Bare ids back to index space; an id that is not ``<prefix><n>``
-    becomes -1, which no reference answer holds."""
+    """Bare ids back to index space; an id that is not ``<prefix><n>`` as the
+    world writes it (no leading zero: ``d00`` is not ``d0``) becomes -1, which
+    no reference answer holds."""
     cut = len(prefix)
     return np.array([int(s[cut:]) if s.startswith(prefix) and s[cut:].isdecimal()
-                     else -1 for s in ids], np.int64)
+                     and s[cut:] == str(int(s[cut:])) else -1 for s in ids], np.int64)
 
 
 def judge(cell: dict, w: dict, pool: list, log: list, hung: int) -> dict:

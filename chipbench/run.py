@@ -8,7 +8,8 @@ One process.  It finds the cell's configuration, traffic mix, entry point
 and per-layer readers by name (configs/<config>.json, worlds/<world>.py,
 traffic/<mix>.json, entries/<entry>.py, layers/<metric>.py), makes the world
 and the requests from ``--seed``, loads the program through its public
-client, warms the cell's own shapes, drives the window, has the entry
+client (the world's own ``load_edges`` where it has one, else its ``SHAPES``
+as id columns), warms the cell's own shapes, drives the window, has the entry
 compare every answer the window returned with the world's plain reference,
 and prints the contract's result line last.  Anything but a TPU with enough
 chips exits non-zero with no result; ``--rehearse-cpu`` is the only way onto
@@ -36,6 +37,9 @@ import numpy as np
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
+#: the manifest that names the cells; a cell's mix and world are looked for
+#: under its ``paths`` (the tests point this at a fixture manifest of theirs)
+MANIFEST = os.path.join(ROOT, "BENCHMARK.json")
 TRACE_DIR = os.path.join(ROOT, ".chipbench_trace")
 for _p in (ROOT, os.path.join(HERE, "layers"), os.path.join(HERE, "entries"), HERE):
     if _p not in sys.path:
@@ -60,9 +64,18 @@ def load_json(*parts):
         return json.load(f)
 
 
-def load_module(kind: str, name: str):
-    """chipbench/<kind>/<name>.py as a module (names may hold dots)."""
-    path = os.path.join(HERE, kind, name + ".py")
+def find(dirs, kind: str, file: str) -> str:
+    """The first <dir>/<kind>/<file> that exists."""
+    for d in dirs:
+        path = os.path.join(d, kind, file)
+        if os.path.isfile(path):
+            return path
+    raise SystemExit(f"chipbench: no {kind}/{file} under {list(dirs)}")
+
+
+def load_module(kind: str, name: str, dirs=(HERE,)):
+    """<kind>/<name>.py as a module (names may hold dots)."""
+    path = find(dirs, kind, name + ".py")
     spec = importlib.util.spec_from_file_location(
         f"chipbench_{kind}_{name.replace('.', '_')}", path)
     mod = importlib.util.module_from_spec(spec)
@@ -84,15 +97,18 @@ def load_entry(name: str):
 def load_cell(workload: str, rehearse: bool) -> dict:
     """The cell with its configuration, traffic mix, entry point and metric
     lists."""
-    manifest = load_json(ROOT, "BENCHMARK.json")
+    root = os.path.dirname(MANIFEST)
+    manifest = load_json(MANIFEST)
     cells = {c["name"]: c for c in manifest["workloads"]}
     if workload not in cells:
-        raise SystemExit(f"chipbench: no workload {workload!r} in BENCHMARK.json;"
+        raise SystemExit(f"chipbench: no workload {workload!r} in {MANIFEST};"
                          f" it has {sorted(cells)}")
     cell = cells[workload]
+    # a cell's data (mix, world): under the manifest's ``paths``, else here
+    dirs = [os.path.join(root, p) for p in manifest["paths"]] + [HERE]
     entry = next(c for c in manifest["configs"] if c["name"] == cell["config"])
-    config = load_json(ROOT, entry["file"])
-    traffic = load_json(HERE, "traffic", cell["traffic"] + ".json")
+    config = load_json(root, entry["file"])
+    traffic = load_json(find(dirs, "traffic", cell["traffic"] + ".json"))
     if rehearse:
         traffic = {**traffic, **traffic.get("rehearsal", {})}
     reports = lambda m: workload in m.get("workloads", [workload])
@@ -100,7 +116,7 @@ def load_cell(workload: str, rehearse: bool) -> dict:
         "name": workload, "chips": cell["chips"], "config": config,
         "traffic": traffic, "entry": load_entry(traffic["entry"]),
         "sizes": config["rehearsal_sizes" if rehearse else "sizes"],
-        "world": load_module("worlds", config["world"]),
+        "world": load_module("worlds", config["world"], dirs),
         "end_to_end": [m for m in manifest["end_to_end"] if reports(m)],
         "per_layer": [m for m in manifest["per_layer"] if reports(m)],
     }
@@ -186,9 +202,26 @@ class CompileWatch:
             self.cache_hits += 1
 
 
+def import_shapes(client, ctx, ids: dict, mod, w: dict) -> int:
+    """The loader of a world that has none of its own: each edge list of
+    ``SHAPES`` is a pair of id columns and nothing else (no caveat, no
+    stored context, no expiration).  Returns the edges imported."""
+    edges = 0
+    for key, rtype, relation, stype, srel in mod.SHAPES:
+        r, s = w[key]
+        client.import_relationship_id_columns(
+            ctx(), resource_ids=ids[rtype][r], resource_relation=relation,
+            subject_ids=ids[stype][s], subject_relation=srel)
+        edges += int(r.shape[0])
+    return edges
+
+
 class Program:
     """The system under test: a client with the cell's world imported,
-    behind the entry point the traffic mix names."""
+    behind the entry point the traffic mix names.  How the edges go in is
+    the world's: ``load_edges(client, ctx, ids, w, sizes) -> edges imported``
+    (``ctx()`` makes a context, ``ids[type][i]`` is the interned node of
+    object i) where the module has one, else ``import_shapes``."""
 
     def __init__(self, cell: dict, w: dict, say) -> None:
         import gochugaru_tpu.client as gclient
@@ -211,18 +244,16 @@ class Program:
         ids = {t: itn.node_batch(t, [f"{p}{i}" for i in range(sizes[k])])
                for t, p, k in mod.TYPES}
         t1 = time.perf_counter()
-        edges = 0
-        for key, rtype, relation, stype, srel in mod.SHAPES:
-            r, s = w[key]
-            self.client.import_relationship_id_columns(
-                self.ctx(), resource_ids=ids[rtype][r],
-                resource_relation=relation, subject_ids=ids[stype][s],
-                subject_relation=srel)
-            edges += int(r.shape[0])
+        if hasattr(mod, "load_edges"):
+            loader = "world.load_edges"
+            edges = mod.load_edges(self.client, self.ctx, ids, w, sizes)
+        else:
+            loader = "run.import_shapes"
+            edges = import_shapes(self.client, self.ctx, ids, mod, w)
         if edges != sizes["edges"]:
             raise AssertionError(f"imported {edges} edges, the"
                                  f" configuration states {sizes['edges']}")
-        say("loaded", edges=edges, native_ingest=native.available(),
+        say("loaded", edges=edges, loader=loader, native_ingest=native.available(),
             intern_s=t1 - t0, import_s=time.perf_counter() - t1)
         self.handle = None  # an entry that serves through a handle opens it
         self._call = cell["entry"].bind(self)
@@ -341,8 +372,10 @@ def run_cell(args, make_program=Program) -> int:
     pool = entry.requests(cell, w, np.random.default_rng([args.seed, 1]))
     t = stage("requests_s", t)
     rng = np.random.default_rng([args.seed, 2])
-    first = _checks.to_rels(cell["world"].PROBE, *cell["world"].make_probes(
-        w, cell["sizes"], rng, 4))  # whatever the entry: a check of four probes
+    # whatever the entry: a check of four of the world's own probes, with
+    # whatever request context the cell's checks carry
+    first = _checks.probe_rels(cell["world"], cell["world"].make_probes(
+        w, cell["sizes"], rng, 4))
     warm = entry.warm_requests(cell, w, rng)
     program.first_answer(first)
     t = stage("first_answer_s", t)

@@ -38,11 +38,76 @@ def test_every_seed_gives_the_same_strata_in_another_order(world):
     assert all(np.array_equal(a, b) for a, b in zip(drawn[0], drawn[1]))
     assert not np.array_equal(drawn[0][1], drawn[2][1])
     assert not np.array_equal(drawn[0][2], drawn[2][2])  # shuffled by the seed
-    # the full mix is 256 lookups, 160 / 28 / 4 / 64
+    # the full mix is 1,024 lookups, 640 / 112 / 16 / 256 (PR 35: four times
+    # PR 31's, in its proportions; a viewer of each of the 16 level-1 folders)
     assert run.load_cell(CELL, rehearse=False)["traffic"]["strata"] == {
-        "users": 160, "level2_viewers": 28, "level1_viewers": 4, "documents": 64}
+        "users": 640, "level2_viewers": 112, "level1_viewers": 16, "documents": 256}
     with pytest.raises(ValueError, match="level3_viewers"):
         lookups_of(world, 7, {"level3_viewers": 1})
+
+
+def test_the_top_of_the_tree_has_one_shape_and_a_seed_draws_the_rest():
+    """``fix_top``: on every seed the root and its 16 children are viewed by
+    the same kinds (user / group) and the groups have the same number of
+    groups nested below them; who they are differs; nothing else of the drawn
+    world changes, and every folder keeps exactly one viewer edge."""
+    cell = run.load_cell(CELL, rehearse=True)
+    mod, sizes = cell["world"], cell["sizes"]
+    shapes, whos = [], []
+    for seed in (7, 9, 2**31 + 5):
+        w, drawn = mod.build_world(sizes, seed), mod.draw_world(sizes, seed)
+        for key in drawn:
+            same = all(np.array_equal(a, b) for a, b in zip(drawn[key], w[key]))
+            assert same == (key not in ("folder_group", "folder_user")), key
+        (gf, gg), (uf, uu) = w["folder_group"], w["folder_user"]
+        assert np.array_equal(np.sort(np.concatenate([gf, uf])),
+                              np.arange(sizes["folders"]))
+        assert np.all(np.diff(gf) > 0) and np.all(np.diff(uf) > 0)
+        nested = dict(zip(*w["group_group"]))  # group -> the group nested in it
+
+        def below(g):
+            return 0 if g not in nested else 1 + below(nested[g])
+
+        by_group = dict(zip(gf.tolist(), gg.tolist()))
+        shapes.append([below(by_group[f]) if f in by_group else None
+                       for f in range(17)])
+        whos.append([by_group.get(f, -1) for f in range(17)])
+        for key in ("folder_group", "folder_user"):  # below the top: as drawn
+            (f, v), (df, dv) = w[key], drawn[key]
+            assert np.array_equal(f[f >= 17], df[df >= 17])
+            assert np.array_equal(v[f >= 17], dv[df >= 17])
+    assert shapes[0] == shapes[1] == shapes[2] == [mod.top_viewer(f) for f in range(17)]
+    assert shapes[0][0] == 2 and {4, 3, 0, None} <= set(shapes[0][1:])
+    assert whos[0] != whos[1]
+
+
+def test_every_seed_draws_its_users_from_the_same_size_classes(world):
+    """``answer_bounds`` is what it says, from the edge lists alone (the sum of
+    a user's direct documents, its groups' and the documents at or below what
+    either views: never under the true answer, equal where nothing overlaps),
+    and the ``users`` of two seeds hold every size class in the same number, to
+    within one: the class's share of all users."""
+    cell, w = world
+    mod, sizes = cell["world"], cell["sizes"]
+    bound = mod.answer_bounds(w, sizes)
+    users = np.arange(0, sizes["users"], 7)
+    true = np.array([a.shape[0] for a in mod.lookup_reference(w, sizes)(
+        mod.RESOURCES, users)])
+    assert np.all(bound[users] >= true) and np.mean(bound[users] == true) > 0.5
+    below = mod.documents_below(w, sizes)
+    assert below[0] == sizes["docs"] and below[1:17].sum() + np.sum(
+        w["doc_folder"][1] == 0) == sizes["docs"]
+    classes = mod.size_class(bound)
+    share = np.bincount(classes) / classes.shape[0]
+    for seed in (7, 11):
+        _, keys, names = lookups_of(world, seed)
+        mine = keys[names == "users"]
+        drawn = np.bincount(classes[mine], minlength=share.shape[0])
+        assert np.all(np.abs(drawn - share * mine.shape[0]) < 1)
+    picked = mod.spread_over_classes(np.array([0] * 6 + [1] * 3 + [5]), 5,
+                                     np.random.default_rng(1))
+    assert sorted(np.array([0] * 6 + [1] * 3 + [5])[picked].tolist()) == [0, 0, 0, 1, 1]
+    assert np.unique(picked).shape[0] == 5
 
 
 @pytest.mark.parametrize("level,lo,hi", [(1, 1, 17), (2, 17, 273)])

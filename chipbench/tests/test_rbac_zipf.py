@@ -22,9 +22,10 @@ def world():
 def test_same_seed_same_probes_and_records_is_honoured(world):
     cell, w = world
     mod, sizes = cell["world"], cell["sizes"]
+    # probes as the entries read them: a tuple of columns, however many
     draw = lambda s: mod.make_probes(w, sizes, np.random.default_rng([s, 1]), 5000)
     assert all(np.array_equal(a, b) for a, b in zip(draw(7), draw(7)))
-    assert not np.array_equal(draw(7)[0], draw(8)[0])
+    assert not any(np.array_equal(a, b) for a, b in zip(draw(7), draw(8)))
     assert w["records"][0].shape == (sizes["records"],) == w["zipfian_cdf"].shape
     assert cell["config"]["sizes"]["records"] == 10_000_000
     at = mod.record_indices(w, np.random.default_rng(3), 200_000)

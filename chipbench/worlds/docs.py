@@ -52,11 +52,60 @@ NEWEST = "doc_user"
 GROUP_DEPTH = 5  # nesting chains break every 5 groups
 FOLDER_ARITY = 16
 MEMBERS_PER_GROUP = 6
+#: the folder levels whose viewers have one shape on every seed (0 and 1: the
+#: root and its 16 children), see ``fix_top``
+TOP_LEVELS = 2
+
+
+def top_viewer(folder: int):
+    """The shape of a top folder's viewer, the same on every seed: None for a
+    user, else how many groups are nested below the group that views it
+    (0 .. GROUP_DEPTH - 1).  Kinds alternate and the depths cycle, as a draw
+    gives them on average (half groups, every depth as often); the root's is a
+    group with two nested below it."""
+    return (folder // 2 + 2) % GROUP_DEPTH if folder % 2 == 0 else None
 
 
 def build_world(size: dict, seed: int) -> dict:
     """Edge lists per relation shape, as index pairs, exactly
-    ``size['edges']`` edges in total."""
+    ``size['edges']`` edges in total: ``draw_world``'s, with the top of the
+    folder tree given its fixed shape."""
+    return fix_top(draw_world(size, seed), size, seed)
+
+
+def fix_top(w: dict, size: dict, seed: int) -> dict:
+    """Every document lies under the root and under one of its 16 children,
+    so what views those 17 folders is paid by every lookup of a world: drawn,
+    it made one world in ten send all its LookupSubjects over the fused
+    rounds (the root's viewer a group with four nested below it: a third off
+    the lookup cell's rate, PERF.md) and gave every other world its own
+    number of rounds a lookup and its own level-1 answers.
+    Here the *shape* of those viewers (user or group, and the group's place in
+    its nesting chain) is ``top_viewer``'s on every seed; *who* they are still
+    comes from the seed (stream ``[seed, 4]``).  One viewer edge a folder is
+    swapped for another, so every count stays what ``draw_world`` made it."""
+    U, G = size["users"], size["groups"]
+    top = folders_of_level(TOP_LEVELS - 1, size["folders"])[1]
+    rng = np.random.default_rng([seed, 4])
+    chain = rng.integers(0, max(G // GROUP_DEPTH, 1), top) * GROUP_DEPTH
+    user = rng.integers(0, U, top)
+    shape = [top_viewer(f) for f in range(top)]
+    by_group = np.array([d is not None for d in shape], bool)
+    group = np.minimum(chain + np.array([GROUP_DEPTH - 1 - (d or 0) for d in shape]),
+                       G - 1)
+    f = np.arange(top)
+    for key, mine, who in (("folder_group", by_group, group),
+                           ("folder_user", ~by_group, user)):
+        folders, viewers = w[key]
+        below = folders >= top  # the lists stay sorted by folder
+        w[key] = (np.concatenate([f[mine], folders[below]]),
+                  np.concatenate([who[mine], viewers[below]]))
+    return w
+
+
+def draw_world(size: dict, seed: int) -> dict:
+    """The world as the seed alone draws it: every folder's viewer, the top
+    folders' too, a user or any group with equal chance."""
     rng = np.random.default_rng(seed)
     U, G, F, D = size["users"], size["groups"], size["folders"], size["docs"]
     w = {}
@@ -198,7 +247,8 @@ RESOURCES, SUBJECTS = 0, 1  # the two kinds of lookup
 #: stratum -> (kind, folder level or None).  A folder stratum looks up a
 #: user who views, or is a member (half of them through nesting) of the
 #: group that views, a folder that many steps below the root (level 1:
-#: indices 1-16, level 2: 17-272); the others draw their key uniformly.
+#: indices 1-16, level 2: 17-272); ``documents`` draws its keys uniformly,
+#: ``users`` uniformly inside each class of answer size, a class in its share.
 #: ``make_lookups`` draws the strata in this order.
 LOOKUP_STRATA = {
     "users": (RESOURCES, None),
@@ -215,6 +265,57 @@ def folders_of_level(level: int, F: int):
     return min(lo, F), min(hi, F)
 
 
+def documents_below(w, size: dict) -> np.ndarray:
+    """For each folder, the documents in it and in every folder below it.
+    Built once per world."""
+    below = w.get("_documents_below")
+    if below is None:
+        F = size["folders"]
+        below = w["_documents_below"] = np.bincount(w["doc_folder"][1], minlength=F)
+        for f in range(F - 1, 0, -1):  # a parent comes before its children
+            below[(f - 1) // FOLDER_ARITY] += below[f]
+    return below
+
+
+def answer_bounds(w, size: dict) -> np.ndarray:
+    """For each user, a bound on the documents it views, from counts alone:
+    its direct documents, those of every group it is a member of (nested to
+    any depth), and all the documents at or below each folder that it or
+    such a group views — the size of its LookupResources answer where none
+    of these overlap.  Built once per world."""
+    bound = w.get("_answer_bounds")
+    if bound is None:
+        U, G = size["users"], size["groups"]
+        below = documents_below(w, size)
+        of_group = np.bincount(w["doc_group"][1], minlength=G)
+        np.add.at(of_group, w["folder_group"][1], below[w["folder_group"][0]])
+        bound = np.bincount(w["doc_user"][1], minlength=U)
+        np.add.at(bound, w["folder_user"][1], below[w["folder_user"][0]])
+        members = member_closure(pair_keys(*w["group_user"]),
+                                 *w["group_group"], G)
+        np.add.at(bound, members & 0xFFFFFFFF, of_group[members >> 32])
+        w["_answer_bounds"] = bound
+    return bound
+
+
+def size_class(documents) -> np.ndarray:
+    """The class of an answer of about that many documents: its power of two."""
+    return np.log2(np.maximum(documents, 16)).astype(np.int64)
+
+
+def spread_over_classes(classes: np.ndarray, n: int, rng) -> np.ndarray:
+    """``n`` distinct indices into ``classes``, each class given its share of
+    them (largest remainders first), drawn inside a class by ``rng``: a
+    uniform draw's make-up without its luck."""
+    values, counts = np.unique(classes, return_counts=True)
+    exact = n * counts / counts.sum()
+    take = np.floor(exact).astype(np.int64)
+    order = np.argsort(-(exact - take), kind="stable")
+    take[order[:n - int(take.sum())]] += 1
+    return np.concatenate([rng.choice(np.nonzero(classes == v)[0], t, replace=False)
+                           for v, t in zip(values, take)])
+
+
 def viewer_of(ix, rng, folders):
     """One user per folder who views it: its viewer, or a member (half of
     them through nesting) of the group that is its viewer."""
@@ -228,7 +329,7 @@ def make_lookups(w, size: dict, rng, strata: dict):
     """``sum(strata.values())`` lookups as (kinds, keys, stratum names): the
     key of a RESOURCES lookup is a user index, of a SUBJECTS lookup a
     document index.  Every seed gives the same strata, with other keys
-    (distinct inside a stratum's draw), in another order."""
+    (distinct inside ``users`` and ``documents``), in another order."""
     unknown = set(strata) - set(LOOKUP_STRATA)
     if unknown:
         raise ValueError(f"no lookup stratum {sorted(unknown)}; this world has"
@@ -237,12 +338,19 @@ def make_lookups(w, size: dict, rng, strata: dict):
     kinds, keys, names = [], [], []
     for name, (kind, level) in LOOKUP_STRATA.items():
         n = int(strata.get(name, 0))
-        if level is None:
-            domain = size["users" if kind == RESOURCES else "docs"]
-            key = rng.choice(domain, n, replace=False)
+        if kind == RESOURCES:
+            # a user's answer is a few documents or most of a folder tree, and
+            # a folder's half a thousand or ninety thousand (the tree's last
+            # level is not full): drawn uniformly, the keys would change the
+            # work with the seed.  So the users — all of them, or one viewer
+            # of each folder of the level — come from every size class in the
+            # class's own share, which is a seed's to within one
+            users = (np.arange(size["users"]) if level is None else viewer_of(
+                ix, rng, np.arange(*folders_of_level(level, size["folders"]))))
+            classes = size_class(answer_bounds(w, size)[users])
+            key = users[spread_over_classes(classes, n, rng)]
         else:
-            lo, hi = folders_of_level(level, size["folders"])
-            key = viewer_of(ix, rng, lo + rng.choice(hi - lo, n, replace=False))
+            key = rng.choice(size["docs"], n, replace=False)
         kinds.append(np.full(n, kind, np.int8))
         keys.append(np.asarray(key, np.int64))
         names += [name] * n
