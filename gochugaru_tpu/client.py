@@ -32,6 +32,7 @@ from typing import (
     Iterable,
     Iterator,
     List,
+    Mapping,
     NamedTuple,
     Optional,
     Sequence,
@@ -1748,11 +1749,17 @@ class Client:
         subject_type: str,
         subject_ids: Sequence[str],
         subject_relation: str = "",
+        caveat_name: str = "",
+        context_ids=None,
+        contexts: Sequence[Mapping[str, Any]] = (),
     ) -> None:
         """Columnar bulk restore: one relationship shape, ids as parallel
         string columns — the native-path complement of
         ``import_relationships`` for the plain rows that dominate
         restores (no per-edge objects; batch interning; one validation).
+        ``caveat_name`` writes every row ``with`` that caveat;
+        ``context_ids`` (an int column, −1 for none) index ``contexts``,
+        the distinct stored-context dicts of the call.
         Falls back to a retried TOUCH import on AlreadyExists, like the
         reference's recovery (client/client.go:448-463)."""
         self._check_overlap(ctx)
@@ -1760,7 +1767,8 @@ class Client:
             resource_type=resource_type, resource_ids=resource_ids,
             resource_relation=resource_relation,
             subject_type=subject_type, subject_ids=subject_ids,
-            subject_relation=subject_relation,
+            subject_relation=subject_relation, caveat_name=caveat_name,
+            context_ids=context_ids, contexts=contexts,
         )
         try:
             self._store.import_columns(**kw)
@@ -1810,18 +1818,25 @@ class Client:
         resource_relation: str,
         subject_ids,
         subject_relation: str = "",
+        caveat_name: str = "",
+        context_ids=None,
+        contexts: Sequence[Mapping[str, Any]] = (),
     ) -> None:
         """Pre-interned columnar bulk restore: int node-id columns from
         THIS store's interner (``export_relationship_id_columns``
         chunks, or ``Interner.node_batch`` results) — no string work at
         all, the fastest restore path (~5x the string-columnar rate).
-        Rows may mix resource/subject types.  Falls back to a retried
-        TOUCH import on AlreadyExists, like the reference's recovery
+        Rows may mix resource/subject types.  ``caveat_name``,
+        ``context_ids`` and ``contexts`` as in
+        ``import_relationship_columns``.  Falls back to a retried TOUCH
+        import on AlreadyExists, like the reference's recovery
         (client/client.go:448-463)."""
         self._check_overlap(ctx)
         kw = dict(
             resource_ids=resource_ids, resource_relation=resource_relation,
             subject_ids=subject_ids, subject_relation=subject_relation,
+            caveat_name=caveat_name, context_ids=context_ids,
+            contexts=contexts,
         )
         try:
             self._store.import_interned_columns(**kw)

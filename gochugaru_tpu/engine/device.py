@@ -1056,7 +1056,11 @@ class DeviceEngine:
         runs once per row.  The batch's node ids come from one
         ``interner.lookup_pairs`` call: ``engine.intern_s`` is observed
         around it, ``intern.lookups`` counts its 2·B keys and
-        ``intern.batch_calls`` the call."""
+        ``intern.batch_calls`` the call.  On a schema with caveats the
+        request-context dedup and encode are ``engine.context_s``, and
+        ``engine.context_batches`` / ``engine.query_contexts`` /
+        ``engine.context_checks`` count the batch, its distinct request
+        contexts and its checks that carry one."""
         with _trace.stage("engine.lower", span, cpu=True) as st:
             return self._lower(snap, rels, strings, st)
 
@@ -1065,23 +1069,6 @@ class DeviceEngine:
         interner = snap.interner
         slot_of = self.compiled.slot_of_name
         wc_of = snap.wildcard_node_of_type
-
-        q_ctx = np.full(B, -1, np.int32)
-
-        # dedup request contexts (the caveat_context of the query
-        # relationship IS the request context, client/client.go:241-259)
-        ctx_rows: List[Mapping] = []
-        ctx_index: Dict[str, int] = {}
-        if self.caveat_plan is not None:
-            for i, r in enumerate(rels):
-                if r.caveat_context:
-                    key = repr(sorted(r.caveat_context.items(), key=lambda kv: kv[0]))
-                    at = ctx_index.get(key)
-                    if at is None:
-                        at = len(ctx_rows)
-                        ctx_index[key] = at
-                        ctx_rows.append(r.caveat_context)
-                    q_ctx[i] = at
 
         res_type = [r.resource_type for r in rels]
         res_id = [r.resource_id for r in rels]
@@ -1128,17 +1115,44 @@ class DeviceEngine:
         q_res[no_slot] = -1
         q_srel[no_slot] = -1
 
+        # dedup request contexts (the caveat_context of the query
+        # relationship IS the request context, client/client.go:241-259)
+        # and encode them: ``engine.context_s``, a batch, on a schema
+        # with caveats
+        t0 = _time.perf_counter()
+        q_ctx = np.full(B, -1, np.int32)
+        ctx_rows: List[Mapping] = []
+        ctx_index: Dict[str, int] = {}
+        if self.caveat_plan is not None:
+            for i, r in enumerate(rels):
+                if r.caveat_context:
+                    key = repr(sorted(r.caveat_context.items(), key=lambda kv: kv[0]))
+                    at = ctx_index.get(key)
+                    if at is None:
+                        at = len(ctx_rows)
+                        ctx_index[key] = at
+                        ctx_rows.append(r.caveat_context)
+                    q_ctx[i] = at
+        qctx = self._encode_query_contexts(ctx_rows, strings)
+        context_s = _time.perf_counter() - t0
+
         m = metrics.default
         m.inc("intern.lookups", 2 * B)
         m.inc("intern.batch_calls")
         m.observe("engine.intern_s", intern_s)
         st.note(batch=B, intern_s=round(intern_s, 6))
+        if self.caveat_plan is not None:
+            m.observe("engine.context_s", context_s)
+            m.inc("engine.context_batches")
+            m.inc("engine.query_contexts", len(ctx_rows))
+            m.inc("engine.context_checks", int(np.count_nonzero(q_ctx >= 0)))
+            st.note(context_s=round(context_s, 6), contexts=len(ctx_rows))
         queries = {
             "q_res": q_res, "q_perm": q_perm, "q_subj": q_subj,
             "q_srel": q_srel, "q_wc": q_wc, "q_ctx": q_ctx,
             "q_self": q_self,
         }
-        return queries, self._encode_query_contexts(ctx_rows, strings)
+        return queries, qctx
 
     def _two_phase_call(
         self, dsnap: DeviceSnapshot, queries: Dict[str, np.ndarray],

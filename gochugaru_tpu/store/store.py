@@ -817,6 +817,9 @@ class Store:
         subject_type: str,
         subject_ids: Sequence[str],
         subject_relation: str = "",
+        caveat_name: str = "",
+        context_ids=None,
+        contexts: Sequence[Mapping[str, Any]] = (),
         touch: bool = False,
     ) -> str:
         """Columnar bulk import: one (resource type, relation, subject
@@ -825,16 +828,20 @@ class Store:
         points at (SURVEY.md §2.1 — "compress the boundary": intern
         strings host-side, ship int32 columns): no per-edge Relationship
         objects, one validation for the whole call, batch interning.
-        Caveated/expiring rows use the object path
-        (``import_relationships``).  Returns the minted revision; raises
-        AlreadyExistsError (nothing applied) on any live duplicate
-        unless ``touch``."""
+        ``caveat_name`` puts every row of the call under one caveat;
+        ``context_ids`` (−1 for none) index ``contexts``, the call's
+        distinct stored contexts (``_caveat_columns``).  Expiring rows
+        use the object path (``import_relationships``).  Returns the
+        minted revision; raises AlreadyExistsError (nothing applied) on
+        any live duplicate unless ``touch``."""
         B = len(resource_ids)
         if len(subject_ids) != B:
             raise ValueError("resource_ids and subject_ids lengths differ")
         with self._lock:
             compiled = self._require_schema()
             now_us = self._now_us()
+            caveat, ctx, novel = self._caveat_columns(
+                compiled, B, caveat_name, context_ids, contexts)
             # shape validation: wildcardness is part of the validation
             # shape, so a mixed batch validates BOTH representatives
             concrete = next((s for s in subject_ids if s != "*"), None)
@@ -849,6 +856,7 @@ class Store:
                     subject_type=subject_type,
                     subject_id=rep,
                     subject_relation=subject_relation,
+                    caveat_name=caveat_name,
                 ))
             if B == 0:
                 return RevisionToken(self._head_rev)
@@ -875,8 +883,8 @@ class Store:
                     slot_of[subject_relation] + 1 if subject_relation else 0,
                     np.int32,
                 ),
-                "caveat": np.zeros(B, np.int32),
-                "ctx": np.full(B, -1, np.int32),
+                "caveat": caveat,
+                "ctx": ctx,
                 "exp_us": np.zeros(B, np.int64),
             }
 
@@ -887,9 +895,11 @@ class Store:
                     f"@{subject_type}:{subject_ids[i]}{srel}"
                 )
 
-            return self._commit_columns_locked(
+            token = self._commit_columns_locked(
                 cols, now_us, touch, describe=describe
             )
+            self._adopt_contexts(novel)
+            return token
 
     def import_interned_columns(
         self,
@@ -898,6 +908,9 @@ class Store:
         resource_relation: str,
         subject_ids,
         subject_relation: str = "",
+        caveat_name: str = "",
+        context_ids=None,
+        contexts: Sequence[Mapping[str, Any]] = (),
         touch: bool = False,
     ) -> str:
         """Pre-interned columnar bulk import: node-id columns from THIS
@@ -906,11 +919,13 @@ class Store:
         hashing, no packing, no per-id Python.  Rows may mix resource
         and subject types freely; validation runs once per distinct
         (resource type, subject type, wildcardness) combination through
-        the same validator as the object path.  This is the 1B-edge
-        restore fast path (the reference's BulkImportRelationships
-        surface, client/client.go:438-465, at ~5x the string-columnar
-        rate).  Returns the minted revision; raises AlreadyExistsError
-        (nothing applied) on any live duplicate unless ``touch``."""
+        the same validator as the object path.  ``caveat_name``,
+        ``context_ids`` and ``contexts`` as in ``import_columns``.  This
+        is the 1B-edge restore fast path (the reference's
+        BulkImportRelationships surface, client/client.go:438-465, at
+        ~5x the string-columnar rate).  Returns the minted revision;
+        raises AlreadyExistsError (nothing applied) on any live duplicate
+        unless ``touch``."""
         res = np.ascontiguousarray(resource_ids, dtype=np.int32)
         subj = np.ascontiguousarray(subject_ids, dtype=np.int32)
         B = int(res.shape[0])
@@ -919,6 +934,8 @@ class Store:
         with self._lock:
             compiled = self._require_schema()
             now_us = self._now_us()
+            caveat, ctx, novel = self._caveat_columns(
+                compiled, B, caveat_name, context_ids, contexts)
             itn = self.interner
             NN = len(itn)
             if B:
@@ -970,6 +987,7 @@ class Store:
                         resource_relation=resource_relation,
                         subject_type=stype, subject_id=sid,
                         subject_relation=subject_relation,
+                        caveat_name=caveat_name,
                     ))
             if B == 0:
                 return RevisionToken(self._head_rev)
@@ -982,8 +1000,8 @@ class Store:
                     slot_of[subject_relation] + 1 if subject_relation else 0,
                     np.int32,
                 ),
-                "caveat": np.zeros(B, np.int32),
-                "ctx": np.full(B, -1, np.int32),
+                "caveat": caveat,
+                "ctx": ctx,
                 "exp_us": np.zeros(B, np.int64),
             }
 
@@ -996,9 +1014,62 @@ class Store:
                     f"@{stype}:{sid}{srel}"
                 )
 
-            return self._commit_columns_locked(
+            token = self._commit_columns_locked(
                 cols, now_us, touch, describe=describe
             )
+            self._adopt_contexts(novel)
+            return token
+
+    def _caveat_columns(
+        self, compiled: CompiledSchema, B: int, caveat_name: str,
+        context_ids, contexts: Sequence[Mapping[str, Any]],
+    ) -> Tuple[np.ndarray, np.ndarray, List[Tuple[str, Mapping[str, Any]]]]:
+        """The ``caveat`` and ``ctx`` columns of a columnar import, and
+        the call's stored contexts the pool lacks yet (appended by
+        ``_adopt_contexts`` once the commit has gone through, so a refused
+        call leaves the pool as it was).  Each distinct context of the
+        call is looked up in the store's pool once, not once per row;
+        the column is one gather.  The caveat itself is validated with
+        the call's representative rows; a context naming a parameter the
+        caveat does not declare, or an id out of range, refuses the call
+        here, before anything is applied."""
+        if not caveat_name:
+            if context_ids is not None or contexts:
+                raise ValueError("context_ids/contexts given without caveat_name")
+            return np.zeros(B, np.int32), np.full(B, -1, np.int32), []
+        cid = compiled.caveat_ids.get(caveat_name)
+        if cid is None:
+            raise SchemaValidationError(f"caveat `{caveat_name}` not found")
+        pool_at: List[int] = []
+        novel: List[Tuple[str, Mapping[str, Any]]] = []
+        seen: Dict[str, int] = {}
+        for c in contexts:
+            self._validate_caveat_context(
+                Relationship(caveat_name=caveat_name, caveat_context=c))
+            key = repr(sorted(c.items(), key=lambda kv: kv[0]))
+            at = self._base_ctx_index.get(key, seen.get(key))
+            if at is None:
+                at = seen[key] = len(self._base_contexts) + len(novel)
+                novel.append((key, dict(c)))
+            pool_at.append(at)
+        if context_ids is None:
+            return np.full(B, cid, np.int32), np.full(B, -1, np.int32), novel
+        ids = np.ascontiguousarray(context_ids, dtype=np.int64)
+        if ids.shape != (B,):
+            raise ValueError("context_ids and the id columns lengths differ")
+        if B and (int(ids.min()) < -1 or int(ids.max()) >= len(pool_at)):
+            raise ValueError(
+                f"context id out of range: {len(pool_at)} contexts given"
+            )
+        to_pool = np.asarray(pool_at + [-1], np.int32)  # -1 reads the last
+        return np.full(B, cid, np.int32), to_pool[ids], novel
+
+    def _adopt_contexts(self, novel) -> None:
+        """Append the committed call's new stored contexts to the pool,
+        at the indices ``_caveat_columns`` gave them."""
+        for key, c in novel:
+            self._base_ctx_index[key] = len(self._base_contexts)
+            self._base_contexts.append(c)
 
     def export_interned_columns_at(self, revision: str):
         """Interned columnar export at an exact snapshot: yields chunk

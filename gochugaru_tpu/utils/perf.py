@@ -280,6 +280,15 @@ def gathered_bytes_model(dsnap) -> BytesModel:
       range-group view and re-runs the leaf sites at a frontier widened
       by the per-slot arrow fanout (pow2-bucketed, exactly the lattice
       the kernel compiles).
+
+    Caveat gates, on a view whose ``*_hascav`` is set: every candidate
+    lane of a gated probe feeds the CEL VM, which reads one row of the
+    stored-context tables (``ectx_*``) and one of the request-context
+    tables (``qctx_*``, the same widths: both are ``encode_contexts`` of
+    one plan), charged as ``ectx`` and ``qctx``.  The gate columns
+    themselves ride inside the probes' rows (``cav``/``ctx`` lanes of
+    ``ehx``, ``pfx``, ``usx``, ``arx``) and are in those rows' widths.
+    A caveat-free snapshot charges none of this.
     """
     meta = dsnap.flat_meta
     if meta is None:
@@ -310,11 +319,27 @@ def gathered_bytes_model(dsnap) -> BytesModel:
 
     wc = 2 if meta.has_wc_edges else 1
     wcc = 2 if meta.has_wc_closure else 1
+    aligned_caps = {k: sum(caps) for k, _w, caps in meta.aligned}
+    ctx_row = sum(row(k) for k in ("ectx_vi", "ectx_vf", "ectx_pr", "ectx_host"))
+
+    def entries(tbl: str, cap: int) -> int:
+        """Candidate lanes one probe of ``tbl`` returns."""
+        if tbl + "_al" in arrs and tbl in aligned_caps:
+            return aligned_caps[tbl]
+        return cap
+
+    def gate(view: str, lanes: float) -> float:
+        """The context rows the VM reads for ``lanes`` candidates of a
+        gated view."""
+        if not getattr(meta, view + "_hascav", False):
+            return 0.0
+        return charge("ectx", lanes * ctx_row) + charge("qctx", lanes * ctx_row)
 
     def e_block(width: float) -> float:
         """The direct-edge probe at ``width`` lattice nodes."""
         if not meta.e_slots:
             return 0.0
+        g = gate("e", wc * width * entries("ehx", meta.e_cap))
         al = arrs.get("ehx_al")
         if al is not None:
             b = int(al.shape[1]) * int(np.dtype(al.dtype).itemsize)
@@ -324,8 +349,8 @@ def gathered_bytes_model(dsnap) -> BytesModel:
                 for k in arrs
                 if k.startswith("ehx_als")
             )
-            return charge("ehx_al", wc * width * (b + extra))
-        return charge("eh_off", wc * width * off("eh_off")) + charge(
+            return g + charge("ehx_al", wc * width * (b + extra))
+        return g + charge("eh_off", wc * width * off("eh_off")) + charge(
             "ehx", wc * width * meta.e_cap * row("ehx")
         )
 
@@ -353,6 +378,7 @@ def gathered_bytes_model(dsnap) -> BytesModel:
             charge("usr_off", width * off("usr_off"))
             + charge("usgx", width * meta.usr_cap * row("usgx"))
             + charge("usx", width * fan * row("usx"))
+            + gate("us", width * fan)
             + cl_block(width * fan)
         )
 
@@ -363,6 +389,7 @@ def gathered_bytes_model(dsnap) -> BytesModel:
         if meta.pf_has_e:
             total += charge("pfh_off", wc * width * off("pfh_off"))
             total += charge("pfx", wc * width * meta.pf_e_cap * row("pfx"))
+            total += gate("pf", wc * width * entries("pfx", meta.pf_e_cap))
         if meta.pf_has_u:
             if meta.pf_direct:
                 total += charge("pfu_start", width * 2 * off("pfu_start"))
@@ -440,6 +467,7 @@ def gathered_bytes_model(dsnap) -> BytesModel:
                 charge("arr_off", width * off("arr_off"))
                 + charge("argx", width * meta.arr_cap * row("argx"))
                 + charge("arx", width * fan * row("arx"))
+                + gate("ar", width * fan)
             )
             width *= fan
             a += leaf_sites(width)
