@@ -54,6 +54,7 @@ from __future__ import annotations
 
 import datetime as _dt
 from dataclasses import dataclass
+from itertools import count
 from typing import Any, Callable, Dict, List, Mapping, Optional, Sequence, Tuple
 
 import numpy as np
@@ -824,6 +825,75 @@ def encode_contexts(
                     vi[i, slot] = string_id(value)
                 present[i, slot] = True
     return ContextTable(vi=vi, vf=vf, present=present, host=host)
+
+
+class _Missing:
+    """A parameter a context does not name: not an explicit ``None``,
+    which ``encode_contexts`` flags for the host."""
+
+
+_MISSING = _Missing()
+#: value types whose equality is ``encode_contexts``' own: equal values of
+#: one such type encode alike.  Any other type keys by ``repr`` (a float's
+#: ``-0.0 == 0.0``, a list is unhashable)
+_EXACT_TYPES = frozenset({str, int, bool, type(None), _Missing})
+
+
+def _compact(key: np.ndarray) -> Tuple[np.ndarray, int]:
+    """``key`` renumbered densely from 0, and its new width."""
+    _, key = np.unique(key, return_inverse=True)
+    key = key.reshape(-1)
+    return key, int(key.max(initial=-1)) + 1
+
+
+def dedup_contexts(
+    plan: CaveatDevicePlan, contexts: Sequence[Mapping[str, Any]]
+) -> Tuple[np.ndarray, List[Mapping[str, Any]], int, int]:
+    """Group request contexts that encode alike, one parameter column at a
+    time: ``(index, rows, keyed, by_repr)``, where ``rows`` holds the first
+    context of each group, in the order they come, and ``contexts[i]``
+    encodes as ``rows[index[i]]``.
+
+    Only the plan's parameters are read — a key no caveat declares encodes
+    to nothing.  A column is factorised by value, or by ``(type, value)``
+    where it mixes types (``1``, ``True`` and ``1.0`` are equal in Python
+    and encode apart); a column holding a value outside ``_EXACT_TYPES``
+    is keyed by ``(type, repr)``.  ``keyed`` / ``by_repr`` count the
+    columns of each kind; a parameter no context names is no column."""
+    n = len(contexts)
+    # the combined key indexes a table of ``width`` groups below: keep it
+    # under ``room`` (a compacted key is under n, so n·codes fits int64)
+    room = max(4 * n, 1 << 16)
+    key = np.zeros(n, np.int64)
+    width, keyed, by_repr = 1, 0, 0
+    for pname in plan.slots_of_param:
+        col = [c.get(pname, _MISSING) for c in contexts]
+        types = set(map(type, col))
+        if types == {_Missing}:
+            continue
+        if types <= _EXACT_TYPES:
+            keyed += 1
+            keys = col if len(types) == 1 else list(zip(map(type, col), col))
+        else:
+            by_repr += 1
+            keys = list(zip(map(type, col), map(repr, col)))
+        codes = dict(zip(dict.fromkeys(keys), count()))
+        if width * len(codes) > room:
+            key, width = _compact(key)
+        key = key * len(codes) + np.fromiter(map(codes.__getitem__, keys), np.int64, n)
+        width *= len(codes)
+    if width > room:
+        key, width = _compact(key)
+    first = np.full(width, n, np.int64)
+    np.minimum.at(first, key, np.arange(n, dtype=np.int64))
+    used = np.flatnonzero(first < n)
+    # groups in the order of their first context, so that the encoder
+    # meets unknown strings in the batch's order (its fresh ids follow it)
+    order = np.argsort(first[used])
+    rank = np.empty(width, np.int32)
+    rank[used[order]] = np.arange(used.size, dtype=np.int32)
+    return (rank[key], [contexts[i] for i in first[used[order]].tolist()],
+            keyed, by_repr)
 
 
 def make_tri_fn(plan: CaveatDevicePlan):

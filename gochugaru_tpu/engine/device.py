@@ -44,6 +44,7 @@ from jax import lax
 from ..caveats.device import (
     CaveatDevicePlan,
     build_caveat_plan,
+    dedup_contexts,
     encode_contexts,
     make_tri_fn,
 )
@@ -1053,14 +1054,18 @@ class DeviceEngine:
         int32 query columns and the request-context tables, as one
         ``engine.lower`` stage (wall, and thread CPU while it records).
         Column operations only: nothing but the pulls of the six fields
-        runs once per row.  The batch's node ids come from one
-        ``interner.lookup_pairs`` call: ``engine.intern_s`` is observed
+        (on a schema with caveats, of the request context and of each
+        parameter it names) runs once per row.  The batch's node ids come
+        from one ``interner.lookup_pairs`` call: ``engine.intern_s`` is observed
         around it, ``intern.lookups`` counts its 2·B keys and
         ``intern.batch_calls`` the call.  On a schema with caveats the
         request-context dedup and encode are ``engine.context_s``, and
         ``engine.context_batches`` / ``engine.query_contexts`` /
         ``engine.context_checks`` count the batch, its distinct request
-        contexts and its checks that carry one."""
+        contexts and its checks that carry one;
+        ``engine.context_keyed_columns`` / ``engine.context_repr_columns``
+        the parameter columns ``caveats.device.dedup_contexts`` keyed by
+        value / by ``repr``."""
         with _trace.stage("engine.lower", span, cpu=True) as st:
             return self._lower(snap, rels, strings, st)
 
@@ -1117,22 +1122,22 @@ class DeviceEngine:
 
         # dedup request contexts (the caveat_context of the query
         # relationship IS the request context, client/client.go:241-259)
-        # and encode them: ``engine.context_s``, a batch, on a schema
-        # with caveats
+        # a parameter column at a time, and encode them:
+        # ``engine.context_s``, a batch, on a schema with caveats.  A check
+        # with an empty context keeps -1; any other gets a row, even one
+        # that names no parameter
         t0 = _time.perf_counter()
         q_ctx = np.full(B, -1, np.int32)
         ctx_rows: List[Mapping] = []
-        ctx_index: Dict[str, int] = {}
+        keyed = by_repr = 0
         if self.caveat_plan is not None:
-            for i, r in enumerate(rels):
-                if r.caveat_context:
-                    key = repr(sorted(r.caveat_context.items(), key=lambda kv: kv[0]))
-                    at = ctx_index.get(key)
-                    if at is None:
-                        at = len(ctx_rows)
-                        ctx_index[key] = at
-                        ctx_rows.append(r.caveat_context)
-                    q_ctx[i] = at
+            contexts = [r.caveat_context for r in rels]
+            at = np.flatnonzero(np.fromiter(map(bool, contexts), bool, B))
+            if at.size:
+                if at.size < B:
+                    contexts = [contexts[i] for i in at.tolist()]
+                q_ctx[at], ctx_rows, keyed, by_repr = dedup_contexts(
+                    self.caveat_plan, contexts)
         qctx = self._encode_query_contexts(ctx_rows, strings)
         context_s = _time.perf_counter() - t0
 
@@ -1146,6 +1151,8 @@ class DeviceEngine:
             m.inc("engine.context_batches")
             m.inc("engine.query_contexts", len(ctx_rows))
             m.inc("engine.context_checks", int(np.count_nonzero(q_ctx >= 0)))
+            m.inc("engine.context_keyed_columns", keyed)
+            m.inc("engine.context_repr_columns", by_repr)
             st.note(context_s=round(context_s, 6), contexts=len(ctx_rows))
         queries = {
             "q_res": q_res, "q_perm": q_perm, "q_subj": q_subj,

@@ -54,7 +54,8 @@ STORED = ([{"edge_tenant": f"t{k}", "tier": 2} for k in range(T)]
 CS = consistency.full()
 HOST = ("checks.oracle", "checks.fallback_conditional", "checks.fallback_overflow")
 CONTEXT = ("engine.context_s.count", "engine.context_batches",
-           "engine.query_contexts", "engine.context_checks")
+           "engine.query_contexts", "engine.context_checks",
+           "engine.context_keyed_columns", "engine.context_repr_columns")
 
 
 def build_world(seed: int) -> dict:
@@ -357,10 +358,11 @@ def test_the_context_timer_and_counters(clients, world, caveated):
     c.check(background(), CS, *rels)
     gained = [a - b for a, b in zip(counters(CONTEXT), before)]
     if not caveated:
-        assert gained == [0, 0, 0, 0]
+        assert gained == [0] * len(CONTEXT)
         return
     distinct = len(set(zip(tenants.tolist(), tiers.tolist())))
-    assert gained == [1, 1, distinct, 300]
+    # two parameter columns, tenant and tier, both keyed by value
+    assert gained == [1, 1, distinct, 300, 2, 0]
 
 
 def bytes_world(caveated: bool):
