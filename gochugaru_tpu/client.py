@@ -1880,6 +1880,9 @@ class Client:
         schemas the device can't evaluate.  Transient dispatch faults
         (``lookup.dispatch`` site) retry under the reference's backoff
         envelope like checks do."""
+        import time as _time
+
+        t0 = _time.perf_counter()
         self._check_overlap(ctx)
         subj_type, subj_id, subj_rel = parse_object_set(subject)
         obj_type, obj_rel = parse_typed_relation(permission)
@@ -1889,24 +1892,25 @@ class Client:
             from .engine.lookup import lookup_resources_device
 
             self._metrics.inc("lookups.resources_device")
-            ids = retry_retriable_errors(
-                ctx,
-                lambda: lookup_resources_device(
-                    engine, self._dsnap_for(engine, snap),
-                    obj_type, obj_rel, subj_type, subj_id, subj_rel,
-                    oracle_factory=lambda: self._oracle_for(snap),
-                ),
-            )
+
+            def run() -> List[str]:
+                return retry_retriable_errors(
+                    ctx,
+                    lambda: lookup_resources_device(
+                        engine, self._dsnap_for(engine, snap),
+                        obj_type, obj_rel, subj_type, subj_id, subj_rel,
+                        oracle_factory=lambda: self._oracle_for(snap),
+                    ),
+                )
         else:
             self._metrics.inc("lookups.resources_oracle")
-            ids = self._oracle_for(snap).lookup_resources(
-                obj_type, obj_rel, subj_type, subj_id, subj_rel
-            )
-        for rid in ids:
-            err = ctx.err()
-            if err is not None:
-                raise err
-            yield rid
+
+            def run() -> List[str]:
+                _trace.current().set_attr("path", "oracle")
+                return list(self._oracle_for(snap).lookup_resources(
+                    obj_type, obj_rel, subj_type, subj_id, subj_rel
+                ))
+        yield from self._lookup_stream(ctx, "resources", t0, run)
 
     def lookup_subjects(
         self, ctx: Context, cs: Strategy, resource: str, permission: str, subject: str
@@ -1917,6 +1921,9 @@ class Client:
 
         Device path mirrors lookup_resources: forward frontier expansion
         bounds the candidates, batched device checks filter exactly."""
+        import time as _time
+
+        t0 = _time.perf_counter()
         self._check_overlap(ctx)
         res_type, res_id, _ = parse_object_set(resource)
         subj_type, _, subj_rel = subject.partition("#")
@@ -1926,24 +1933,57 @@ class Client:
             from .engine.lookup import lookup_subjects_device
 
             self._metrics.inc("lookups.subjects_device")
-            ids = retry_retriable_errors(
-                ctx,
-                lambda: lookup_subjects_device(
-                    engine, self._dsnap_for(engine, snap),
-                    res_type, res_id, permission, subj_type, subj_rel,
-                    oracle_factory=lambda: self._oracle_for(snap),
-                ),
-            )
+
+            def run() -> List[str]:
+                return retry_retriable_errors(
+                    ctx,
+                    lambda: lookup_subjects_device(
+                        engine, self._dsnap_for(engine, snap),
+                        res_type, res_id, permission, subj_type, subj_rel,
+                        oracle_factory=lambda: self._oracle_for(snap),
+                    ),
+                )
         else:
             self._metrics.inc("lookups.subjects_oracle")
-            ids = self._oracle_for(snap).lookup_subjects(
-                res_type, res_id, permission, subj_type, subj_rel
-            )
-        for sid in ids:
-            err = ctx.err()
-            if err is not None:
-                raise err
-            yield sid
+
+            def run() -> List[str]:
+                _trace.current().set_attr("path", "oracle")
+                return list(self._oracle_for(snap).lookup_subjects(
+                    res_type, res_id, permission, subj_type, subj_rel
+                ))
+        yield from self._lookup_stream(ctx, "subjects", t0, run)
+
+    def _lookup_stream(self, ctx: Context, kind: str, t0: float,
+                       run) -> Iterator[str]:
+        """The ids of one lookup, streamed to the caller.  ``run()``
+        answers it whole while the ``lookup`` root span is the thread's
+        current span — the lookup path's stages and tallies read it
+        there — and the span is never current across a ``yield``.  The
+        span ends, and the timer-only stage ``client.lookup`` (from the
+        generator's first ``next``, ``t0``, on; it encloses the stages)
+        is observed, when the last id has been taken; a caller that
+        stops early records neither."""
+        import time as _time
+
+        root = _trace.root_span("lookup", kind=kind)
+        tail = _trace.tail_clock() if root is _trace.NOOP else 0.0
+        try:
+            with _trace.activated(root):
+                ids = run()
+            for rid in ids:
+                err = ctx.err()
+                if err is not None:
+                    raise err
+                yield rid
+        except Exception as exc:
+            root.set_attr("error", type(exc).__name__)
+            root.end()
+            raise
+        t1 = _time.perf_counter()
+        root.set_attr("ids", len(ids))
+        root.end(t1)
+        _trace.observe_stage("client.lookup", t0, t1)
+        _trace.maybe_keep_slow("lookup", tail, kind=kind, ids=len(ids))
 
     def lookup_resources_page(
         self, ctx: Context, cs: Strategy, permission: str, subject: str,

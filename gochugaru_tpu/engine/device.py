@@ -1706,8 +1706,8 @@ class DeviceEngine:
             dsnap, q_res, q_perm, q_subj, q_srel, q_wc, q_ctx, qctx_rows,
             span=span,
         )
-        now_flat = jnp.int32(snap.now_rel32(now_us))
         with _trace.stage("engine.enqueue", span):
+            now_flat = jnp.int32(snap.now_rel32(now_us))
             out = self._flat_call(
                 dsnap, queries, qctx, now_flat, B, bucket_min=bucket_min
             )

@@ -47,6 +47,7 @@ import numpy as np
 from ..native.sort import argsort1, lexsort2
 from ..rel.relationship import WILDCARD_ID
 from ..store.snapshot import Snapshot
+from ..utils import trace as _trace
 
 #: padding floor for the lookup exact-filter batch (see _exact_filter)
 LOOKUP_BUCKET_MIN = 4096
@@ -216,16 +217,20 @@ def _exact_filter(
     only ever report "possible" but the host answer is definite."""
     # coarse bucket floor: per-subject candidate counts vary, and every
     # fresh pow2 bucket costs a kernel retrace — with a 4096 floor, warm
-    # lookups share one compiled program
+    # lookups share one compiled program.  The check keeps its own
+    # engine.lower / .enqueue / .fetch stages, on the lookup's span
+    sp = _trace.current()
     d, p, ovf = engine.check_columns(
         dsnap, q_res, q_perm, q_subj, q_srel=q_srel, q_wc=q_wc,
-        now_us=now_us, bucket_min=LOOKUP_BUCKET_MIN,
+        now_us=now_us, bucket_min=LOOKUP_BUCKET_MIN, span=sp,
     )
     needs_host = ovf | (p & ~d)
     granted = cand[d & ~needs_host].astype(np.int64, copy=False)
-    host = [n for n in cand[needs_host].tolist() if oracle_check(n)]
-    if host:
-        granted = np.concatenate([granted, np.asarray(host, np.int64)])
+    if needs_host.any():
+        with _trace.stage("lookup.oracle", sp):
+            host = [n for n in cand[needs_host].tolist() if oracle_check(n)]
+        if host:
+            granted = np.concatenate([granted, np.asarray(host, np.int64)])
     return granted
 
 
@@ -607,48 +612,51 @@ def lookup_resources_page(
     host walker (delta-exact through advance_lookup_index)."""
     from . import spmv
 
-    names = (resource_type, permission, subject_type, subject_id,
-             subject_relation)
-    # evaluation time resolves ONCE and rides the cursor: a recompute-
-    # resume must re-gate expirations at the same instant (spmv.py)
-    now_us = spmv.resolve_now_us(cursor, now_us)
-    token = spmv.query_token("res", dsnap.revision, now_us, *names)
-    resolved = _resolve_resources(dsnap, *names)
-    if resolved is None:
-        return [], None
-    rtid, perm_slot, srel_slot, subj_node, wc_node = resolved
-    filt, ids_of = _res_filter(
-        engine, dsnap, resolved, names, now_us, oracle_factory
-    )
-    snap = dsnap.snapshot
+    sp = _trace.current()
+    # everything up to the stream's first candidate: one stage a page
+    with _trace.stage("lookup.resolve", sp):
+        names = (resource_type, permission, subject_type, subject_id,
+                 subject_relation)
+        # evaluation time resolves ONCE and rides the cursor: a recompute-
+        # resume must re-gate expirations at the same instant (spmv.py)
+        now_us = spmv.resolve_now_us(cursor, now_us)
+        token = spmv.query_token("res", dsnap.revision, now_us, *names)
+        resolved = _resolve_resources(dsnap, *names)
+        if resolved is None:
+            return [], None
+        rtid, perm_slot, srel_slot, subj_node, wc_node = resolved
+        filt, ids_of = _res_filter(
+            engine, dsnap, resolved, names, now_us, oracle_factory
+        )
+        snap = dsnap.snapshot
 
-    def make_stream():
-        if spmv.frontier_ok(engine, dsnap):
-            from ..utils import metrics as _m
+        def make_stream():
+            if spmv.frontier_ok(engine, dsnap):
+                from ..utils import metrics as _m
 
-            _m.default.inc("lookups.frontier")
-            st = spmv.state_for(engine, dsnap)
-            if st._spmm is not None:
-                # served by the fused K-hop SpMM program (engine/spmm.py)
-                _m.default.inc("lookups.fused")
-            cands = st.resource_candidates(
-                rtid, subj_node, srel_slot, wc_node, now_us
-            )
-            cost = _frontier_stream_bytes(dsnap.flat_meta, snap)
-        else:
-            from ..utils import metrics as _m
+                _m.default.inc("lookups.frontier")
+                st = spmv.state_for(engine, dsnap)
+                if st._spmm is not None:
+                    # served by the fused K-hop SpMM program (engine/spmm.py)
+                    _m.default.inc("lookups.fused")
+                cands = st.resource_candidates(
+                    rtid, subj_node, srel_slot, wc_node, now_us
+                )
+                cost = _frontier_stream_bytes(dsnap.flat_meta, snap)
+            else:
+                from ..utils import metrics as _m
 
-            _m.default.inc("lookups.walker")
-            seen = _walk_resource_candidates(
-                snap, subj_node, srel_slot, wc_node
-            )
-            cands = _one_block(seen[snap.node_type[seen] == rtid])
-            cost = 1 << 20
-        return spmv._ResultStream(cands, filt, ids_of, cost_bytes=cost)
+                _m.default.inc("lookups.walker")
+                sp.set_attr("path", "walker")
+                seen = _walk_resource_candidates(
+                    snap, subj_node, srel_slot, wc_node
+                )
+                cands = _one_block(seen[snap.node_type[seen] == rtid])
+                cost = 1 << 20
+            return spmv._ResultStream(cands, filt, ids_of, cost_bytes=cost)
 
-    return spmv.paginate(
-        dsnap, token, make_stream, page_size, cursor, now_us
-    )
+        stream, skip = spmv.open_stream(dsnap, token, make_stream, cursor)
+    return spmv.paginate(dsnap, token, stream, skip, page_size, now_us)
 
 
 def lookup_subjects_page(
@@ -669,44 +677,46 @@ def lookup_subjects_page(
     the forward-frontier mirror of ``lookup_resources_page``."""
     from . import spmv
 
-    names = (resource_type, resource_id, permission, subject_type,
-             subject_relation)
-    now_us = spmv.resolve_now_us(cursor, now_us)
-    token = spmv.query_token("subj", dsnap.revision, now_us, *names)
-    resolved = _resolve_subjects(dsnap, *names)
-    if resolved is None:
-        return [], None
-    res_node, perm_slot, srel_slot, stid, wc_node = resolved
-    filt, ids_of = _subj_filter(
-        engine, dsnap, resolved, names, now_us, oracle_factory
-    )
-    snap = dsnap.snapshot
+    sp = _trace.current()
+    with _trace.stage("lookup.resolve", sp):
+        names = (resource_type, resource_id, permission, subject_type,
+                 subject_relation)
+        now_us = spmv.resolve_now_us(cursor, now_us)
+        token = spmv.query_token("subj", dsnap.revision, now_us, *names)
+        resolved = _resolve_subjects(dsnap, *names)
+        if resolved is None:
+            return [], None
+        res_node, perm_slot, srel_slot, stid, wc_node = resolved
+        filt, ids_of = _subj_filter(
+            engine, dsnap, resolved, names, now_us, oracle_factory
+        )
+        snap = dsnap.snapshot
 
-    def make_stream():
-        if spmv.frontier_ok(engine, dsnap) and dsnap.flat_meta.has_fw:
-            from ..utils import metrics as _m
+        def make_stream():
+            if spmv.frontier_ok(engine, dsnap) and dsnap.flat_meta.has_fw:
+                from ..utils import metrics as _m
 
-            _m.default.inc("lookups.frontier")
-            st = spmv.state_for(engine, dsnap)
-            if st._spmm is not None:
-                _m.default.inc("lookups.fused")
-            cands = st.subject_candidates(
-                res_node, stid, srel_slot, wc_node, now_us
-            )
-            cost = _frontier_stream_bytes(dsnap.flat_meta, snap)
-        else:
-            from ..utils import metrics as _m
+                _m.default.inc("lookups.frontier")
+                st = spmv.state_for(engine, dsnap)
+                if st._spmm is not None:
+                    _m.default.inc("lookups.fused")
+                cands = st.subject_candidates(
+                    res_node, stid, srel_slot, wc_node, now_us
+                )
+                cost = _frontier_stream_bytes(dsnap.flat_meta, snap)
+            else:
+                from ..utils import metrics as _m
 
-            _m.default.inc("lookups.walker")
-            cands = _one_block(_walk_subject_candidates(
-                snap, res_node, stid, srel_slot, wc_node
-            ))
-            cost = 1 << 20
-        return spmv._ResultStream(cands, filt, ids_of, cost_bytes=cost)
+                _m.default.inc("lookups.walker")
+                sp.set_attr("path", "walker")
+                cands = _one_block(_walk_subject_candidates(
+                    snap, res_node, stid, srel_slot, wc_node
+                ))
+                cost = 1 << 20
+            return spmv._ResultStream(cands, filt, ids_of, cost_bytes=cost)
 
-    return spmv.paginate(
-        dsnap, token, make_stream, page_size, cursor, now_us
-    )
+        stream, skip = spmv.open_stream(dsnap, token, make_stream, cursor)
+    return spmv.paginate(dsnap, token, stream, skip, page_size, now_us)
 
 
 def lookup_resources_device(
@@ -736,7 +746,8 @@ def lookup_resources_device(
         )
         out.extend(ids)
         if cursor is None:
-            return sorted(out)
+            with _trace.stage("lookup.sort", _trace.current()):
+                return sorted(out)
 
 
 def lookup_subjects_device(
@@ -765,7 +776,8 @@ def lookup_subjects_device(
         )
         out.extend(ids)
         if cursor is None:
-            return sorted(out)
+            with _trace.stage("lookup.sort", _trace.current()):
+                return sorted(out)
 
 
 # ---------------------------------------------------------------------------

@@ -58,7 +58,9 @@ that contract.
 
 Counters: ``spmm.dispatches`` (fused program launches — a ≥2-hop
 lookup answers with exactly ONE), ``spmm.fallbacks`` (overflows to the
-looped path), and the ``spmm.dispatch`` fault site (utils/faults.py)
+looped path), ``spmm.rounds`` (+ the fixpoint rounds a fused dispatch
+ran: its loop counter, fetched with the answer), and the
+``spmm.dispatch`` fault site (utils/faults.py)
 fire under the client's retry envelope exactly like ``lookup.dispatch``.
 Fused programs register with the PR-12 cost ledger (utils/perf.py,
 kind ``spmm``) so ``/perf`` and the roofline columns attribute their
@@ -72,6 +74,7 @@ from typing import List, Optional, Tuple
 import numpy as np
 
 from ..utils import faults, metrics
+from ..utils import trace as _trace
 from .hash import _ceil_pow2
 
 _mt = metrics.default
@@ -351,13 +354,13 @@ class SpmmKernels:
                     ovf | o1 | o2 | o3, r + 1,
                 )
 
-            kf, nf, bm_k, bm_n, cand, ncand, ovf, _r = lax.while_loop(
+            kf, nf, bm_k, bm_n, cand, ncand, ovf, rounds = lax.while_loop(
                 cond, body,
                 (kf0, nf0, bm_k, bm_n, jnp.zeros(C, jnp.int32),
                  jnp.int32(0), jnp.bool_(False), jnp.int32(0)),
             )
             converged = ~(jnp.any(kf >= 0) | jnp.any(nf >= 0))
-            return cand, ncand, ovf | ~converged
+            return cand, ncand, ovf | ~converged, rounds
 
         return fn
 
@@ -499,7 +502,7 @@ class SpmmKernels:
                 )
 
             (nf, pf, _bn, _bp, _bc, cand, ncand, gsr, ngsr, wc, ovf,
-             _r) = lax.while_loop(
+             rounds) = lax.while_loop(
                 cond, body,
                 (
                     nf0, pf0, bm_n,
@@ -511,7 +514,7 @@ class SpmmKernels:
                 ),
             )
             converged = ~(jnp.any(nf >= 0) | jnp.any(pf >= 0))
-            return cand, ncand, gsr, ngsr, wc, ovf | ~converged
+            return cand, ncand, gsr, ngsr, wc, ovf | ~converged, rounds
 
         return fn
 
@@ -629,6 +632,9 @@ class FusedLookup:
 
     # -- dispatch plumbing ----------------------------------------------
     def _dispatch(self, direction: str, fn, args):
+        """One fused launch: the program's outputs on the host, its
+        round counter taken off (counter ``spmm.rounds``, the root
+        span's ``rounds``)."""
         import jax
 
         # a fused launch IS a lookup dispatch: both sites fire, so
@@ -636,8 +642,16 @@ class FusedLookup:
         faults.fire("lookup.dispatch")
         faults.fire("spmm.dispatch")
         _mt.inc("spmm.dispatches")
+        _trace.count_if_active("dispatches")
         self._register_cost(direction, fn, args)
-        return jax.device_get(fn(*args))
+        sp = _trace.current()
+        with _trace.stage("lookup.fused.enqueue", sp):
+            out = fn(*args)
+        with _trace.stage("lookup.fused.fetch", sp):
+            *out, rounds = jax.device_get(out)
+        _mt.inc("spmm.rounds", int(rounds))
+        _trace.count_if_active("rounds", int(rounds))
+        return out
 
     def _register_cost(self, direction: str, fn, args) -> None:
         # per-SpmmKernels (= per-meta) guard, same as the spmv hop path
@@ -667,41 +681,42 @@ class FusedLookup:
 
         st = self.st
         N, S1 = st.N, st.S1
-        seeds: List[int] = []
-        if 0 <= subj_node < N:
-            if srel_slot < 0:
-                seeds.append(subj_node * S1)
-            elif st.k2d[srel_slot] >= 0:
-                seeds.append(subj_node * S1 + int(st.k2d[srel_slot]) + 1)
-        if 0 <= wc_node < N:
-            seeds.append(wc_node * S1)
-        sk = np.full(_SEED_KEYS, -1, np.int32)
-        uniq = sorted(set(seeds))[:_SEED_KEYS]
-        sk[: len(uniq)] = uniq
-        sn = np.full(_SEED_NODES, -1, np.int32)
-        if 0 <= subj_node < N:
-            sn[0] = subj_node
-        blocks: List[np.ndarray] = []
-        nt_shape = st.snap.node_type.shape[0]
-        if 0 <= subj_node < nt_shape and (
-            int(st.snap.node_type[subj_node]) == rtid
-        ):
-            blocks.append(np.asarray([subj_node], np.int64))
-        cand, ncand, ovf = self._dispatch(
-            "res", self.kern._res_fn,
-            (
+        sp = _trace.current()
+        with _trace.stage("lookup.args", sp):
+            seeds: List[int] = []
+            if 0 <= subj_node < N:
+                if srel_slot < 0:
+                    seeds.append(subj_node * S1)
+                elif st.k2d[srel_slot] >= 0:
+                    seeds.append(subj_node * S1 + int(st.k2d[srel_slot]) + 1)
+            if 0 <= wc_node < N:
+                seeds.append(wc_node * S1)
+            sk = np.full(_SEED_KEYS, -1, np.int32)
+            uniq = sorted(set(seeds))[:_SEED_KEYS]
+            sk[: len(uniq)] = uniq
+            sn = np.full(_SEED_NODES, -1, np.int32)
+            if 0 <= subj_node < N:
+                sn[0] = subj_node
+            args = (
                 *st.rv_args, *st.ra_args,
                 self.nt_d, self.k2p1_d, self.chain_ok_d, self.child_ok_d,
                 self.perm_tab_d,
                 jnp.asarray(sk), jnp.asarray(sn),
                 jnp.int32(rtid), st._now(now_us),
-            ),
-        )
+            )
+        cand, ncand, ovf = self._dispatch("res", self.kern._res_fn, args)
         if bool(ovf):
             return None
-        arr = np.asarray(cand[: int(ncand)], np.int64)
-        if arr.size:
-            blocks.append(arr)
+        with _trace.stage("lookup.expand", sp):
+            blocks: List[np.ndarray] = []
+            nt_shape = st.snap.node_type.shape[0]
+            if 0 <= subj_node < nt_shape and (
+                int(st.snap.node_type[subj_node]) == rtid
+            ):
+                blocks.append(np.asarray([subj_node], np.int64))
+            arr = np.asarray(cand[: int(ncand)], np.int64)
+            if arr.size:
+                blocks.append(arr)
         return blocks
 
     # -- LookupSubjects: the whole forward fixpoint, one dispatch --------
@@ -715,13 +730,13 @@ class FusedLookup:
 
         st = self.st
         N = st.N
-        sn = np.full(_SEED_NODES, -1, np.int32)
-        if 0 <= res_node < N:
-            sn[0] = res_node
-        arg_p = tuple(st.arg_args) if st.arg_aligned else st.arg_args
-        cand, ncand, gsr, ngsr, wc, ovf = self._dispatch(
-            "subj", self.kern._subj_fn,
-            (
+        sp = _trace.current()
+        with _trace.stage("lookup.args", sp):
+            sn = np.full(_SEED_NODES, -1, np.int32)
+            if 0 <= res_node < N:
+                sn[0] = res_node
+            arg_p = tuple(st.arg_args) if st.arg_aligned else st.arg_args
+            args = (
                 *st.fw_args, arg_p, st.arx,
                 self.nt_d, self.slot_e_d, self.e_k1d_d,
                 self.slot_ts_d, self.ts_k1d_d,
@@ -729,44 +744,48 @@ class FusedLookup:
                 jnp.asarray(sn),
                 jnp.int32(stid), jnp.int32(srel_slot),
                 jnp.int32(wc_node), st._now(now_us),
-            ),
+            )
+        cand, ncand, gsr, ngsr, wc, ovf = self._dispatch(
+            "subj", self.kern._subj_fn, args
         )
         if bool(ovf):
             return None
-        blocks: List[np.ndarray] = []
-        emitted: set = set()
-        arr = np.asarray(cand[: int(ncand)], np.int64)
-        if arr.size:
-            blocks.append(arr)
-            emitted.update(int(x) for x in arr)
-        # trailing blocks, mirroring the walker/looped tail order
-        nt = st.snap.node_type
-        if srel_slot >= 0 and int(ngsr):
-            gs = np.unique(np.asarray(gsr[: int(ngsr)], np.int64))
-            gs = gs[(gs >= 0) & (gs < nt.shape[0])]
-            gs = gs[nt[gs] == stid]
-            gs = np.asarray(
-                [g for g in gs if int(g) not in emitted], np.int64
-            )
-            if gs.size:
-                blocks.append(gs)
-                emitted.update(int(x) for x in gs)
-        if (
-            0 <= res_node < nt.shape[0]
-            and int(nt[res_node]) == stid
-            and res_node not in emitted
-        ):
-            blocks.append(np.asarray([res_node], np.int64))
-            emitted.add(res_node)
-        if bool(wc) and srel_slot < 0:
-            subs = st.all_subjects()
-            subs = subs[(subs >= 0) & (subs < nt.shape[0])]
-            subs = subs[nt[subs] == stid]
-            subs = np.asarray(
-                [s for s in subs if int(s) not in emitted], np.int64
-            )
-            if subs.size:
-                blocks.append(subs)
+        # the program's candidates, then the trailing blocks mirroring the
+        # walker/looped tail order
+        with _trace.stage("lookup.expand", sp):
+            blocks: List[np.ndarray] = []
+            emitted: set = set()
+            arr = np.asarray(cand[: int(ncand)], np.int64)
+            if arr.size:
+                blocks.append(arr)
+                emitted.update(int(x) for x in arr)
+            nt = st.snap.node_type
+            if srel_slot >= 0 and int(ngsr):
+                gs = np.unique(np.asarray(gsr[: int(ngsr)], np.int64))
+                gs = gs[(gs >= 0) & (gs < nt.shape[0])]
+                gs = gs[nt[gs] == stid]
+                gs = np.asarray(
+                    [g for g in gs if int(g) not in emitted], np.int64
+                )
+                if gs.size:
+                    blocks.append(gs)
+                    emitted.update(int(x) for x in gs)
+            if (
+                0 <= res_node < nt.shape[0]
+                and int(nt[res_node]) == stid
+                and res_node not in emitted
+            ):
+                blocks.append(np.asarray([res_node], np.int64))
+                emitted.add(res_node)
+            if bool(wc) and srel_slot < 0:
+                subs = st.all_subjects()
+                subs = subs[(subs >= 0) & (subs < nt.shape[0])]
+                subs = subs[nt[subs] == stid]
+                subs = np.asarray(
+                    [s for s in subs if int(s) not in emitted], np.int64
+                )
+                if subs.size:
+                    blocks.append(subs)
         return blocks
 
 

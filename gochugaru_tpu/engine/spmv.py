@@ -50,6 +50,7 @@ from typing import Callable, Dict, Iterator, List, Optional, Tuple
 import numpy as np
 
 from ..utils import faults, metrics
+from ..utils import trace as _trace
 from .hash import _ceil_pow2, mix32, take_in_bounds
 from .rev import REV_TABLES, row_lanes
 
@@ -479,16 +480,20 @@ class FrontierKernels:
         """(lo, ln, total) device handles + host total for padded keys."""
         faults.fire("lookup.dispatch")
         _mt.inc("lookup.dispatches")
-        kp = self.pad_keys(keys)
+        _trace.count_if_active("dispatches")
+        sp = _trace.current()
         import jax.numpy as jnp
 
-        if kind == "arg" and self._arg_aligned:
-            self._register_cost(kind, self._runs[kind], (tuple(args), kp))
-            lo, ln = self._runs[kind](tuple(args), jnp.asarray(kp))
-        else:
+        with _trace.stage("lookup.args", sp):
+            kp = self.pad_keys(keys)
+            if kind == "arg" and self._arg_aligned:
+                args = (tuple(args),)
             self._register_cost(kind, self._runs[kind], (*args, kp))
-            lo, ln = self._runs[kind](*args, jnp.asarray(kp))
-        total = int(np.asarray(ln).sum())
+            kp = jnp.asarray(kp)
+        with _trace.stage("lookup.hop.enqueue", sp):
+            lo, ln = self._runs[kind](*args, kp)
+        with _trace.stage("lookup.hop.fetch", sp):
+            total = int(np.asarray(ln).sum())
         return lo, ln, total
 
     def _register_cost(
@@ -520,10 +525,14 @@ class FrontierKernels:
         import jax.numpy as jnp
 
         _mt.inc("lookup.dispatches")
-        rows, live = self._emits[kind](
-            tbl, lo, ln, jnp.int32(chunk0), now, ch or self.CH
-        )
-        rows, live = jax.device_get((rows, live))
+        _trace.count_if_active("dispatches")
+        sp = _trace.current()
+        with _trace.stage("lookup.args", sp):
+            chunk0 = jnp.int32(chunk0)
+        with _trace.stage("lookup.hop.enqueue", sp):
+            out = self._emits[kind](tbl, lo, ln, chunk0, now, ch or self.CH)
+        with _trace.stage("lookup.hop.fetch", sp):
+            rows, live = jax.device_get(out)
         return rows, live
 
     def _tier(self, n: int) -> int:
@@ -541,22 +550,31 @@ class FrontierKernels:
             return
         fused = self._hops_fused.get(kind)
         _mt.inc("lookup.hops")
+        _trace.count_if_active("hops")
+        sp = _trace.current()
         if fused is not None:
             faults.fire("lookup.dispatch")
             _mt.inc("lookup.dispatches")
-            kp = self.pad_keys(keys)
-            self._register_cost(
-                f"hop:{kind}", fused,
-                (args[0], args[1], args[2], tbl, kp,
-                 now if hasattr(now, "dtype") else jnp.int32(now)),
-                F=int(kp.shape[0]),
-            )
-            lo, ln, rows, live = fused(
-                args[0], args[1], args[2], tbl, jnp.asarray(kp), now
-            )
-            ln_h, rows, live = jax.device_get((ln, rows, live))
-            total = int(ln_h.sum())
-            yield rows[live]
+            _trace.count_if_active("dispatches")
+            with _trace.stage("lookup.args", sp):
+                kp = self.pad_keys(keys)
+                self._register_cost(
+                    f"hop:{kind}", fused,
+                    (args[0], args[1], args[2], tbl, kp,
+                     now if hasattr(now, "dtype") else jnp.int32(now)),
+                    F=int(kp.shape[0]),
+                )
+                kp = jnp.asarray(kp)
+            with _trace.stage("lookup.hop.enqueue", sp):
+                lo, ln, rows, live = fused(
+                    args[0], args[1], args[2], tbl, kp, now
+                )
+            with _trace.stage("lookup.hop.fetch", sp):
+                ln_h, rows, live = jax.device_get((ln, rows, live))
+            with _trace.stage("lookup.expand", sp):
+                total = int(ln_h.sum())
+                rows = rows[live]
+            yield rows
             at = self.CH0
         else:
             lo, ln, total = self.runs(kind, args, keys)
@@ -564,7 +582,9 @@ class FrontierKernels:
         while at < total:
             ch = self._tier(total - at)
             rows, live = self.emit(kind, tbl, lo, ln, at, now, ch)
-            yield rows[live]
+            with _trace.stage("lookup.expand", sp):
+                rows = rows[live]
+            yield rows
             at += ch
 
 
@@ -770,12 +790,16 @@ class FrontierState:
             return self._hops.expand("arg", keys, now)
         lo, ln, total = self.kern.runs("arg", self.arg_args, keys)
         _mt.inc("lookup.hops")
+        _trace.count_if_active("hops")
+        sp = _trace.current()
 
         def gen():
             at = 0
             while at < total:
                 rows, live = self.kern.emit("arg", self.arx, lo, ln, at, now)
-                yield rows[live]
+                with _trace.stage("lookup.expand", sp):
+                    rows = rows[live]
+                yield rows
                 at += self.kern.CH
 
         return gen()
@@ -807,104 +831,119 @@ class FrontierState:
         runs in ONE device dispatch; overflow (frontier/emission/
         candidate capacity, round budget) falls back to the looped
         per-hop body below, which is also the streaming path big
-        answers want."""
+        answers want.
+
+        Stages (utils/trace.py): the host work between dispatches is
+        ``lookup.expand``, never open across a ``yield`` or a hop's own
+        dispatch stages."""
+        sp = _trace.current()
         if self._spmm is not None:
             blocks = self._spmm.resources(
                 rtid, subj_node, srel_slot, wc_node, now_us
             )
             if blocks is not None:
+                sp.set_attr("path", "fused")
+                sp.set_attr("fallback", False)
                 for b in blocks:
                     if b.size:
                         _mt.inc("lookup.candidates", b.size)
                         yield b
                 return
             _mt.inc("spmm.fallbacks")
+            sp.set_attr("fallback", True)
+        sp.set_attr("path", "looped")
         N, S1, logN = self.N, self.S1, self.logN
-        now = self._now(now_us)
-        seen_keys = _Seen(N * S1)
-        seen_nodes = _Seen(N)
-        nt_shape = self.snap.node_type.shape[0]
-
-        seeds: List[np.ndarray] = []
-        if 0 <= subj_node < N:
-            if srel_slot < 0:
-                seeds.append(np.asarray([subj_node * S1], np.int64))
-            elif self.k2d[srel_slot] >= 0:
-                seeds.append(np.asarray(
-                    [subj_node * S1 + int(self.k2d[srel_slot]) + 1], np.int64
-                ))
-        if 0 <= wc_node < N:
-            seeds.append(np.asarray([wc_node * S1], np.int64))
-        # self-identity: the subject node itself may be the resource
-        first_nodes = (
-            np.asarray([subj_node], np.int64)
-            if 0 <= subj_node < nt_shape else np.empty(0, np.int64)
-        )
-        first_nodes = seen_nodes.fresh(first_nodes)
-        if first_nodes.size:
+        with _trace.stage("lookup.args", sp):
+            now = self._now(now_us)
+        with _trace.stage("lookup.expand", sp):
+            seen_keys = _Seen(N * S1)
+            seen_nodes = _Seen(N)
+            nt_shape = self.snap.node_type.shape[0]
+            seeds: List[np.ndarray] = []
+            if 0 <= subj_node < N:
+                if srel_slot < 0:
+                    seeds.append(np.asarray([subj_node * S1], np.int64))
+                elif self.k2d[srel_slot] >= 0:
+                    seeds.append(np.asarray(
+                        [subj_node * S1 + int(self.k2d[srel_slot]) + 1],
+                        np.int64,
+                    ))
+            if 0 <= wc_node < N:
+                seeds.append(np.asarray([wc_node * S1], np.int64))
+            # self-identity: the subject node itself may be the resource
+            first_nodes = seen_nodes.fresh(
+                np.asarray([subj_node], np.int64)
+                if 0 <= subj_node < nt_shape else np.empty(0, np.int64)
+            )
             cand = first_nodes[self.node_type_of(first_nodes) == rtid]
-            if cand.size:
-                _mt.inc("lookup.candidates", cand.size)
-                yield cand
-        frontier = seen_keys.fresh(
-            np.concatenate(seeds) if seeds else np.empty(0, np.int64)
-        )
+            frontier = seen_keys.fresh(
+                np.concatenate(seeds) if seeds else np.empty(0, np.int64)
+            )
+        if cand.size:
+            _mt.inc("lookup.candidates", cand.size)
+            yield cand
         while frontier.size:
             new_keys: List[np.ndarray] = []
             node_parts: List[np.ndarray] = []
             for rows in self.expand_rv(frontier.astype(np.int32), now):
                 if rows.shape[0] == 0:
                     continue
-                k1 = rows[:, 1].astype(np.int64)
-                res = k1 & (N - 1)
-                slotd = k1 >> logN
-                node_parts.append(res)
-                # granted usersets continue the membership chain — only
-                # where the schema declares (type(res), rel) a legal
-                # subject form (type-safety pruning: everything else is
-                # structurally dead and never probes)
-                nk = self.k2p1_of_k1d[slotd]
-                chain = (nk > 0) & self.chain_ok[
-                    self.node_type_of(res), np.maximum(nk, 0)
-                ]
-                if chain.any():
-                    new_keys.append(res[chain] * S1 + nk[chain])
-            nodes = seen_nodes.fresh(
-                np.concatenate(node_parts)
-                if node_parts else np.empty(0, np.int64)
-            )
+                with _trace.stage("lookup.expand", sp):
+                    k1 = rows[:, 1].astype(np.int64)
+                    res = k1 & (N - 1)
+                    slotd = k1 >> logN
+                    node_parts.append(res)
+                    # granted usersets continue the membership chain —
+                    # only where the schema declares (type(res), rel) a
+                    # legal subject form (type-safety pruning: everything
+                    # else is structurally dead and never probes)
+                    nk = self.k2p1_of_k1d[slotd]
+                    chain = (nk > 0) & self.chain_ok[
+                        self.node_type_of(res), np.maximum(nk, 0)
+                    ]
+                    if chain.any():
+                        new_keys.append(res[chain] * S1 + nk[chain])
+            with _trace.stage("lookup.expand", sp):
+                nodes = seen_nodes.fresh(
+                    np.concatenate(node_parts)
+                    if node_parts else np.empty(0, np.int64)
+                )
             # close candidates under reverse arrows (parents granting
             # through tupleset traversal) — device hops over rax
             while nodes.size:
-                cand = nodes[self.node_type_of(nodes) == rtid]
+                with _trace.stage("lookup.expand", sp):
+                    cand = nodes[self.node_type_of(nodes) == rtid]
                 if cand.size:
                     _mt.inc("lookup.candidates", cand.size)
                     yield cand
-                if self.perm_chains:
-                    tids = self.node_type_of(nodes)
-                    for t in np.unique(tids):
-                        k2p1 = self.perm_k2p1_of_tid.get(int(t))
-                        if k2p1 is None:
-                            continue
-                        nn = nodes[tids == t]
-                        new_keys.append(
-                            (nn[:, None] * S1 + k2p1[None, :]).ravel()
-                        )
-                # only declared arrow-child types can have parents
-                ch = nodes[self.child_ok[self.node_type_of(nodes)]]
+                with _trace.stage("lookup.expand", sp):
+                    if self.perm_chains:
+                        tids = self.node_type_of(nodes)
+                        for t in np.unique(tids):
+                            k2p1 = self.perm_k2p1_of_tid.get(int(t))
+                            if k2p1 is None:
+                                continue
+                            nn = nodes[tids == t]
+                            new_keys.append(
+                                (nn[:, None] * S1 + k2p1[None, :]).ravel()
+                            )
+                    # only declared arrow-child types can have parents
+                    ch = nodes[self.child_ok[self.node_type_of(nodes)]]
                 parent_parts = [
                     rows[:, 1].astype(np.int64) & (N - 1)
                     for rows in self.expand_ra(ch.astype(np.int32), now)
                     if rows.shape[0]
                 ]
-                nodes = seen_nodes.fresh(
-                    np.concatenate(parent_parts)
-                    if parent_parts else np.empty(0, np.int64)
+                with _trace.stage("lookup.expand", sp):
+                    nodes = seen_nodes.fresh(
+                        np.concatenate(parent_parts)
+                        if parent_parts else np.empty(0, np.int64)
+                    )
+            with _trace.stage("lookup.expand", sp):
+                frontier = seen_keys.fresh(
+                    np.concatenate(new_keys)
+                    if new_keys else np.empty(0, np.int64)
                 )
-            frontier = seen_keys.fresh(
-                np.concatenate(new_keys)
-                if new_keys else np.empty(0, np.int64)
-            )
 
     # -- LookupSubjects candidate stream ---------------------------------
     def subject_candidates(
@@ -913,40 +952,52 @@ class FrontierState:
     ) -> Iterator[np.ndarray]:
         """Forward frontier expansion from the resource over the fw/argx
         views — the walker's node/pair worklist as device hops (or ONE
-        fused SpMM dispatch, overflow falling back here)."""
+        fused SpMM dispatch, overflow falling back here).  Stages as in
+        ``resource_candidates``."""
+        sp = _trace.current()
         if self._spmm is not None:
             blocks = self._spmm.subjects(
                 res_node, stid, srel_slot, wc_node, now_us
             )
             if blocks is not None:
+                sp.set_attr("path", "fused")
+                sp.set_attr("fallback", False)
                 for b in blocks:
                     if b.size:
                         _mt.inc("lookup.candidates", b.size)
                         yield b
                 return
             _mt.inc("spmm.fallbacks")
+            sp.set_attr("fallback", True)
+        sp.set_attr("path", "looped")
         N, S1, logN = self.N, self.S1, self.logN
         snap = self.snap
         num_slots = max(snap.num_slots, 1)
-        now = self._now(now_us)
-        seen_nodes = _Seen(N)
-        seen_pairs = _Seen(N * (num_slots + 1))
-        seen_cand = _Seen(N)
-        pair_list: List[np.ndarray] = []  # raw (g·NS + r) pairs, for srel
-        wildcard_found = [False]
-        # dense k2 value+1 → raw slot (decoding emitted userset subjects)
-        k2p1_raw = np.full(S1 + 1, -1, np.int64)
-        for raw, d in enumerate(self.k2d):
-            if d >= 0:
-                k2p1_raw[d + 1] = raw
-        e_slot_raw = np.asarray(
-            [s for s in self.meta.e_slots if self.k1d[s] >= 0], np.int64
-        )
-        e_slot_k1d = self.k1d[e_slot_raw].astype(np.int64)
-        ts_raw = np.asarray(
-            [s for s in self.ts_slots if self.k1d[s] >= 0], np.int64
-        )
-        ts_k1d = self.k1d[ts_raw].astype(np.int64)
+        with _trace.stage("lookup.args", sp):
+            now = self._now(now_us)
+        with _trace.stage("lookup.expand", sp):
+            seen_nodes = _Seen(N)
+            seen_pairs = _Seen(N * (num_slots + 1))
+            seen_cand = _Seen(N)
+            pair_list: List[np.ndarray] = []  # raw (g·NS + r) pairs, for srel
+            wildcard_found = [False]
+            # dense k2 value+1 → raw slot (decoding emitted userset subjects)
+            k2p1_raw = np.full(S1 + 1, -1, np.int64)
+            for raw, d in enumerate(self.k2d):
+                if d >= 0:
+                    k2p1_raw[d + 1] = raw
+            e_slot_raw = np.asarray(
+                [s for s in self.meta.e_slots if self.k1d[s] >= 0], np.int64
+            )
+            e_slot_k1d = self.k1d[e_slot_raw].astype(np.int64)
+            ts_raw = np.asarray(
+                [s for s in self.ts_slots if self.k1d[s] >= 0], np.int64
+            )
+            ts_k1d = self.k1d[ts_raw].astype(np.int64)
+            node_frontier = seen_nodes.fresh(
+                np.asarray([res_node], np.int64)
+                if 0 <= res_node < N else np.empty(0, np.int64)
+            )
 
         def absorb(k2vals: np.ndarray):
             """Emitted subject keys → (direct candidate block or None,
@@ -979,10 +1030,20 @@ class FrontierState:
             kk = nodes[:, None] + (e_slot_k1d[None, :] * N)
             return kk[ok].ravel()
 
-        node_frontier = seen_nodes.fresh(
-            np.asarray([res_node], np.int64)
-            if 0 <= res_node < N else np.empty(0, np.int64)
-        )
+        def fw_hop(keys: np.ndarray, new_pairs: List[np.ndarray]):
+            """One hop over the fw view: yields each emitted chunk's
+            fresh direct candidates, appends its userset pairs."""
+            for rows in self.expand_fw(keys.astype(np.int32), now):
+                if rows.shape[0] == 0:
+                    continue
+                with _trace.stage("lookup.expand", sp):
+                    cand, pairs = absorb(rows[:, 1].astype(np.int64))
+                    if pairs.size:
+                        new_pairs.append(pairs)
+                if cand is not None:
+                    _mt.inc("lookup.candidates", cand.size)
+                    yield cand
+
         pair_frontier = np.empty(0, np.int64)
         pending_nodes: List[np.ndarray] = []
         while node_frontier.size or pair_frontier.size:
@@ -992,10 +1053,11 @@ class FrontierState:
                 fresh_all: List[np.ndarray] = [node_frontier]
                 cur = node_frontier
                 while cur.size and ts_k1d.size:
-                    tok = self.slot_of_type[
-                        self.node_type_of(cur)[:, None], ts_raw[None, :]
-                    ]
-                    keys = (cur[:, None] + ts_k1d[None, :] * N)[tok].ravel()
+                    with _trace.stage("lookup.expand", sp):
+                        tok = self.slot_of_type[
+                            self.node_type_of(cur)[:, None], ts_raw[None, :]
+                        ]
+                        keys = (cur[:, None] + ts_k1d[None, :] * N)[tok].ravel()
                     child_parts = [
                         rows[:, 0].astype(np.int64)
                         for rows in self.expand_arrows_fwd(
@@ -1003,66 +1065,53 @@ class FrontierState:
                         )
                         if rows.shape[0]
                     ]
-                    cur = seen_nodes.fresh(
-                        np.concatenate(child_parts)
-                        if child_parts else np.empty(0, np.int64)
-                    )
+                    with _trace.stage("lookup.expand", sp):
+                        cur = seen_nodes.fresh(
+                            np.concatenate(child_parts)
+                            if child_parts else np.empty(0, np.int64)
+                        )
                     if cur.size:
                         fresh_all.append(cur)
-                nodes = np.concatenate(fresh_all)
-                for rows in self.expand_fw(
-                    fw_keys_of_nodes(nodes).astype(np.int32), now
-                ):
-                    if rows.shape[0] == 0:
-                        continue
-                    cand, pairs = absorb(rows[:, 1].astype(np.int64))
-                    if cand is not None:
-                        _mt.inc("lookup.candidates", cand.size)
-                        yield cand
-                    if pairs.size:
-                        new_pairs.append(pairs)
+                with _trace.stage("lookup.expand", sp):
+                    keys = fw_keys_of_nodes(np.concatenate(fresh_all))
+                yield from fw_hop(keys, new_pairs)
             if pair_frontier.size:
-                g = pair_frontier // (num_slots + 1)
-                r = pair_frontier % (num_slots + 1)
-                tids = self.node_type_of(g)
-                ok_t = (tids >= 0) & (r < num_slots)
-                is_perm = np.zeros(g.shape[0], bool)
-                if self.perm_raw_table is not None:
-                    is_perm[ok_t] = self.perm_raw_table[
-                        tids[ok_t], r[ok_t]
-                    ]
-                # permission pairs: holders of g#p ⊆ expansion of g
-                pending_nodes.append(g[is_perm])
-                rel_g, rel_r = g[~is_perm], r[~is_perm]
-                kd = self.k1d[np.clip(rel_r, 0, self.k1d.shape[0] - 1)]
-                okk = (kd >= 0) & (rel_r < self.k1d.shape[0])
-                keys = kd[okk] * N + rel_g[okk]
-                for rows in self.expand_fw(keys.astype(np.int32), now):
-                    if rows.shape[0] == 0:
-                        continue
-                    cand, pairs = absorb(rows[:, 1].astype(np.int64))
-                    if cand is not None:
-                        _mt.inc("lookup.candidates", cand.size)
-                        yield cand
-                    if pairs.size:
-                        new_pairs.append(pairs)
-            pair_frontier = seen_pairs.fresh(
-                np.concatenate(new_pairs)
-                if new_pairs else np.empty(0, np.int64)
-            )
-            if pair_frontier.size:
-                pair_list.append(pair_frontier)
-            node_frontier = seen_nodes.fresh(
-                np.concatenate(pending_nodes)
-                if pending_nodes else np.empty(0, np.int64)
-            )
-            pending_nodes = []
+                with _trace.stage("lookup.expand", sp):
+                    g = pair_frontier // (num_slots + 1)
+                    r = pair_frontier % (num_slots + 1)
+                    tids = self.node_type_of(g)
+                    ok_t = (tids >= 0) & (r < num_slots)
+                    is_perm = np.zeros(g.shape[0], bool)
+                    if self.perm_raw_table is not None:
+                        is_perm[ok_t] = self.perm_raw_table[
+                            tids[ok_t], r[ok_t]
+                        ]
+                    # permission pairs: holders of g#p ⊆ expansion of g
+                    pending_nodes.append(g[is_perm])
+                    rel_g, rel_r = g[~is_perm], r[~is_perm]
+                    kd = self.k1d[np.clip(rel_r, 0, self.k1d.shape[0] - 1)]
+                    okk = (kd >= 0) & (rel_r < self.k1d.shape[0])
+                    keys = kd[okk] * N + rel_g[okk]
+                yield from fw_hop(keys, new_pairs)
+            with _trace.stage("lookup.expand", sp):
+                pair_frontier = seen_pairs.fresh(
+                    np.concatenate(new_pairs)
+                    if new_pairs else np.empty(0, np.int64)
+                )
+                if pair_frontier.size:
+                    pair_list.append(pair_frontier)
+                node_frontier = seen_nodes.fresh(
+                    np.concatenate(pending_nodes)
+                    if pending_nodes else np.empty(0, np.int64)
+                )
+                pending_nodes = []
 
         # trailing blocks, same order as the walker's tail
         if srel_slot >= 0 and pair_list:
-            allp = np.concatenate(pair_list)
-            gs = allp[allp % (num_slots + 1) == srel_slot] // (num_slots + 1)
-            cand = seen_cand.fresh(gs[self.node_type_of(gs) == stid])
+            with _trace.stage("lookup.expand", sp):
+                allp = np.concatenate(pair_list)
+                gs = allp[allp % (num_slots + 1) == srel_slot] // (num_slots + 1)
+                cand = seen_cand.fresh(gs[self.node_type_of(gs) == stid])
             if cand.size:
                 _mt.inc("lookup.candidates", cand.size)
                 yield cand
@@ -1073,8 +1122,9 @@ class FrontierState:
             if cand.size:
                 yield cand
         if wildcard_found[0] and srel_slot < 0:
-            subs = self.all_subjects()
-            cand = seen_cand.fresh(subs[self.node_type_of(subs) == stid])
+            with _trace.stage("lookup.expand", sp):
+                subs = self.all_subjects()
+                cand = seen_cand.fresh(subs[self.node_type_of(subs) == stid])
             if cand.size:
                 _mt.inc("lookup.candidates", cand.size)
                 yield cand
@@ -1116,6 +1166,7 @@ class _ResultStream:
         self.cost_bytes = int(cost_bytes)
 
     def take(self, n: int) -> List[str]:
+        sp = _trace.current()
         out: List[str] = []
         while len(out) < n:
             if self._pending:
@@ -1123,16 +1174,21 @@ class _ResultStream:
                 out.extend(self._pending[:k])
                 del self._pending[:k]
                 continue
+            # the candidate generator and the filter run their own
+            # stages: none is open across either call
             block = next(self._cands, None)
             if block is None:
                 self.exhausted = True
                 break
             if block.size == 0:
                 continue
+            _trace.count_if_active("blocks")
+            _trace.count_if_active("candidates", int(block.size))
             granted = self._filter(block)
             if granted.size == 0:
                 continue
-            self._pending.extend(self._ids_of(granted))
+            with _trace.stage("lookup.decode", sp):
+                self._pending.extend(self._ids_of(granted))
             _mt.inc("lookup.id_blocks")
             _mt.inc("lookup.ids", int(granted.size))
         self.emitted += len(out)
@@ -1153,25 +1209,19 @@ class _ResultStream:
 _STREAM_CACHE_BYTES = 256 << 20
 
 
-def paginate(
+def open_stream(
     dsnap,
     token: str,
     make_stream: Callable[[], _ResultStream],
-    page_size: int,
     cursor: Optional[LookupCursor],
-    now_us: Optional[int] = None,
-) -> Tuple[List[str], Optional[LookupCursor]]:
-    """One page of results with exact resume semantics.  The live stream
-    is cached on the DeviceSnapshot keyed by ``token``; an evicted or
-    cross-process resume deterministically recomputes and skips
-    ``cursor.pos`` results.  ``now_us`` (already resolved via
-    resolve_now_us) rides the returned cursor so the recompute is
-    evaluated at the same instant."""
+) -> Tuple[_ResultStream, int]:
+    """The live stream a page reads, and how many results it must skip
+    first: the one cached on the DeviceSnapshot under ``token`` where it
+    stands at the cursor, else a fresh ``make_stream()`` that skips
+    ``cursor.pos`` (an evicted or cross-process resume recomputes
+    deterministically)."""
     from ..utils.errors import PreconditionFailedError
 
-    cache: Dict[str, _ResultStream] = dsnap.__dict__.setdefault(
-        "_lookup_streams", {}
-    )
     pos = 0
     if cursor is not None:
         if cursor.token != token:
@@ -1184,11 +1234,30 @@ def paginate(
                 f"snapshot is at {dsnap.revision}"
             )
         pos = cursor.pos
-    stream = cache.pop(token, None)
-    if stream is None or stream.emitted != pos:
-        stream = make_stream()
-        _mt.inc("lookup.stream_recomputes" if pos else "lookup.streams")
-        stream.skip(pos)
+    stream = dsnap.__dict__.setdefault("_lookup_streams", {}).pop(token, None)
+    if stream is not None and stream.emitted == pos:
+        return stream, 0
+    _mt.inc("lookup.stream_recomputes" if pos else "lookup.streams")
+    return make_stream(), pos
+
+
+def paginate(
+    dsnap,
+    token: str,
+    stream: _ResultStream,
+    skip: int,
+    page_size: int,
+    now_us: Optional[int] = None,
+) -> Tuple[List[str], Optional[LookupCursor]]:
+    """One page of results with exact resume semantics, from the stream
+    and skip ``open_stream`` gave.  An unfinished stream is cached on the
+    DeviceSnapshot keyed by ``token``.  ``now_us`` (already resolved via
+    resolve_now_us) rides the returned cursor so a recompute is evaluated
+    at the same instant."""
+    cache: Dict[str, _ResultStream] = dsnap.__dict__.setdefault(
+        "_lookup_streams", {}
+    )
+    stream.skip(skip)
     ids = stream.take(page_size)
     done = stream.exhausted and not stream._pending
     nxt = None

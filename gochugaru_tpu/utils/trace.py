@@ -47,7 +47,10 @@ plumbing a parameter through every signature.
 
 Stages (``stage`` / ``observe_stage`` / ``annotation``): one primitive
 at every layer boundary of the check path, per batch and per request,
-never per check or per key.  A stage is the interval between two
+and of the lookup path, per dispatch, candidate block and lookup (the
+``lookup`` root is the thread's current span while the device lookup
+runs: ``activated``; its tallies ride ``count_if_active``) — never per
+check, key or id.  A stage is the interval between two
 ``perf_counter`` stamps and feeds, from those SAME two stamps,
 
 1. the registry timer ``<name>_s`` (always on);
@@ -1061,6 +1064,43 @@ def event_if_active(name: str, **attrs) -> None:
     sp = getattr(_tls, "span", None)
     if sp is not None:
         sp.event(name, **attrs)
+
+
+def count_if_active(key: str, n: int = 1) -> None:
+    """Add ``n`` to the integer attribute ``key`` of the thread's active
+    span, if it is sampled — a request's tallies (a lookup's hops,
+    dispatches, candidates) from sites that see no span.  One load +
+    branch when tracing is disabled."""
+    if _TRACER is None:
+        return
+    sp = getattr(_tls, "span", None)
+    if sp is not None and sp.sampled:
+        attrs = sp.attrs
+        if attrs is None:
+            attrs = sp.attrs = {}
+        attrs[key] = attrs.get(key, 0) + n
+
+
+class activated:
+    """``with activated(span):`` makes ``span`` the thread's current span
+    for the block and does NOT end it on exit — the form for a root that
+    outlives the block (a lookup's, which ends when its caller has taken
+    the last id).  ``NOOP`` is activated too, so an unsampled request's
+    sites never reach an enclosing span."""
+
+    __slots__ = ("span", "_prev")
+
+    def __init__(self, span) -> None:
+        self.span = span
+
+    def __enter__(self):
+        self._prev = getattr(_tls, "span", None)
+        _tls.span = self.span
+        return self.span
+
+    def __exit__(self, *exc) -> bool:
+        _tls.span = self._prev
+        return False
 
 
 # -- stages: the check path's boundaries, on every clock ---------------------
