@@ -48,6 +48,8 @@ from ..caveats.device import (
     encode_contexts,
     make_tri_fn,
 )
+from ..native import lower as _native_lower
+from ..native.interner import NativeInterner
 from ..rel.relationship import Relationship
 from ..schema.compiler import CompiledSchema
 from ..store.snapshot import Snapshot
@@ -1055,8 +1057,14 @@ class DeviceEngine:
         ``engine.lower`` stage (wall, and thread CPU while it records).
         Column operations only: nothing but the pulls of the six fields
         (on a schema with caveats, of the request context and of each
-        parameter it names) runs once per row.  The batch's node ids come
-        from one ``interner.lookup_pairs`` call: ``engine.intern_s`` is observed
+        parameter it names) runs once per row.  On a ``NativeInterner``
+        with ``native/lower.cpp`` loaded the six pulls, the id packing,
+        the type ids and the two slot columns are one native pass
+        (``native.lower.pull``; ``engine.lower_native_batches`` counts the
+        batch, the stage notes ``native=True``); otherwise the Python
+        pass, which gives the same columns.  The batch's node ids come
+        from one locked interner call (``lookup_packed`` after the native
+        pull, else ``lookup_pairs``): ``engine.intern_s`` is observed
         around it, ``intern.lookups`` counts its 2·B keys and
         ``intern.batch_calls`` the call.  On a schema with caveats the
         request-context dedup and encode are ``engine.context_s``, and
@@ -1073,19 +1081,34 @@ class DeviceEngine:
         B = len(rels)
         interner = snap.interner
         slot_of = self.compiled.slot_of_name
+        # an empty subject relation is the direct subject, -1; a non-empty
+        # one with no slot reads -2 until the rows are forced false below
+        srel_of = {**slot_of, "": -1}
         wc_of = snap.wildcard_node_of_type
 
-        res_type = [r.resource_type for r in rels]
-        res_id = [r.resource_id for r in rels]
-        res_rel = [r.resource_relation for r in rels]
-        subj_type = [r.subject_type for r in rels]
-        subj_id = [r.subject_id for r in rels]
-        subj_rel = [r.subject_relation for r in rels]
+        pulled = (_native_lower.pull(rels, interner, slot_of, srel_of)
+                  if isinstance(interner, NativeInterner) else None)
         # every node id of the batch in ONE interner call (2·B keys)
-        t0 = _time.perf_counter()
-        nodes, type_ids = interner.lookup_pairs(
-            res_type + subj_type, res_id + subj_id)
-        intern_s = _time.perf_counter() - t0
+        if pulled is not None:
+            buf, offsets, type_ids, q_perm, q_srel = pulled
+            t0 = _time.perf_counter()
+            nodes = interner.lookup_packed(buf, offsets, type_ids)
+            intern_s = _time.perf_counter() - t0
+        else:
+            res_type = [r.resource_type for r in rels]
+            res_id = [r.resource_id for r in rels]
+            res_rel = [r.resource_relation for r in rels]
+            subj_type = [r.subject_type for r in rels]
+            subj_id = [r.subject_id for r in rels]
+            subj_rel = [r.subject_relation for r in rels]
+            t0 = _time.perf_counter()
+            nodes, type_ids = interner.lookup_pairs(
+                res_type + subj_type, res_id + subj_id)
+            intern_s = _time.perf_counter() - t0
+            q_perm = np.fromiter(
+                map(slot_of.get, res_rel, repeat(-1)), np.int32, B)
+            q_srel = np.fromiter(
+                map(srel_of.get, subj_rel, repeat(-2)), np.int32, B)
         q_res, q_subj = nodes[:B], nodes[B:]
         # the wildcard node of the subject's type, unless the subject is
         # that node: wc_of holds lookup(type, "*"), so q_subj equals it
@@ -1095,12 +1118,6 @@ class DeviceEngine:
         wc = wc_of[np.where(typed, stid, 0)]
         q_wc = np.where(typed & (wc != q_subj), wc, np.int32(-1))
 
-        q_perm = np.fromiter(
-            map(slot_of.get, res_rel, repeat(-1)), np.int32, B)
-        # an empty subject relation is the direct subject, -1; a non-empty
-        # one with no slot reads -2 until the rows are forced false below
-        q_srel = np.fromiter(
-            map({**slot_of, "": -1}.get, subj_rel, repeat(-2)), np.int32, B)
         no_slot = q_srel == -2
         # reflexive userset identity: the same (type, id) on both sides and
         # the same non-empty relation.  A name has one slot and a known
@@ -1111,9 +1128,11 @@ class DeviceEngine:
             ((q_srel >= 0) & (q_srel == q_perm)) | (no_slot & (q_perm < 0)))
         q_self = same & (q_srel >= 0) & (q_subj >= 0)
         for i in np.flatnonzero(same & ~q_self).tolist():
+            r = rels[i]
             q_self[i] = (
-                res_type[i] == subj_type[i] and res_id[i] == subj_id[i]
-                and res_rel[i] == subj_rel[i]
+                r.resource_type == r.subject_type
+                and r.resource_id == r.subject_id
+                and r.resource_relation == r.subject_relation
             )
         # an unknown subject relation can never be granted; -1 would alias
         # "direct subject", so force the query false
@@ -1145,7 +1164,10 @@ class DeviceEngine:
         m.inc("intern.lookups", 2 * B)
         m.inc("intern.batch_calls")
         m.observe("engine.intern_s", intern_s)
-        st.note(batch=B, intern_s=round(intern_s, 6))
+        if pulled is not None:
+            m.inc("engine.lower_native_batches")
+        st.note(batch=B, intern_s=round(intern_s, 6),
+                native=pulled is not None)
         if self.caveat_plan is not None:
             m.observe("engine.context_s", context_s)
             m.inc("engine.context_batches")

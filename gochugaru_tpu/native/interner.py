@@ -234,22 +234,36 @@ class NativeInterner:
         """Node ids of many (type name, id) pairs of mixed types, without
         interning, in ONE native call: ``(nodes, type_ids)``, both
         int32[n], -1 where the id or its type is unknown (see
-        store/interner.py).  The ids are packed outside the lock; it is
-        held for the type table and the call, which releases the GIL."""
+        store/interner.py).  The ids are packed and the type names
+        mapped outside the lock (the type table is append-only and a dict
+        read holds the GIL); ``lookup_packed`` takes it for the call."""
         if len(type_names) != len(ids):
             raise ValueError("lookup_pairs: one type name per id")
         buf, offsets = self._pack(ids)
-        names = set(type_names)
+        types = self._types
+        tid_of = {t: types.get(t, -1) for t in set(type_names)}
+        tids = np.fromiter(
+            map(tid_of.__getitem__, type_names), np.int32, len(ids))
+        return self.lookup_packed(buf, offsets, tids), tids
+
+    def lookup_packed(
+        self, buf: bytes, offsets: np.ndarray, type_ids: np.ndarray
+    ) -> np.ndarray:
+        """Node ids of ids already packed as ``_pack`` packs them, with
+        their interner type ids (−1 for an unknown type): int32[n], −1
+        where the id or its type is unknown.  One ``gi_lookup_batch``
+        call under the interner's lock, so a writer interning at the same
+        time is excluded; the call releases the GIL."""
+        if len(type_ids) != len(offsets) - 1:
+            raise ValueError("lookup_packed: one type id per packed id")
         with self._lock:
-            tid_of = {t: self._types.get(t, -1) for t in names}
-            tids = np.fromiter(
-                map(tid_of.__getitem__, type_names), np.int32, len(ids))
             # an unknown type never reaches the C hash: its keys are
             # looked up as type 0 and masked
             nodes = self._call(
-                self._lib.gi_lookup_batch, np.maximum(tids, 0), buf, offsets)
-        nodes[tids < 0] = -1
-        return nodes, tids
+                self._lib.gi_lookup_batch, np.maximum(type_ids, 0), buf,
+                offsets)
+        nodes[type_ids < 0] = -1
+        return nodes
 
 
 def make_interner():

@@ -1,11 +1,16 @@
 """Host lowering of a check batch (``DeviceEngine._lower_queries``): the
 int32 query columns against a per-key reference, concurrent callers
-against one, on both interners, and the subject-row table of the
+against one, on both interners and through both passes over the
+``Relationship`` objects (the native pull of ``native/lower.cpp`` and the
+Python pass it falls back to), and the subject-row table of the
 two-phase programs (``subject_rows``), which the lowering no longer
 builds."""
 
+import ctypes
+import dataclasses
 import sys
 import threading
+from types import SimpleNamespace
 
 import numpy as np
 import pytest
@@ -13,7 +18,11 @@ import pytest
 from gochugaru_tpu import native, rel
 from gochugaru_tpu.engine.device import DeviceEngine, subject_rows
 from gochugaru_tpu.engine.plan import EngineConfig
-from gochugaru_tpu.rel.relationship import WILDCARD_ID
+from gochugaru_tpu.rel.relationship import (
+    WILDCARD_ID,
+    Relationship,
+    decoded_relationship,
+)
 from gochugaru_tpu.schema import compile_schema, parse_schema
 from gochugaru_tpu.store.interner import Interner
 from gochugaru_tpu.store.snapshot import build_snapshot
@@ -28,11 +37,37 @@ definition doc {
 }
 """
 
-INTERNERS = ["python"] + (["native"] if native.available() else [])
+#: the interner a world is built on, and which pass lowers its batches:
+#: "python" the pure-Python interner (always the Python pass), "native"
+#: the native interner and the native pull, "native-nopull" the native
+#: interner with the pull's library missing (the Python pass)
+INTERNERS = ["python"] + (
+    ["native", "native-nopull"] if native.available() else [])
+
+
+def _no_pull(monkeypatch, directory, source=None):
+    """Put a lowering library in place whose source is ``source`` in
+    ``directory`` (missing where None), so that the engine's lowering
+    finds no pull and runs its Python pass."""
+    if source is not None:
+        (directory / "lower.cpp").write_text(source)
+    monkeypatch.setattr(native, "_LOWER", native._Library(
+        "lower", native._LOWER.flag_sets, ctypes.PyDLL, native._bind_lower,
+        directory=str(directory)))
+
+
+@pytest.fixture
+def lowering(request, monkeypatch, tmp_path):
+    """Sets up the pass a test's ``kind`` names; returns whether the
+    native pull lowers its batches."""
+    kind = request.node.callspec.params["kind"]
+    if kind == "native-nopull":
+        _no_pull(monkeypatch, tmp_path)
+    return kind == "native" and native.lower_lib() is not None
 
 
 def _world(kind, **config):
-    if kind == "native":
+    if kind.startswith("native"):
         from gochugaru_tpu.native.interner import NativeInterner
 
         interner = NativeInterner()
@@ -46,6 +81,8 @@ def _world(kind, **config):
         rel.must_from_triple("doc:d2", "reader", "group:g0#member"),
         rel.must_from_triple("group:g0", "member", "user:u1"),
         rel.must_from_triple("group:g1", "member", "group:g0#member"),
+        rel.must_from_triple("doc:dé", "reader", "user:ü"),
+        rel.must_from_triple("doc:文書", "reader", "user:u1"),
     ]
     snap = build_snapshot(1, cs, interner, rels, epoch_us=1_700_000_000_000_000)
     return DeviceEngine(cs, EngineConfig.for_schema(cs, **config)), snap
@@ -106,6 +143,33 @@ MIXED = [
 
 SELF_ROWS = {7, 11, 14}
 
+
+class TaggedRelationship(Relationship):
+    """A subclass, as a caller's own type may be."""
+
+
+@dataclasses.dataclass(frozen=True)
+class SlotRelationship:
+    """An object with the six fields and no ``__dict__``."""
+
+    __slots__ = ("resource_type", "resource_id", "resource_relation",
+                 "subject_type", "subject_id", "subject_relation")
+    resource_type: str
+    resource_id: str
+    resource_relation: str
+    subject_type: str
+    subject_id: str
+    subject_relation: str
+
+
+KEY_FIELDS = [f.name for f in dataclasses.fields(SlotRelationship)]
+
+
+def _decoded(r):
+    return decoded_relationship(
+        *(getattr(r, k) for k in KEY_FIELDS), "", {}, None)
+
+
 BATCHES = {
     "mixed": MIXED,
     "empty": [],
@@ -116,21 +180,132 @@ BATCHES = {
     "one-wildcard": MIXED[6:7],
     "one-wildcard-no-node": MIXED[19:20],
     "one-wildcard-unknown-type": MIXED[20:21],
+    # ids the interner holds and does not hold, outside ASCII
+    "non-ascii": [
+        rel.must_from_triple("doc:dé", "read", "user:ü"),
+        rel.must_from_triple("doc:文書", "read", "user:u1"),
+        rel.must_from_triple("doc:dé", "read", "user:üü"),
+        rel.must_from_triple("doc:d1", "read", "user:🙂"),
+        rel.must_from_triple("doc:dé", "read", "user:u1"),
+    ],
+    # types the interner does not know, one a name that is no str
+    "unknown-type": [
+        rel.must_from_triple("ghost:x", "read", "user:u1"),
+        rel.must_from_triple("doc:d1", "read", "ghost:y"),
+        dataclasses.replace(MIXED[0], resource_type=7),
+        dataclasses.replace(MIXED[0], subject_type=("user",)),
+        rel.must_from_triple("doc:d3", "read", "user:u3"),
+    ],
+    # a relation with no slot on the resource's side, the subject's, both
+    "no-slot": [
+        rel.must_from_triple("doc:d1", "nosuch", "user:u1"),
+        rel.must_from_triple("doc:d2", "read", "group:g0#nosuch"),
+        rel.must_from_triple("group:g0", "nosuch", "group:g1#nosuch"),
+        rel.must_from_triple("doc:d3", "read", "user:u3"),
+    ],
+    "decoded": [_decoded(r) for r in MIXED],
+    "subclass": [TaggedRelationship(**{k: getattr(r, k) for k in KEY_FIELDS})
+                 for r in MIXED],
+    "slots": [SlotRelationship(*(getattr(r, k) for k in KEY_FIELDS))
+              for r in MIXED],
+    "mixed-objects": [
+        f(r) for r in MIXED for f in (
+            lambda r: r, _decoded,
+            lambda r: SlotRelationship(*(getattr(r, k) for k in KEY_FIELDS)))],
 }
+
+
+def _native_batches():
+    return metrics.default.counter("engine.lower_native_batches")
 
 
 @pytest.mark.parametrize("batch", list(BATCHES))
 @pytest.mark.parametrize("kind", INTERNERS)
-def test_lowered_columns_equal_a_per_key_reference(kind, batch):
+def test_lowered_columns_equal_a_per_key_reference(kind, batch, lowering):
     engine, snap = _world(kind)
     rels = BATCHES[batch]
+    before = _native_batches()
     queries, _ = engine._lower_queries(snap, rels)
+    assert _native_batches() - before == (1 if lowering else 0)
     want = _reference_columns(engine, snap, rels)
     assert set(queries) == set(want) | {"q_ctx"}
     for k, col in want.items():
         assert queries[k].tolist() == col, k
         assert queries[k].dtype == (bool if k == "q_self" else np.int32), k
     assert queries["q_ctx"].tolist() == [-1] * len(rels)
+
+
+def test_the_native_pull_engages_where_its_library_builds():
+    """The pull is what the native interner's batches take on a host
+    with a compiler and the interpreter's headers, as this one has."""
+    assert native.available()
+    assert native.lower_lib() is not None
+
+
+def _bad(**fields):
+    return [*MIXED[:3], dataclasses.replace(MIXED[3], **fields), *MIXED[4:6]]
+
+
+BAD_BATCHES = {
+    "non-str-resource-id": (_bad(resource_id=5), TypeError),
+    "non-str-subject-id": (_bad(subject_id=None), TypeError),
+    "surrogate-id": (_bad(subject_id="u\ud800"), UnicodeEncodeError),
+    "unhashable-resource-type": (_bad(resource_type=["doc"]), TypeError),
+    "unhashable-subject-type": (_bad(subject_type={}), TypeError),
+    "unhashable-resource-relation": (_bad(resource_relation=["read"]), TypeError),
+    "unhashable-subject-relation": (_bad(subject_relation=["member"]), TypeError),
+    "missing-field": (
+        [*MIXED[:2], SimpleNamespace(**{
+            k: "x" for k in KEY_FIELDS if k != "subject_relation"})],
+        AttributeError),
+}
+
+
+@pytest.mark.skipif(not native.available(), reason="no native interner")
+@pytest.mark.parametrize("case", list(BAD_BATCHES))
+def test_a_bad_field_raises_the_same_type_through_both_passes(
+        case, monkeypatch, tmp_path):
+    rels, error = BAD_BATCHES[case]
+    engine, snap = _world("native")
+    with pytest.raises(error):
+        engine._lower_queries(snap, rels)
+    _no_pull(monkeypatch, tmp_path)
+    with pytest.raises(error):
+        engine._lower_queries(snap, rels)
+
+
+def _random_batch(n, seed):
+    rng = np.random.default_rng(seed)
+    pool = [r for b in BATCHES.values() for r in b]
+    return [pool[i] for i in rng.integers(0, len(pool), n).tolist()]
+
+
+@pytest.mark.skipif(not native.available(), reason="no native interner")
+@pytest.mark.parametrize("how", ["disabled", "missing", "failed-build"])
+def test_without_the_pull_the_python_pass_gives_the_same_columns(
+        how, monkeypatch, tmp_path):
+    """``native.set_enabled(False)``, a missing source or a build that
+    fails leaves the engine on its Python pass, with the same columns as
+    the native pull's; without the pull library the interner's library
+    stays loaded."""
+    engine, snap = _world("native")
+    rels = _random_batch(2_000, 39)
+    before = _native_batches()
+    want, _ = engine._lower_queries(snap, rels)
+    assert _native_batches() - before == 1
+    if how == "disabled":
+        monkeypatch.setattr(native, "_forced_off", native._forced_off)
+        native.set_enabled(False)
+    else:
+        _no_pull(monkeypatch, tmp_path,
+                 "#error a broken build\n" if how == "failed-build" else None)
+        assert native.available()
+        assert not (tmp_path / "libgochugaru_lower.so").exists()
+    assert native.lower_lib() is None
+    got, _ = engine._lower_queries(snap, rels)
+    assert _native_batches() - before == 1
+    for k, col in want.items():
+        assert col.dtype == got[k].dtype and np.array_equal(col, got[k]), k
 
 
 def test_the_reference_settles_the_rows_it_is_there_for():
@@ -215,10 +390,10 @@ def test_only_the_two_phase_dispatch_builds_the_subject_rows(door):
 
 
 @pytest.mark.parametrize("kind", INTERNERS)
-def test_concurrent_lowering_with_a_writer_equals_one_thread(kind):
+def test_concurrent_lowering_with_a_writer_equals_one_thread(kind, lowering):
     """Four threads lower different batches at once while a fifth interns
-    new nodes (the native table grows under them): each gets the columns
-    one thread gets."""
+    new nodes and new types (the native table grows under them): each
+    gets the columns one thread gets."""
     engine, snap = _world(kind)
     # the native table (65,536 slots) rehashes at 0.7 load: start just
     # under it, so that the writer's first few hundred nodes cross it
@@ -231,22 +406,31 @@ def test_concurrent_lowering_with_a_writer_equals_one_thread(kind):
         for k in range(4)
     ]
     want = [engine._lower_queries(snap, b)[0] for b in batches]
+    before = metrics.default.counter("engine.lower_native_batches")
     got = [[] for _ in batches]
     errors = []
-    stop = threading.Event()
+    stop, grown = threading.Event(), threading.Event()
 
     def lower(k):
+        # six batches at least, and on until the writer has crossed the
+        # rehash: a fast pass must not finish before the table grows
         try:
-            for _ in range(6):
+            for n in range(1_000):
                 got[k].append(engine._lower_queries(snap, batches[k])[0])
+                if n >= 5 and grown.is_set():
+                    break
         except Exception as e:  # surfaced below
             errors.append(e)
 
     def write():
         i = 0
         while not stop.is_set() and i < 120_000:
-            snap.interner.node("user", f"new{i}")
+            # a type no batch names, now and then: the type table grows too
+            snap.interner.node(f"newtype{i}" if i % 64 == 0 else "user",
+                               f"new{i}")
             i += 1
+            if i == 1_000:
+                grown.set()
 
     writer = threading.Thread(target=write)
     threads = [threading.Thread(target=lower, args=(k,)) for k in range(4)]
@@ -265,8 +449,12 @@ def test_concurrent_lowering_with_a_writer_equals_one_thread(kind):
     assert not any(t.is_alive() for t in threads + [writer])
     assert not errors, errors
     assert len(snap.interner) > 46_000, "the writer never grew the table"
+    assert snap.interner.type_lookup("newtype320") >= 0
+    lowered = sum(map(len, got))
+    assert metrics.default.counter("engine.lower_native_batches") - before == (
+        lowered if lowering else 0)
     for k, runs in enumerate(got):
-        assert len(runs) == 6
+        assert len(runs) >= 6
         for q in runs:
             for name, col in want[k].items():
                 assert np.array_equal(q[name], col), (k, name)

@@ -173,3 +173,8 @@ def test_lookup_pairs_native_matches_python_on_random_pairs():
     want, want_t = ref.lookup_pairs(types, ids)
     assert (got == want).all() and (got_t == want_t).all()
     assert (got >= 0).any() and (got < 0).any()
+    # the locked half alone, on ids packed as the lowering's pull packs them
+    buf, offsets = nat._pack(ids)
+    assert (nat.lookup_packed(buf, offsets, got_t) == want).all()
+    with pytest.raises(ValueError):
+        nat.lookup_packed(buf, offsets, got_t[1:])
