@@ -1752,6 +1752,7 @@ class Client:
         caveat_name: str = "",
         context_ids=None,
         contexts: Sequence[Mapping[str, Any]] = (),
+        expirations=None,
     ) -> None:
         """Columnar bulk restore: one relationship shape, ids as parallel
         string columns — the native-path complement of
@@ -1759,9 +1760,11 @@ class Client:
         restores (no per-edge objects; batch interning; one validation).
         ``caveat_name`` writes every row ``with`` that caveat;
         ``context_ids`` (an int column, −1 for none) index ``contexts``,
-        the distinct stored-context dicts of the call.
-        Falls back to a retried TOUCH import on AlreadyExists, like the
-        reference's recovery (client/client.go:448-463)."""
+        the distinct stored-context dicts of the call.  ``expirations``
+        (an int column of micros since the Unix epoch, 0 for none) gives
+        each row its expiry; a row already expired is stored and never
+        grants.  Falls back to a retried TOUCH import on AlreadyExists,
+        like the reference's recovery (client/client.go:448-463)."""
         self._check_overlap(ctx)
         kw = dict(
             resource_type=resource_type, resource_ids=resource_ids,
@@ -1769,6 +1772,7 @@ class Client:
             subject_type=subject_type, subject_ids=subject_ids,
             subject_relation=subject_relation, caveat_name=caveat_name,
             context_ids=context_ids, contexts=contexts,
+            expirations=expirations,
         )
         try:
             self._store.import_columns(**kw)
@@ -1821,13 +1825,14 @@ class Client:
         caveat_name: str = "",
         context_ids=None,
         contexts: Sequence[Mapping[str, Any]] = (),
+        expirations=None,
     ) -> None:
         """Pre-interned columnar bulk restore: int node-id columns from
         THIS store's interner (``export_relationship_id_columns``
         chunks, or ``Interner.node_batch`` results) — no string work at
         all, the fastest restore path (~5x the string-columnar rate).
         Rows may mix resource/subject types.  ``caveat_name``,
-        ``context_ids`` and ``contexts`` as in
+        ``context_ids``, ``contexts`` and ``expirations`` as in
         ``import_relationship_columns``.  Falls back to a retried TOUCH
         import on AlreadyExists, like the reference's recovery
         (client/client.go:448-463)."""
@@ -1836,7 +1841,7 @@ class Client:
             resource_ids=resource_ids, resource_relation=resource_relation,
             subject_ids=subject_ids, subject_relation=subject_relation,
             caveat_name=caveat_name, context_ids=context_ids,
-            contexts=contexts,
+            contexts=contexts, expirations=expirations,
         )
         try:
             self._store.import_interned_columns(**kw)
@@ -1852,11 +1857,11 @@ class Client:
         self, ctx: Context, revision: str
     ) -> Iterator[Dict[str, Any]]:
         """Interned columnar export at an exact snapshot revision: yields
-        chunks of int32 node-id columns (one (relation, subject-relation)
-        shape per chunk) — the zero-string mirror of
-        ``import_relationship_id_columns`` for restore pipelines staying
-        within this store's interner.  Cancellation is honored between
-        chunks."""
+        chunks of int32 node-id columns and their ``expirations`` (one
+        (relation, subject-relation) shape per chunk) — the zero-string
+        mirror of ``import_relationship_id_columns`` for restore pipelines
+        staying within this store's interner.  Cancellation is honored
+        between chunks."""
         self._check_overlap(ctx)
         for chunk in self._store.export_interned_columns_at(revision):
             err = ctx.err()

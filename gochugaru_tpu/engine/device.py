@@ -1536,7 +1536,8 @@ class DeviceEngine:
         request's trace span (utils/trace.py): sampled dispatches record
         a ``device.check_batch`` child whose children are the stages
         ``engine.lower`` / ``.enqueue`` / ``.fetch``; the NOOP span costs
-        one branch."""
+        one branch.  A batch whose programs read expiries
+        (``FlatMeta.gates_expiry``) counts ``engine.expiry_batches``."""
         if not rels:
             z = np.zeros(0, bool)
             return z, z, z
@@ -1549,6 +1550,8 @@ class DeviceEngine:
                 snap, rels, dsnap.strings, span=dsp
             )
             B = len(rels)
+            if dsnap.flat_meta is not None and dsnap.flat_meta.gates_expiry:
+                metrics.default.inc("engine.expiry_batches")
             if latency:
                 out = self.latency_path(dsnap).dispatch(
                     queries, qctx, B, snap.now_rel32(now_us),

@@ -358,6 +358,17 @@ class FlatMeta:
     pf_has_e: bool = False
     pf_has_u: bool = False
 
+    @property
+    def gates_expiry(self) -> bool:
+        """The programs of this snapshot read expiries: a view carries the
+        ``exp`` gate lane, or the fold's rows or its pf_u / closure slices
+        carry until values (the plane masks compile in)."""
+        return (
+            self.e_hasexp or self.us_hasexp or self.ar_hasexp
+            or self.pf_hasuntil
+            or self.pf_has_u and not (self.pf_u_alllive and self.pf_s_alllive)
+        )
+
 
 def placement_split(dsnap) -> Dict[str, int]:
     """{"total", "sharded", "replicated"} resident device-table bytes:
@@ -984,16 +995,18 @@ def _pf_view_tables(
     packed key itself, two element gathers per range — or through legacy
     hash group tables when the key space is over budget.  Until columns
     are omitted entirely when every row is unexpiring (the common case;
-    the kernel then skips the plane masks).  Returns (arrays, meta kw)."""
+    the kernel then skips the plane masks); where they ship, their
+    packing observes ``prepare.expiry_s``.  Returns (arrays, meta kw)."""
     from ..store.closure import NO_EXP
 
     out: Dict[str, np.ndarray] = {}
     pad_u, pad_s = max(64, u_fan), max(64, s_fan)
     out["pfu_gk"] = _pf_col(u_gk, pad_u, -1)
+    out["csr_gk"] = _pf_col(cl_k2, pad_s, -1)
+    t0 = time.perf_counter()
     u_alllive = bool((u_until == NO_EXP).all()) if u_until.shape[0] else True
     if not u_alllive:
         out["pfu_u"] = _pf_col(u_until, pad_u, 0)
-    out["csr_gk"] = _pf_col(cl_k2, pad_s, -1)
     s_alllive = (
         bool((cl_d == NO_EXP).all() and (cl_p == NO_EXP).all())
         if cl_k1.shape[0] else True
@@ -1001,6 +1014,11 @@ def _pf_view_tables(
     if not s_alllive:
         out["csr_d"] = _pf_col(cl_d, pad_s, 0)
         out["csr_p"] = _pf_col(cl_p, pad_s, 0)
+    if not (u_alllive and s_alllive):
+        from ..utils import metrics
+
+        # the until slices: the fold's expiry work outside prepare.fold_s
+        metrics.default.observe("prepare.expiry_s", time.perf_counter() - t0)
     n_f = max(len(fold_slots), 1)
     budget = config.flat_pf_direct_max_entries
     u_direct = n_f * N + 1 <= budget

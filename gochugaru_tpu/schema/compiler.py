@@ -131,12 +131,17 @@ class CompiledSchema:
         return "absent"
 
     # -- write-path validation --------------------------------------------
-    def validate_relationship(self, r: Relationship) -> None:
+    def validate_relationship(
+        self, r: Relationship, *, expiry_trait: bool = False
+    ) -> None:
         """Validate a relationship against the schema the way SpiceDB
         validates writes: the resource type must be defined, the resource
         relation must be a plain relation (not a permission), and the
         subject must match one of the relation's allowed subject types
-        (including wildcard/userset/caveat forms)."""
+        (including wildcard/userset/caveat forms).  With ``expiry_trait``
+        an expiring relationship must match an alternative written
+        ``with expiration`` (the columnar imports hold rows to it); without
+        it an expiring relationship is accepted on any alternative."""
         d = self.schema.definitions.get(r.resource_type)
         if d is None:
             raise SchemaValidationError(f"object definition `{r.resource_type}` not found")
@@ -169,10 +174,18 @@ class CompiledSchema:
         # Multiple alternatives may differ only in caveat/expiration traits
         # (``user | user with office_hours``); the relationship must satisfy
         # at least one alternative exactly.
+        expiring = r.has_expiration()
         if not any(
-            a.caveat == r.caveat_name and (not a.expiration or r.has_expiration())
+            a.caveat == r.caveat_name
+            and (expiring if a.expiration else not (expiring and expiry_trait))
             for a in matches
         ):
+            same = [a for a in matches if a.caveat == r.caveat_name]
+            if same and expiring and not any(a.expiration for a in same):
+                raise SchemaValidationError(
+                    f"relation `{r.resource_type}#{r.resource_relation}` does"
+                    " not allow an expiration for this subject"
+                )
             if r.caveat_name:
                 raise SchemaValidationError(
                     f"caveat `{r.caveat_name}` is not allowed for this subject on"

@@ -46,19 +46,26 @@ def _exp_to_rel32(exp_us: np.ndarray, epoch_us: int) -> np.ndarray:
     that would land exactly on 0 (i.e. at/before the snapshot epoch) maps
     to -1 so it can't collide with the no-expiration sentinel; out-of-range
     futures clip to I32_MAX-1 (still in the future for any plausible query
-    time)."""
+    time).  A column with expiries observes ``prepare.expiry_s``."""
     if not exp_us.any():
         # bulk imports rarely carry expirations: skip the int64 clip
         # chain for the all-zero column (identical output — zero maps
         # to the no-expiration sentinel 0 either way)
         return np.zeros(exp_us.shape[0], np.int32)
+    import time as _time
+
+    from ..utils import metrics
+
+    t0 = _time.perf_counter()
     rel = np.clip(
         -(-(exp_us - epoch_us) // 1_000_000),  # ceil division
         -(2**31) + 2,
         2**31 - 2,
     )
     rel = np.where(rel == 0, np.int64(-1), rel)
-    return np.where(exp_us == 0, np.int64(0), rel).astype(np.int32)
+    out = np.where(exp_us == 0, np.int64(0), rel).astype(np.int32)
+    metrics.default.observe("prepare.expiry_s", _time.perf_counter() - t0)
+    return out
 
 
 @dataclass

@@ -20,6 +20,7 @@ one lock with RCU-style snapshot swaps).  Semantics enforced:
 from __future__ import annotations
 
 import bisect
+import datetime as _dt
 import threading
 import time
 from dataclasses import dataclass
@@ -65,6 +66,39 @@ _TOKEN_PREFIX = "gtz1."
 #: go through the live dict (interactive-write path) so segment count
 #: stays bounded by the number of genuine bulk loads
 COLUMNAR_IMPORT_MIN = 10_000
+
+
+def _expiry_column(B: int, expirations) -> np.ndarray:
+    """The ``exp_us`` column of a columnar import: ``expirations`` as
+    int64 micros since the Unix epoch, 0 for none (all zeros when not
+    given).  A column that is not B whole numbers ≥ 0 refuses the call
+    before anything is applied.  A row already expired is stored as it
+    is and never grants."""
+    if expirations is None:
+        return np.zeros(B, np.int64)
+    exp = np.asarray(expirations)
+    if exp.shape != (B,):
+        raise ValueError("expirations and the id columns lengths differ")
+    if B and exp.dtype.kind not in "iu":
+        raise ValueError(
+            f"expirations must be integer micros since the Unix epoch,"
+            f" not {exp.dtype}"
+        )
+    exp = np.ascontiguousarray(exp, np.int64)
+    if B and int(exp.min()) < 0:
+        raise ValueError("expirations must be 0 (none) or micros since the epoch")
+    return exp
+
+
+def _expiry_of(exp_us) -> Optional[_dt.datetime]:
+    """A validation representative's expiration: the row's own instant,
+    None for 0."""
+    exp_us = int(exp_us)
+    if not exp_us:
+        return None
+    return _dt.datetime(1970, 1, 1, tzinfo=_dt.timezone.utc) + _dt.timedelta(
+        microseconds=exp_us
+    )
 
 
 def RevisionToken(rev: int) -> str:
@@ -820,6 +854,7 @@ class Store:
         caveat_name: str = "",
         context_ids=None,
         contexts: Sequence[Mapping[str, Any]] = (),
+        expirations=None,
         touch: bool = False,
     ) -> str:
         """Columnar bulk import: one (resource type, relation, subject
@@ -830,10 +865,11 @@ class Store:
         objects, one validation for the whole call, batch interning.
         ``caveat_name`` puts every row of the call under one caveat;
         ``context_ids`` (−1 for none) index ``contexts``, the call's
-        distinct stored contexts (``_caveat_columns``).  Expiring rows
-        use the object path (``import_relationships``).  Returns the
-        minted revision; raises AlreadyExistsError (nothing applied) on
-        any live duplicate unless ``touch``."""
+        distinct stored contexts (``_caveat_columns``).  ``expirations``
+        (int micros since the Unix epoch, 0 for none) gives each row its
+        expiry (``_expiry_column``).  Returns the minted revision; raises
+        AlreadyExistsError (nothing applied) on any live duplicate unless
+        ``touch``."""
         B = len(resource_ids)
         if len(subject_ids) != B:
             raise ValueError("resource_ids and subject_ids lengths differ")
@@ -842,22 +878,28 @@ class Store:
             now_us = self._now_us()
             caveat, ctx, novel = self._caveat_columns(
                 compiled, B, caveat_name, context_ids, contexts)
-            # shape validation: wildcardness is part of the validation
-            # shape, so a mixed batch validates BOTH representatives
-            concrete = next((s for s in subject_ids if s != "*"), None)
-            reps = ([concrete] if concrete is not None else []) + (
-                ["*"] if "*" in subject_ids else []
-            )
-            for rep in reps or (["x"] if B == 0 else []):
+            exp_us = _expiry_column(B, expirations)
+            # shape validation: wildcardness and expiry are part of the
+            # validation shape, so a mixed batch validates a
+            # representative of each (subject, expiry) combination it holds
+            if "*" in subject_ids:
+                is_wc = np.fromiter((s == "*" for s in subject_ids), bool, B)
+            else:
+                is_wc = np.zeros(B, bool)
+            combos = np.unique(
+                is_wc.astype(np.int64) << 1 | (exp_us != 0), return_index=True
+            )[1] if B else [None]
+            for i in combos:
                 compiled.validate_relationship(Relationship(
                     resource_type=resource_type,
                     resource_id=resource_ids[0] if B else "x",
                     resource_relation=resource_relation,
                     subject_type=subject_type,
-                    subject_id=rep,
+                    subject_id="x" if i is None else subject_ids[i],
                     subject_relation=subject_relation,
                     caveat_name=caveat_name,
-                ))
+                    expiration=None if i is None else _expiry_of(exp_us[i]),
+                ), expiry_trait=True)
             if B == 0:
                 return RevisionToken(self._head_rev)
             itn = self.interner
@@ -885,7 +927,7 @@ class Store:
                 ),
                 "caveat": caveat,
                 "ctx": ctx,
-                "exp_us": np.zeros(B, np.int64),
+                "exp_us": exp_us,
             }
 
             def describe(i: int) -> str:
@@ -911,6 +953,7 @@ class Store:
         caveat_name: str = "",
         context_ids=None,
         contexts: Sequence[Mapping[str, Any]] = (),
+        expirations=None,
         touch: bool = False,
     ) -> str:
         """Pre-interned columnar bulk import: node-id columns from THIS
@@ -918,12 +961,12 @@ class Store:
         ``Interner.node_batch`` results), skipping ALL string work — no
         hashing, no packing, no per-id Python.  Rows may mix resource
         and subject types freely; validation runs once per distinct
-        (resource type, subject type, wildcardness) combination through
-        the same validator as the object path.  ``caveat_name``,
-        ``context_ids`` and ``contexts`` as in ``import_columns``.  This
-        is the 1B-edge restore fast path (the reference's
-        BulkImportRelationships surface, client/client.go:438-465, at
-        ~5x the string-columnar rate).  Returns the minted revision;
+        (resource type, subject type, wildcardness, expiry) combination
+        through the same validator as the object path.  ``caveat_name``,
+        ``context_ids``, ``contexts`` and ``expirations`` as in
+        ``import_columns``.  This is the 1B-edge restore fast path (the
+        reference's BulkImportRelationships surface,
+        client/client.go:438-465, at ~5x the string-columnar rate).  Returns the minted revision;
         raises AlreadyExistsError (nothing applied) on any live duplicate
         unless ``touch``."""
         res = np.ascontiguousarray(resource_ids, dtype=np.int32)
@@ -936,6 +979,7 @@ class Store:
             now_us = self._now_us()
             caveat, ctx, novel = self._caveat_columns(
                 compiled, B, caveat_name, context_ids, contexts)
+            exp_us = _expiry_column(B, expirations)
             itn = self.interner
             NN = len(itn)
             if B:
@@ -977,7 +1021,8 @@ class Store:
                     if wc_ids.size else np.zeros(B, bool)
                 )
                 combos = np.unique(
-                    (rt << 21) | (st << 1) | wc, return_index=True
+                    (rt << 22) | (st << 2) | (wc << 1) | (exp_us != 0),
+                    return_index=True,
                 )[1]
                 for i in combos:
                     rtype, rid = itn.key_of(int(res[i]))
@@ -988,7 +1033,8 @@ class Store:
                         subject_type=stype, subject_id=sid,
                         subject_relation=subject_relation,
                         caveat_name=caveat_name,
-                    ))
+                        expiration=_expiry_of(exp_us[i]),
+                    ), expiry_trait=True)
             if B == 0:
                 return RevisionToken(self._head_rev)
             cols = {
@@ -1002,7 +1048,7 @@ class Store:
                 ),
                 "caveat": caveat,
                 "ctx": ctx,
-                "exp_us": np.zeros(B, np.int64),
+                "exp_us": exp_us,
             }
 
             def describe(i: int) -> str:
@@ -1073,11 +1119,13 @@ class Store:
 
     def export_interned_columns_at(self, revision: str):
         """Interned columnar export at an exact snapshot: yields chunk
-        dicts with int32 ``res``/``subj`` node-id columns plus decoded
-        ``resource_relation``/``subject_relation`` names — the zero-
-        string mirror of ``import_interned_columns`` for restore
-        pipelines that stay within this store's interner (the ids remain
-        valid across revisions: the interner is append-only)."""
+        dicts with int32 ``res``/``subj`` node-id columns, the int64
+        ``expirations`` column (micros since the Unix epoch, 0 = none)
+        and decoded ``resource_relation``/``subject_relation`` names —
+        the zero-string mirror of ``import_interned_columns`` for
+        restore pipelines that stay within this store's interner (the ids
+        remain valid across revisions: the interner is append-only).
+        Rows expired by now are stored but not exported."""
         snap = self.snapshot_for(Strategy(Requirement.SNAPSHOT, revision))
         now_us = self._now_us()
         live = (snap.e_exp_us == 0) | (snap.e_exp_us > now_us)
@@ -1103,6 +1151,7 @@ class Store:
             yield {
                 "res": snap.e_res[rows[lo:hi]].astype(np.int32),
                 "subj": snap.e_subj[rows[lo:hi]].astype(np.int32),
+                "expirations": snap.e_exp_us[rows[lo:hi]].astype(np.int64),
                 "resource_relation": name_of_slot[int(snap.e_rel[r0])],
                 "subject_relation": (
                     name_of_slot[int(snap.e_srel1[r0]) - 1]
@@ -1280,6 +1329,9 @@ class Store:
             ),
         )
         self._segments.append(seg)
+        expiring = int(np.count_nonzero(seg.exp_us))
+        if expiring:
+            _metrics.default.inc("store.expiring_rows", expiring)
         utype = UpdateType.TOUCH if touch else UpdateType.CREATE
         self._head_rev += 1
         self._log.append(

@@ -1,0 +1,324 @@
+"""Expiring grants on the flat device path, over GitHub-style RBAC whose
+memberships and grants expire (org → team → repo; ``team#member``,
+``org#member``, ``repo#maintainer``'s team share and ``repo#reader`` all
+``with expiration``; ``org#admin`` and ``repo#org`` never expire).
+
+Seeded small worlds go in through the columnar imports with their
+expiries; every check is compared with a numpy reference over the live
+edges alone and with the host oracle, and must be answered on the device.
+Named cases sit beside the random ones: an expired reader, an expired team
+membership that reaches both ``maintainer`` and ``org->member``, an expired
+org-team share and an expired repo-team share, each with a live twin.
+
+An expiry that falls inside the second of a check's instant is the one place
+the device is not exact to the microsecond: the tables hold expiries as
+whole seconds from the snapshot's epoch rounded up and the instant rounded
+down, so such a grant holds until the next whole second, never less.
+"""
+
+from __future__ import annotations
+
+import time
+
+import numpy as np
+import pytest
+
+from gochugaru_tpu import consistency, new_tpu_evaluator, rel
+from gochugaru_tpu.client import with_latency_mode
+from gochugaru_tpu.engine.device import DeviceEngine
+from gochugaru_tpu.engine.oracle import Oracle, SnapshotOracle, T
+from gochugaru_tpu.engine.plan import EngineConfig
+from gochugaru_tpu.schema import compile_schema, parse_schema
+from gochugaru_tpu.store.interner import Interner
+from gochugaru_tpu.store.snapshot import build_snapshot
+from gochugaru_tpu.utils import metrics
+from gochugaru_tpu.utils.context import background
+
+SCHEMA = """
+use expiration
+
+definition user {}
+definition team { relation member: user with expiration }
+definition org {
+    relation admin: user
+    relation member: user with expiration | team#member with expiration
+}
+definition repo {
+    relation org: org
+    relation maintainer: user | team#member with expiration
+    relation reader: user with expiration
+    permission admin = org->admin + maintainer
+    permission read = reader + admin + org->member
+}
+"""
+U, TEAMS, O, R = 600, 60, 12, 3000
+HOUR_US = 3600 * 1_000_000
+CS = consistency.full()
+HOST = ("checks.oracle", "checks.fallback_conditional", "checks.fallback_overflow")
+#: (edge list, resource type, relation, subject type, subject relation)
+SHAPES = (
+    ("team_user", "team", "member", "user", ""),
+    ("org_admin", "org", "admin", "user", ""),
+    ("org_team", "org", "member", "team", "member"),
+    ("org_user", "org", "member", "user", ""),
+    ("repo_org", "repo", "org", "org", ""),
+    ("repo_team", "repo", "maintainer", "team", "member"),
+    ("repo_reader", "repo", "reader", "user", ""),
+)
+EXPIRING = ("team_user", "org_team", "org_user", "repo_team", "repo_reader")
+#: the named cases: (repo, user) → granted, on objects of their own
+#: (``r*``/``t*``/``o*``/``u*`` past the random world's ids)
+NAMED = {
+    "an expired reader": ("rx0", "ux0", False),
+    "its live twin": ("rx1", "ux0", True),
+    "an expired membership through maintainer": ("rx2", "ux1", False),
+    "an expired membership through org->member": ("rx3", "ux1", False),
+    "the live member through maintainer": ("rx2", "ux2", True),
+    "the live member through org->member": ("rx3", "ux2", True),
+    "an expired org-team share": ("rx4", "ux3", False),
+    "its live twin share": ("rx5", "ux4", True),
+    "an expired repo-team share": ("rx6", "ux5", False),
+    "its live twin repo share": ("rx7", "ux6", True),
+}
+
+
+def named_edges(now_us: int):
+    """The named cases' edges as (shape, resource, subject, expiry micros)."""
+    live, gone = now_us + 30 * 24 * HOUR_US, now_us - 2 * HOUR_US
+    return [
+        ("repo_reader", "rx0", "ux0", gone), ("repo_reader", "rx1", "ux0", live),
+        # tx0: ux1 expired, ux2 live; a maintainer share and an org's member
+        ("team_user", "tx0", "ux1", gone), ("team_user", "tx0", "ux2", live),
+        ("repo_team", "rx2", "tx0", live), ("org_team", "ox0", "tx0", live),
+        ("repo_org", "rx3", "ox0", 0),
+        # org-team shares: ox1's has expired, ox2's is live
+        ("team_user", "tx1", "ux3", live), ("org_team", "ox1", "tx1", gone),
+        ("repo_org", "rx4", "ox1", 0),
+        ("team_user", "tx2", "ux4", live), ("org_team", "ox2", "tx2", live),
+        ("repo_org", "rx5", "ox2", 0),
+        # repo-team shares: rx6's has expired, rx7's is live
+        ("team_user", "tx3", "ux5", live), ("repo_team", "rx6", "tx3", gone),
+        ("team_user", "tx4", "ux6", live), ("repo_team", "rx7", "tx4", live),
+        ("org_admin", "ox0", "ua", 0), ("org_admin", "ox1", "ua", 0),
+        ("org_admin", "ox2", "ua", 0),
+    ]
+
+
+def build_world(seed: int, now_us: int) -> dict:
+    """Edge lists of ids with expiries (0 for none): the random world, a
+    tenth of every expiring list already expired, plus the named cases."""
+    rng = np.random.default_rng(seed)
+
+    def pairs(n_src, per, n_dst, src, dst):
+        key = np.unique(np.repeat(np.arange(n_src), per) * n_dst
+                        + rng.integers(0, n_dst, n_src * per))
+        return [f"{src}{a}" for a in (key // n_dst).tolist()], \
+            [f"{dst}{b}" for b in (key % n_dst).tolist()]
+
+    w = {
+        "team_user": pairs(TEAMS, 12, U, "t", "u"),
+        "org_admin": pairs(O, 1, U, "o", "u"),
+        "org_team": pairs(O, 2, TEAMS, "o", "t"),
+        "org_user": pairs(O, 4, U, "o", "u"),
+        "repo_org": pairs(R, 1, O, "r", "o"),
+        "repo_team": pairs(R, 1, TEAMS, "r", "t"),
+        "repo_reader": pairs(R, 2, U, "r", "u"),
+    }
+    out = {}
+    for key, (res, subj) in w.items():
+        n = len(res)
+        exp = np.zeros(n, np.int64)
+        if key in EXPIRING:
+            exp = now_us + rng.integers(HOUR_US, 90 * 24 * HOUR_US, n)
+            gone = rng.random(n) < 0.1
+            exp[gone] = now_us - rng.integers(HOUR_US, 24 * HOUR_US, int(gone.sum()))
+        out[key] = (res, subj, exp)
+    for key, res, subj, exp in named_edges(now_us):
+        r, s, e = out[key]
+        out[key] = (r + [res], s + [subj], np.append(e, exp))
+    return out
+
+
+def reference(w, now_us: int):
+    """``read(repo, user)`` from the live edges alone."""
+    live = {k: {(r, s) for r, s, e in zip(res, subj, exp.tolist())
+                if e == 0 or e > now_us}
+            for k, (res, subj, exp) in w.items()}
+    members = {}
+    for t, u in live["team_user"]:
+        members.setdefault(t, set()).add(u)
+    org_of = {r: o for r, o in live["repo_org"]}
+
+    def org_member(o, u):
+        return ((o, u) in live["org_admin"] or (o, u) in live["org_user"]
+                or any(u in members.get(t, ()) for oo, t in live["org_team"]
+                       if oo == o))
+
+    def read(r, u):
+        return ((r, u) in live["repo_reader"]
+                or any(u in members.get(t, ()) for rr, t in live["repo_team"]
+                       if rr == r)
+                or (r in org_of and org_member(org_of[r], u)))
+
+    return read
+
+
+def load(w):
+    """A client holding ``w``: one columnar call an edge list, the
+    expiring lists with their ``expirations``."""
+    c = new_tpu_evaluator(with_latency_mode())
+    ctx = background()
+    c.write_schema(ctx, SCHEMA)
+    for key, rtype, relation, stype, srel in SHAPES:
+        res, subj, exp = w[key]
+        kw = {"expirations": exp} if key in EXPIRING else {}
+        c.import_relationship_columns(
+            ctx, resource_type=rtype, resource_ids=res, resource_relation=relation,
+            subject_type=stype, subject_ids=subj, subject_relation=srel, **kw)
+    return c
+
+
+def probes(w, n: int, seed: int):
+    """(repo, user) pairs: readers, members of the repo's team and of its
+    org's teams (expired edges included), and uniform pairs."""
+    rng = np.random.default_rng(seed)
+    members = {}
+    for t, u in zip(*w["team_user"][:2]):
+        members.setdefault(t, []).append(u)
+    org_teams = {}
+    for o, t in zip(*w["org_team"][:2]):
+        org_teams.setdefault(o, []).append(t)
+    out = []
+    readers, shares, orgs = w["repo_reader"], w["repo_team"], w["repo_org"]
+    org_of = dict(zip(*orgs[:2]))
+    for i in rng.integers(0, len(readers[0]), n // 4).tolist():
+        out.append((readers[0][i], readers[1][i]))
+    for i in rng.integers(0, len(shares[0]), n // 4).tolist():
+        pool = members.get(shares[1][i], ["u0"])
+        out.append((shares[0][i], pool[rng.integers(len(pool))]))
+    for i in rng.integers(0, len(orgs[0]), n // 4).tolist():
+        teams = org_teams.get(org_of[orgs[0][i]], [])
+        pool = members.get(teams[rng.integers(len(teams))], ["u0"]) if teams else ["u0"]
+        out.append((orgs[0][i], pool[rng.integers(len(pool))]))
+    for r, u in zip(rng.integers(0, R, n - 3 * (n // 4)).tolist(),
+                    rng.integers(0, U, n - 3 * (n // 4)).tolist()):
+        out.append((f"r{r}", f"u{u}"))
+    return out
+
+
+def counters(names) -> list:
+    snap = metrics.default.snapshot()
+    return [snap.get(k, 0) for k in names]
+
+
+@pytest.mark.parametrize("seed", [1, 2])
+def test_device_answers_equal_the_reference_and_the_oracle(seed):
+    now_us = time.time_ns() // 1000
+    w = build_world(seed, now_us)
+    c = load(w)
+    pairs = probes(w, 2000, seed) + [(r, u) for r, u, _ in NAMED.values()]
+    rels = [rel.must_from_triple(f"repo:{r}", "read", f"user:{u}") for r, u in pairs]
+    read = reference(w, now_us)
+    want = [read(r, u) for r, u in pairs]
+    assert 0.2 < np.mean(want) < 0.9
+    # an expired edge takes some grant away
+    every = reference({k: (r, s, np.zeros_like(e)) for k, (r, s, e) in w.items()},
+                      now_us)
+    assert any(every(r, u) and not g for (r, u), g in zip(pairs, want))
+    host = counters(HOST)
+    done = counters(["checks.device_definite", "engine.expiry_batches"])
+    got = c.check(background(), CS, *rels)
+    assert got == want
+    assert counters(HOST) == host
+    gained = [a - b for a, b in zip(
+        counters(["checks.device_definite", "engine.expiry_batches"]), done)]
+    assert gained[0] == len(rels) and gained[1] >= 1
+    snap = c.store.snapshot_for(CS)
+    oracle = SnapshotOracle(snap, {})
+    assert [oracle.check_relationship(r) == T for r in rels] == want
+    (dsnap,) = c._dsnap_cache.values()
+    meta = dsnap.flat_meta
+    assert meta.gates_expiry and meta.e_hasexp and meta.us_hasexp
+    assert meta.pf_has_u and not meta.pf_u_alllive and not meta.pf_s_alllive
+
+
+@pytest.mark.parametrize("case", sorted(NAMED))
+def test_a_named_case(case):
+    now_us = time.time_ns() // 1000
+    w = build_world(3, now_us)
+    repo, user, granted = NAMED[case]
+    assert reference(w, now_us)(repo, user) is granted
+    c = _named_client(w)
+    assert c.check(background(), CS, rel.must_from_triple(
+        f"repo:{repo}", "read", f"user:{user}")) == [granted]
+
+
+_CLIENTS = {}
+
+
+def _named_client(w):
+    if "named" not in _CLIENTS:
+        _CLIENTS["named"] = load(w)
+    return _CLIENTS["named"]
+
+
+def test_a_world_without_expiries_gates_nothing():
+    now_us = time.time_ns() // 1000
+    w = build_world(4, now_us)
+    c = new_tpu_evaluator()
+    ctx = background()
+    c.write_schema(ctx, SCHEMA.replace(" with expiration", ""))
+    for key, rtype, relation, stype, srel in SHAPES:
+        res, subj, _ = w[key]
+        c.import_relationship_columns(
+            ctx, resource_type=rtype, resource_ids=res, resource_relation=relation,
+            subject_type=stype, subject_ids=subj, subject_relation=srel)
+    before = counters(["engine.expiry_batches", "prepare.expiry_s.count"])
+    c.check(ctx, CS, rel.must_from_triple("repo:r1", "read", "user:u1"))
+    assert counters(["engine.expiry_batches", "prepare.expiry_s.count"]) == before
+    (dsnap,) = c._dsnap_cache.values()
+    assert not dsnap.flat_meta.gates_expiry
+
+
+def test_a_world_with_expiries_times_their_prepare():
+    now_us = time.time_ns() // 1000
+    before = counters(["prepare.expiry_s.count"])[0]
+    c = load(build_world(5, now_us))
+    c.check(background(), CS, rel.must_from_triple("repo:r1", "read", "user:u1"))
+    # the snapshot's expiry seconds and the fold's until slices
+    assert counters(["prepare.expiry_s.count"])[0] - before == 2
+
+
+EPOCH_US = 1_700_000_000_000_000
+EXPIRY_US = EPOCH_US + 5_300_000  # 5.3 s after the snapshot's epoch
+
+
+@pytest.mark.parametrize("at_us,device,exact", [
+    (EPOCH_US + 5_200_000, True, True),    # before the expiry: live on both
+    (EPOCH_US + 5_300_000, True, False),   # at it: expired, the device a second late
+    (EPOCH_US + 5_900_000, True, False),   # inside its second: still late
+    (EPOCH_US + 6_000_000, False, False),  # the next whole second: exact again
+])
+def test_an_expiry_in_the_checks_second_grants_until_the_next_second(
+        at_us, device, exact):
+    import datetime as dt
+
+    expiry = dt.datetime(1970, 1, 1, tzinfo=dt.timezone.utc) + dt.timedelta(
+        microseconds=EXPIRY_US)
+    rels = [
+        rel.must_from_triple("repo:a", "reader", "user:x").with_expiration(expiry),
+        rel.must_from_triple("team:t", "member", "user:y").with_expiration(expiry),
+        rel.must_from_triple("repo:b", "maintainer", "team:t#member")
+        .with_expiration(expiry + dt.timedelta(days=1)),
+    ]
+    cs = compile_schema(parse_schema(SCHEMA))
+    snap = build_snapshot(1, cs, Interner(), rels, epoch_us=EPOCH_US)
+    engine = DeviceEngine(cs, EngineConfig.for_schema(cs, flat_recursion=3))
+    dsnap = engine.prepare(snap)
+    assert dsnap.flat_meta is not None
+    checks = [rel.must_from_triple("repo:a", "read", "user:x"),
+              rel.must_from_triple("repo:b", "read", "user:y")]
+    d, p, ovf = engine.check_batch(dsnap, checks, now_us=at_us)
+    assert d.tolist() == p.tolist() == [device, device] and not ovf.any()
+    oracle = Oracle(cs, rels, {}, now_us=at_us)
+    assert [oracle.check_relationship(q) == T for q in checks] == [exact, exact]
