@@ -1537,7 +1537,9 @@ class DeviceEngine:
         a ``device.check_batch`` child whose children are the stages
         ``engine.lower`` / ``.enqueue`` / ``.fetch``; the NOOP span costs
         one branch.  A batch whose programs read expiries
-        (``FlatMeta.gates_expiry``) counts ``engine.expiry_batches``."""
+        (``FlatMeta.gates_expiry``) counts ``engine.expiry_batches``, and
+        ``engine.fold_until_row_batches`` too where they read the fold's
+        until slices as key + until rows (``FlatMeta.fold_until_rows``)."""
         if not rels:
             z = np.zeros(0, bool)
             return z, z, z
@@ -1550,8 +1552,11 @@ class DeviceEngine:
                 snap, rels, dsnap.strings, span=dsp
             )
             B = len(rels)
-            if dsnap.flat_meta is not None and dsnap.flat_meta.gates_expiry:
+            fm = dsnap.flat_meta
+            if fm is not None and fm.gates_expiry:
                 metrics.default.inc("engine.expiry_batches")
+                if fm.fold_until_rows:
+                    metrics.default.inc("engine.fold_until_row_batches")
             if latency:
                 out = self.latency_path(dsnap).dispatch(
                     queries, qctx, B, snap.now_rel32(now_us),

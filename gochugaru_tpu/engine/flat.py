@@ -58,6 +58,7 @@ from .hash import (
     probe_range,
     probe_rows,
     slice_blocks,
+    slice_rows,
     take_in_bounds,
 )
 from .packed import decode_block as _pk_decode
@@ -368,6 +369,22 @@ class FlatMeta:
             or self.pf_hasuntil
             or self.pf_has_u and not (self.pf_u_alllive and self.pf_s_alllive)
         )
+
+    @property
+    def fold_until_rows(self) -> bool:
+        """The programs of this snapshot read a fold slice's until values
+        through its key + until row table (``pfu_gku`` / ``csr_gdp``):
+        a not-all-live pf_u or closure slice on the split layout that
+        single-chip and direct routed snapshots keep."""
+        if not self.pf_has_u:
+            return False
+        u_rows = not self.pf_u_alllive and (
+            not self.sharded or self.part_serve and self.pf_direct
+        )
+        s_rows = not self.pf_s_alllive and (
+            not self.sharded or self.part_serve and self.pf_s_direct
+        )
+        return u_rows or s_rows
 
 
 def placement_split(dsnap) -> Dict[str, int]:
@@ -963,12 +980,43 @@ def _pf_starts(keys: np.ndarray, size: int) -> np.ndarray:
     return st.astype(np.int32)
 
 
-def _pf_col(a: np.ndarray, pad: int, fill) -> np.ndarray:
-    """One split pf-view row column: [pow2(rows+pad), 1] int32."""
-    n = _ceil_pow2(max(a.shape[0] + pad, 1))
-    padded = np.full((n, 1), fill, np.int32)
-    padded[: a.shape[0], 0] = a
-    return padded
+def _pf_table(cols, pad: int, fills) -> np.ndarray:
+    """One pf-view row table: [pow2(rows+pad), len(cols)] int32, column
+    j holding ``cols[j]`` and padded with ``fills[j]``."""
+    rows = cols[0].shape[0]
+    out = np.empty((_ceil_pow2(max(rows + pad, 1)), len(cols)), np.int32)
+    for j, (c, fill) in enumerate(zip(cols, fills)):
+        out[:rows, j] = c
+        out[rows:, j] = fill
+    return out
+
+
+def _pf_u_table(u_gk, u_until, pad: int):
+    """The fold's userset-side slice table and whether every row is
+    live: the 1-wide ``pfu_gk`` keys when none expires, else ONE
+    ``pfu_gku`` table of (gk, until) rows — the kernel reads a lane's
+    key and until with one row gather (hash.slice_rows)."""
+    from ..store.closure import NO_EXP
+
+    if not u_until.shape[0] or bool((u_until == NO_EXP).all()):
+        return {"pfu_gk": _pf_table((u_gk,), pad, (-1,))}, True
+    return {"pfu_gku": _pf_table((u_gk, u_until), pad, (-1, 0))}, False
+
+
+def _pf_csr_table(cl_k2, cl_d, cl_p, pad: int):
+    """The fold's subject-side (closure-by-source) slice table and
+    whether every row is live on both planes: the 1-wide ``csr_gk`` keys
+    when none expires, else ONE ``csr_gdp`` table of (gk, d_until,
+    p_until) rows — one row gather a lane (hash.slice_rows)."""
+    from ..store.closure import NO_EXP
+
+    if not cl_k2.shape[0] or bool(
+        (cl_d == NO_EXP).all() and (cl_p == NO_EXP).all()
+    ):
+        return {"csr_gk": _pf_table((cl_k2,), pad, (-1,))}, True
+    return {
+        "csr_gdp": _pf_table((cl_k2, cl_d, cl_p), pad, (-1, 0, 0))
+    }, False
 
 
 def _max_run_sorted(keys: np.ndarray) -> int:
@@ -988,32 +1036,20 @@ def _pf_view_tables(
     *, maps: SlotMaps, N: int, S1: int, fold_slots, config: EngineConfig,
     hk: Optional[Dict] = None,
 ):
-    """Single-chip pf_u / csr view tables: SPLIT 1-wide row columns
-    (narrow contiguous slices vectorize ~15× better than wide ones on
-    gather-poor CPUs; measured in-repo) with the row range resolved
-    DIRECTLY — ``pfu_start``/``csr_start`` offset arrays indexed by the
-    packed key itself, two element gathers per range — or through legacy
-    hash group tables when the key space is over budget.  Until columns
-    are omitted entirely when every row is unexpiring (the common case;
-    the kernel then skips the plane masks); where they ship, their
-    packing observes ``prepare.expiry_s``.  Returns (arrays, meta kw)."""
-    from ..store.closure import NO_EXP
-
-    out: Dict[str, np.ndarray] = {}
+    """Single-chip pf_u / csr view tables: row tables sliced a fan at a
+    time, with the row range resolved DIRECTLY — ``pfu_start``/
+    ``csr_start`` offset arrays indexed by the packed key itself, two
+    element gathers per range — or through legacy hash group tables when
+    the key space is over budget.  A slice whose rows are all unexpiring
+    (the common case) ships its 1-wide key column alone and the kernel
+    skips the plane masks; one that carries until values ships ONE table
+    of key + until rows (``_pf_u_table`` / ``_pf_csr_table``), whose
+    build observes ``prepare.expiry_s``.  Returns (arrays, meta kw)."""
     pad_u, pad_s = max(64, u_fan), max(64, s_fan)
-    out["pfu_gk"] = _pf_col(u_gk, pad_u, -1)
-    out["csr_gk"] = _pf_col(cl_k2, pad_s, -1)
     t0 = time.perf_counter()
-    u_alllive = bool((u_until == NO_EXP).all()) if u_until.shape[0] else True
-    if not u_alllive:
-        out["pfu_u"] = _pf_col(u_until, pad_u, 0)
-    s_alllive = (
-        bool((cl_d == NO_EXP).all() and (cl_p == NO_EXP).all())
-        if cl_k1.shape[0] else True
-    )
-    if not s_alllive:
-        out["csr_d"] = _pf_col(cl_d, pad_s, 0)
-        out["csr_p"] = _pf_col(cl_p, pad_s, 0)
+    out, u_alllive = _pf_u_table(u_gk, u_until, pad_u)
+    csr, s_alllive = _pf_csr_table(cl_k2, cl_d, cl_p, pad_s)
+    out.update(csr)
     if not (u_alllive and s_alllive):
         from ..utils import metrics
 
@@ -1105,13 +1141,14 @@ def _pack_domains(snap, config: EngineConfig) -> Dict:
 
 
 #: group tables and the row views their (glo, ghi) ranges index into —
-#: candidates per table because the single-chip fold keeps split 1-wide
-#: row columns instead of an interleaved view
+#: candidates per table because the single-chip fold keeps its own row
+#: tables (key column alone, or key + until rows) instead of the
+#: stacked view
 _PACK_GROUPS = {
     "usgx": ("usx",),
     "argx": ("arx",),
-    "pfugx": ("pfux", "pfu_gk"),
-    "csrgx": ("csrx", "csr_gk"),
+    "pfugx": ("pfux", "pfu_gk", "pfu_gku"),
+    "csrgx": ("csrx", "csr_gk", "csr_gdp"),
 }
 
 
@@ -2825,24 +2862,16 @@ def build_delta_arrays(
             # pf_has_u — a fold with no base userset rows can still grow
             # dl_pfu overlay rows mid-chain, and those intersect against
             # these tables
-            from ..store.closure import NO_EXP as _NO_EXP
-
             s_run = _max_run_sorted(cl_k1)
             if s_run > config.flat_fold_subj_fan_cap:
                 return None  # a subject's closure outgrew the tile cap
             s_fan = _round_fan(max(s_run, 1))
-            pad_s = max(64, s_fan)
-            out["csr_gk"] = _pf_col(cl_k2, pad_s, -1)
-            s_alllive = (
-                bool(
-                    (new_cl.c_d_until == _NO_EXP).all()
-                    and (new_cl.c_p_until == _NO_EXP).all()
-                )
-                if cl_k1.shape[0] else True
+            csr, s_alllive = _pf_csr_table(
+                cl_k2, new_cl.c_d_until, new_cl.c_p_until, max(64, s_fan)
             )
-            if not s_alllive:
-                out["csr_d"] = _pf_col(new_cl.c_d_until, pad_s, 0)
-                out["csr_p"] = _pf_col(new_cl.c_p_until, pad_s, 0)
+            out.update(csr)
+            # the base's table of the other liveness is dead now
+            drop_keys.append("csr_gk" if "csr_gdp" in csr else "csr_gdp")
             meta_up["pf_s_fan"] = s_fan
             meta_up["pf_s_alllive"] = s_alllive
             # hash-backed csr along the chain: rebuilding the dense
@@ -3699,21 +3728,18 @@ def make_flat_fn(
                     blk = sblock("csrx", lo, fanS)
                     blk = vbcast(valid[..., None], blk)
                     valid = por(valid)
-                    gk = jnp.where(valid, blk[..., 0], -1)
-                    dok = valid & (jnp.where(valid, blk[..., 1], 0) > now)
-                    pok = valid & (jnp.where(valid, blk[..., 2], 0) > now)
-                    return gk, dok, pok
-                gk = slice_blocks(arrs["csr_gk"], lo, fanS)[..., 0]
-                gk = jnp.where(valid, gk, -1)
-                if meta.pf_s_alllive:
+                elif meta.pf_s_alllive:
                     # None planes: containment alone grants both (the
                     # intersection then runs ONE reduce with no plane
                     # tiles — invalid lanes are already -1-masked)
-                    return gk, None, None
-                dv = slice_blocks(arrs["csr_d"], lo, fanS)[..., 0]
-                pv = slice_blocks(arrs["csr_p"], lo, fanS)[..., 0]
-                dok = valid & (jnp.where(valid, dv, 0) > now)
-                pok = valid & (jnp.where(valid, pv, 0) > now)
+                    gk = slice_blocks(arrs["csr_gk"], lo, fanS)[..., 0]
+                    return jnp.where(valid, gk, -1), None, None
+                else:
+                    # (gk, d, p) of a lane by one row gather
+                    blk = slice_rows(arrs["csr_gdp"], lo, fanS)
+                gk = jnp.where(valid, blk[..., 0], -1)
+                dok = valid & (jnp.where(valid, blk[..., 1], 0) > now)
+                pok = valid & (jnp.where(valid, blk[..., 2], 0) > now)
                 return gk, dok, pok
 
             slices = [csr_slice(q_k2)]
@@ -3828,16 +3854,16 @@ def make_flat_fn(
                     ublk = sblock("pfux", lo, fanU)
                     ublk = vbcast(valid[..., None], ublk)
                     valid = por(valid)
-                    gk = jnp.where(valid, ublk[..., 0], -1)
-                    live = valid & (jnp.where(valid, ublk[..., 1], 0) > now)
+                elif meta.pf_u_alllive:
+                    ublk = slice_blocks(arrs["pfu_gk"], lo, fanU)
                 else:
-                    gk = slice_blocks(arrs["pfu_gk"], lo, fanU)[..., 0]
-                    gk = jnp.where(valid, gk, -1)
-                    if meta.pf_u_alllive:
-                        live = valid
-                    else:
-                        uv = slice_blocks(arrs["pfu_u"], lo, fanU)[..., 0]
-                        live = valid & (jnp.where(valid, uv, 0) > now)
+                    # (gk, until) of a lane by one row gather
+                    ublk = slice_rows(arrs["pfu_gku"], lo, fanU)
+                gk = jnp.where(valid, ublk[..., 0], -1)
+                if split_u and meta.pf_u_alllive:
+                    live = valid
+                else:
+                    live = valid & (jnp.where(valid, ublk[..., 1], 0) > now)
                 nd2 = nd + 1
                 ud, up = pf_isect(gk, live)
                 refl = (gk == bq(q_k2, nd2)) & (bq(q_k2, nd2) >= 0)

@@ -327,16 +327,50 @@ def slice_blocks(tbl, start, cap: int):
     explicit jit(backend=...) override on a TPU host still traces the
     TPU form.  Both forms return the same bits (tests/test_hash.py)."""
     import jax
-    import jax.numpy as jnp
 
-    s = jnp.clip(start, 0, tbl.shape[0] - cap).reshape(-1)
     form = (
         _slice_blocks_flat if jax.default_backend() == "tpu"
         else _slice_blocks_dynamic
     )
+    return _clamped_blocks(form, tbl, start, cap)
+
+
+def slice_rows(tbl, start, cap: int):
+    """slice_blocks' contract for a table whose rows are read whole: on
+    TPU each of the ``cap`` lanes is ONE row gather that returns all w
+    values of the row — cap gathers a block, where slice_blocks' flat
+    form issues cap·w element gathers.  The chip prices a gather by the
+    op and the index, not by the byte: on one v5e a row gather of 32,768
+    3-wide rows took 141 µs where each of the three element gathers it
+    replaced took 271 µs (PERF.md §5).  Every other backend keeps the
+    dynamic_slice form.  Both forms return the same bits
+    (tests/test_hash.py)."""
+    import jax
+
+    form = (
+        _slice_rows_gather if jax.default_backend() == "tpu"
+        else _slice_blocks_dynamic
+    )
+    return _clamped_blocks(form, tbl, start, cap)
+
+
+def _clamped_blocks(form, tbl, start, cap: int):
+    """``form``'s [N, cap, w] blocks at ``start`` clamped into the table,
+    shaped back to ``start``'s shape + (cap, w)."""
+    import jax.numpy as jnp
+
+    s = jnp.clip(start, 0, tbl.shape[0] - cap).reshape(-1)
     return form(tbl, s, cap).reshape(
         tuple(jnp.shape(start)) + (cap, tbl.shape[1])
     )
+
+
+def _slice_rows_gather(tbl, s, cap: int):
+    """[N, cap, w] blocks at clamped int32[N] starts: cap row gathers,
+    lane j's whole row ``tbl[s + j]`` in each."""
+    import jax.numpy as jnp
+
+    return jnp.stack([take_in_bounds(tbl, s + j) for j in range(cap)], 1)
 
 
 def _slice_blocks_dynamic(tbl, s, cap: int):

@@ -393,13 +393,9 @@ def gathered_bytes_model(dsnap) -> BytesModel:
         if meta.pf_has_u:
             if meta.pf_direct:
                 total += charge("pfu_start", width * 2 * off("pfu_start"))
-                total += charge(
-                    "pfu_gk", width * meta.pf_u_fan * row("pfu_gk")
-                )
-                if not meta.pf_u_alllive:
-                    total += charge(
-                        "pfu_u", width * meta.pf_u_fan * row("pfu_u")
-                    )
+                # key column alone, or key + until rows (one is shipped)
+                for k in ("pfu_gk", "pfu_gku"):
+                    total += charge(k, width * meta.pf_u_fan * row(k))
             else:
                 total += charge("pfu_off", width * off("pfu_off"))
                 total += charge(
@@ -409,10 +405,8 @@ def gathered_bytes_model(dsnap) -> BytesModel:
             # subject-side closure slice: once per dispatch, not per node
             if meta.pf_s_direct:
                 total += charge("csr_start", 2 * off("csr_start"))
-                total += charge("csr_gk", meta.pf_s_fan * row("csr_gk"))
-                if not meta.pf_s_alllive:
-                    total += charge("csr_d", meta.pf_s_fan * row("csr_d"))
-                    total += charge("csr_p", meta.pf_s_fan * row("csr_p"))
+                for k in ("csr_gk", "csr_gdp"):
+                    total += charge(k, meta.pf_s_fan * row(k))
             else:
                 total += charge("csr_off", off("csr_off"))
                 total += charge("csrgx", meta.pf_s_cap * row("csrgx"))

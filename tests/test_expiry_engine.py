@@ -322,3 +322,180 @@ def test_an_expiry_in_the_checks_second_grants_until_the_next_second(
     assert d.tolist() == p.tolist() == [device, device] and not ovf.any()
     oracle = Oracle(cs, rels, {}, now_us=at_us)
     assert [oracle.check_relationship(q) == T for q in checks] == [exact, exact]
+
+
+#: the fold's slice tables: key + until rows where a slice carries
+#: expiries, the 1-wide key column alone where it is all live
+ROW_TABLES = {"csr_gdp": 3, "pfu_gku": 2}
+KEY_TABLES = {"csr_gk": 1, "pfu_gk": 1}
+SPLIT_COLUMNS = ("csr_d", "csr_p", "pfu_u")
+
+
+def _fold_tables(dsnap) -> dict:
+    return {k: a.shape for k, a in dsnap.arrays.items()
+            if k in ROW_TABLES or k in KEY_TABLES or k in SPLIT_COLUMNS}
+
+
+def test_an_expiring_fold_ships_one_row_table_a_slice():
+    """Expiring memberships: each fold slice is ONE table of key + until
+    rows (``csr_gdp`` = gk, d, p; ``pfu_gku`` = gk, until), padded as the
+    key column was (-1 keys, 0 untils past the rows), and no split
+    until column ships."""
+    now_us = time.time_ns() // 1000
+    c = load(build_world(6, now_us))
+    c.check(background(), CS, rel.must_from_triple("repo:r1", "read", "user:u1"))
+    (dsnap,) = c._dsnap_cache.values()
+    shapes = _fold_tables(dsnap)
+    assert sorted(shapes) == sorted(ROW_TABLES)
+    for k, w in ROW_TABLES.items():
+        a = np.asarray(dsnap.arrays[k])
+        rows = a.shape[0]
+        assert a.dtype == np.int32 and a.shape == (rows, w)
+        assert rows & (rows - 1) == 0
+        keys = a[:, 0]
+        n = int((keys >= 0).sum())
+        assert n and (keys[n:] == -1).all() and (a[n:, 1:] == 0).all()
+        assert rows >= n + 64  # the pad every real slice start needs
+    meta = dsnap.flat_meta
+    assert meta.fold_until_rows and not meta.pf_s_alllive
+    assert not meta.pf_u_alllive
+
+
+EXP_RELS_US = EXPIRY_US  # x's membership and read grant end 5.3 s in
+DAY_US = 24 * HOUR_US
+
+
+def _at(us: int):
+    import datetime as dt
+
+    return dt.datetime(1970, 1, 1, tzinfo=dt.timezone.utc) + dt.timedelta(
+        microseconds=us)
+
+
+def _row_world():
+    """team t0's members (x until 5.3 s after the epoch, y a day later)
+    reach repo b through a maintainer share and repo c through org o's
+    member share, and repo d through a share that ends with x: both fold
+    slices carry until values."""
+    return [
+        rel.must_from_triple("team:t0", "member", "user:x").with_expiration(
+            _at(EXP_RELS_US)),
+        rel.must_from_triple("team:t0", "member", "user:y").with_expiration(
+            _at(EXP_RELS_US + DAY_US)),
+        rel.must_from_triple("repo:b", "maintainer", "team:t0#member")
+        .with_expiration(_at(EXP_RELS_US + DAY_US)),
+        rel.must_from_triple("org:o", "member", "team:t0#member")
+        .with_expiration(_at(EXP_RELS_US + DAY_US)),
+        rel.must_from_triple("repo:c", "org", "org:o"),
+        rel.must_from_triple("repo:a", "reader", "user:x").with_expiration(
+            _at(EXP_RELS_US)),
+        rel.must_from_triple("repo:d", "maintainer", "team:t0#member")
+        .with_expiration(_at(EXP_RELS_US)),
+    ]
+
+
+@pytest.mark.parametrize("stage", ["prepared", "member write",
+                                   "member write to all live"])
+def test_row_tables_answer_as_the_oracle_either_side_of_an_expiry(stage):
+    """The device reads the fold's until slices from the row tables and
+    answers as the host oracle a second before x's expiry and a second
+    after it — on the prepared snapshot, after a membership write that
+    reships the closure slice incrementally (a new expiring member in,
+    y out), and after one that leaves every closure row live (the slice
+    goes back to its key column and the row table is dropped)."""
+    from gochugaru_tpu.store.delta import apply_delta
+
+    rels = _row_world()
+    cs = compile_schema(parse_schema(SCHEMA))
+    interner = Interner()
+    snap = build_snapshot(1, cs, interner, rels, epoch_us=EPOCH_US)
+    engine = DeviceEngine(cs, EngineConfig.for_schema(cs, flat_recursion=3))
+    dsnap = engine.prepare(snap)
+    want = set(ROW_TABLES)
+    users = ["user:x", "user:y"]
+    if stage != "prepared":
+        z = rel.must_from_triple("team:t0", "member", "user:z") \
+            .with_expiration(_at(EXP_RELS_US + 2 * DAY_US))
+        snap = apply_delta(snap, 2, [z], [rels[1]], interner=interner)
+        dsnap = engine.prepare(snap, prev=dsnap)
+        rels = [r for r in rels if r is not rels[1]] + [z]
+        users.append("user:z")
+        if stage == "member write to all live":
+            w = rel.must_from_triple("team:t0", "member", "user:w")
+            gone = [z, rels[0], rels[2]]  # z, x and the org share
+            snap = apply_delta(snap, 3, [w], gone, interner=interner)
+            dsnap = engine.prepare(snap, prev=dsnap)
+            rels = [r for r in rels if all(r is not g for g in gone)] + [w]
+            users.append("user:w")
+            want = {"csr_gk", "pfu_gku"}
+        assert dsnap.flat_meta.delta is not None  # the incremental reship
+    assert set(_fold_tables(dsnap)) == want
+    assert dsnap.flat_meta.fold_until_rows
+    checks = [rel.must_from_triple(f"repo:{r}", "read", u)
+              for r in "abcd" for u in users]
+    seen = set()
+    for at_us in (EXP_RELS_US - 1_300_000, EXP_RELS_US + 1_700_000):
+        d, p, ovf = engine.check_batch(dsnap, checks, now_us=at_us)
+        oracle = Oracle(cs, rels, {}, now_us=at_us)
+        exact = [oracle.check_relationship(q) == T for q in checks]
+        assert d.tolist() == p.tolist() == exact and not ovf.any()
+        seen.add(tuple(exact))
+    assert len(seen) == 2  # the expiries took grants away
+
+
+def test_an_all_live_fold_keeps_the_key_columns_and_their_bytes():
+    """A world with no expiry ships the fold's 1-wide key columns alone,
+    with the shapes and the gathered-bytes model the split layout gave
+    this world: 418 B a check, ``csr_gk`` 32 and ``pfu_gk`` 16 of it."""
+    from gochugaru_tpu.utils.perf import gathered_bytes_model
+
+    w = build_world(4, 1_700_000_000_000_000)
+    c = new_tpu_evaluator()
+    ctx = background()
+    c.write_schema(ctx, SCHEMA.replace(" with expiration", ""))
+    for key, rtype, relation, stype, srel in SHAPES:
+        res, subj, _ = w[key]
+        c.import_relationship_columns(
+            ctx, resource_type=rtype, resource_ids=res, resource_relation=relation,
+            subject_type=stype, subject_ids=subj, subject_relation=srel)
+    c.check(ctx, CS, rel.must_from_triple("repo:r1", "read", "user:u1"))
+    (dsnap,) = c._dsnap_cache.values()
+    assert _fold_tables(dsnap) == {"csr_gk": (1024, 1), "pfu_gk": (16384, 1)}
+    meta = dsnap.flat_meta
+    assert meta.pf_s_alllive and meta.pf_u_alllive and not meta.fold_until_rows
+    model = gathered_bytes_model(dsnap)
+    assert model.total == 418.0
+    assert model.per_table["csr_gk"] == 32.0 and model.per_table["pfu_gk"] == 16.0
+    assert not set(model.per_table) & (set(ROW_TABLES) | set(SPLIT_COLUMNS))
+
+
+def test_fold_until_row_batches_counts_the_batches_that_read_row_tables():
+    """``engine.fold_until_row_batches`` moves once a lowered batch on a
+    snapshot whose fold slices carry until values, with
+    ``engine.expiry_batches`` and ``intern.batch_calls``; on an all-live
+    snapshot it never moves while batches go on being lowered."""
+    names = ["engine.fold_until_row_batches", "engine.expiry_batches",
+             "intern.batch_calls"]
+    now_us = time.time_ns() // 1000
+    w = build_world(7, now_us)
+    c = load(w)
+    rels = [rel.must_from_triple(f"repo:{r}", "read", f"user:{u}")
+            for r, u in probes(w, 400, 7)]
+    before = counters(names)
+    for lo in range(0, len(rels), 100):  # four batches
+        c.check(background(), CS, *rels[lo:lo + 100])
+    moved = [a - b for a, b in zip(counters(names), before)]
+    assert moved[0] == moved[1] == moved[2] >= 4
+    live = new_tpu_evaluator()
+    ctx = background()
+    live.write_schema(ctx, SCHEMA.replace(" with expiration", ""))
+    for key, rtype, relation, stype, srel in SHAPES:
+        res, subj, _ = w[key]
+        live.import_relationship_columns(
+            ctx, resource_type=rtype, resource_ids=res, resource_relation=relation,
+            subject_type=stype, subject_ids=subj, subject_relation=srel)
+    before = counters(names)
+    for lo in range(0, len(rels), 100):
+        live.check(ctx, CS, *rels[lo:lo + 100])
+    moved = [a - b for a, b in zip(counters(names), before)]
+    assert moved[0] == moved[1] == 0 and moved[2] >= 4

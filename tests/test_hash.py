@@ -2,6 +2,7 @@
 agreement, exact probes, range probes, duplicates, and empties."""
 
 import numpy as np
+import pytest
 
 import jax.numpy as jnp
 
@@ -234,6 +235,55 @@ def test_slice_blocks_flat_gather_form_is_bitwise_the_dynamic_slice():
         dyn = jax.jit(H._slice_blocks_dynamic, static_argnums=2)(tbl, s, cap)
         assert flat.dtype == dyn.dtype and flat.shape == (202, cap, w)
         assert np.array_equal(np.asarray(flat), np.asarray(dyn))
+
+
+@pytest.mark.parametrize("w", [2, 3])
+def test_slice_rows_row_gather_is_bitwise_slice_blocks_of_the_split_columns(
+    monkeypatch, w
+):
+    """A key + until row table (the fold's ``pfu_gku`` is 2 wide,
+    ``csr_gdp`` 3) is read by slice_rows, one row gather a lane on TPU:
+    forced here, it returns bit for bit what slice_blocks of each split
+    1-wide column returns — in its CPU form and in its TPU flat form —
+    at starts from 0 through mid-table to rows − cap."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from gochugaru_tpu.engine import hash as H
+
+    rng = np.random.default_rng(40 + w)
+    rows, cap = 1 << 10, 32
+    cols = [
+        rng.integers(-(2 ** 31), 2 ** 31 - 1, rows).astype(np.int32)
+        for _ in range(w)
+    ]
+    tbl = jnp.asarray(np.stack(cols, axis=1))
+    starts = jnp.asarray(np.concatenate([
+        [0, 1, rows // 2, rows - cap - 1, rows - cap],
+        rng.integers(0, rows - cap + 1, 59),
+    ]).astype(np.int32).reshape(8, 8))
+
+    def split(form_backend):
+        monkeypatch.setattr(jax, "default_backend", lambda: form_backend)
+        return [
+            np.asarray(H.slice_blocks(jnp.asarray(c[:, None]), starts, cap))
+            for c in cols
+        ]
+
+    cpu, flat = split("cpu"), split("tpu")
+    called = []
+    orig = H._slice_rows_gather
+    monkeypatch.setattr(
+        H, "_slice_rows_gather", lambda *a: called.append(1) or orig(*a)
+    )
+    got = np.asarray(H.slice_rows(tbl, starts, cap))
+    assert called and got.shape == (8, 8, cap, w) and got.dtype == np.int32
+    for j in range(w):
+        assert np.array_equal(got[..., j], cpu[j][..., 0])
+        assert np.array_equal(got[..., j], flat[j][..., 0])
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    assert np.array_equal(np.asarray(H.slice_rows(tbl, starts, cap)), got)
 
 
 def test_slice_blocks_picks_the_flat_form_on_tpu(monkeypatch):

@@ -450,3 +450,73 @@ def test_v5e_compiles_fused_programs_without_a_table_copy(
     assert _whole_table_ops(hlo, tables) == []
     # the parent's programs held an 8 GiB relayout of a 128 MiB table
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+#: the fold's key + until row tables at rbac10m_exp's row counts
+EXP_ROWS = {"csr_gdp": (2_097_152, 3), "pfu_gku": (8_388_608, 2)}
+
+
+def test_v5e_reads_an_expiring_folds_slices_a_row_gather_a_lane(
+    one_chip, monkeypatch
+):
+    """The check program of a world whose fold slices expire, compiled
+    for a described v5e with its row tables at rbac10m_exp's sizes: each
+    lane of a slice is one row gather (``s32[B, 3]`` of ``csr_gdp``,
+    ``s32[B, 2]`` of ``pfu_gku``), nothing reads a slice table as a flat
+    column, and no copy, transpose or reshape takes a whole one."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    import test_expiry_engine as te
+    from gochugaru_tpu import rel
+    from gochugaru_tpu.utils.context import background
+
+    w = te.build_world(6, NOW)
+    c = te.load(w)
+    rels = [rel.must_from_triple(f"repo:{r}", "read", f"user:{u}")
+            for r, u in te.probes(w, 64, 6)]
+    c.check(background(), te.CS, *rels)
+    (dsnap,) = c._dsnap_cache.values()
+    meta = dsnap.flat_meta
+    assert meta.fold_until_rows and set(EXP_ROWS) <= set(dsnap.arrays)
+    B = 4096
+    queries, qctx = c._engine._lower_queries(
+        dsnap.snapshot, (rels * (B // len(rels) + 1))[:B], dsnap.strings
+    )
+    # the program keys its read forms off the default backend at trace
+    # time: trace the TPU ones
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    fn, args = c._engine.flat_fn_and_args(
+        dsnap, queries, qctx, jnp.int32(0), B, jit=False, bucket_min=B
+    )
+    big = {id(dsnap.arrays[k]): shape for k, shape in EXP_ROWS.items()}
+
+    def described(a):
+        a = a if hasattr(a, "shape") else np.asarray(a)
+        return jax.ShapeDtypeStruct(
+            big.get(id(a), tuple(a.shape)), a.dtype, sharding=one_chip
+        )
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        compiled = jax.jit(fn).lower(
+            *jax.tree_util.tree_map(described, args)
+        ).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        cc.reset_cache()
+    hlo = compiled.as_text()
+
+    def gathers(shape):
+        return len(re.findall(rf"= s32\[{shape}\]\{{[^}}]*\}} gather\(", hlo))
+
+    slices = 2 if meta.has_wc_closure else 1
+    assert gathers(f"{B},3") == meta.pf_s_fan * slices
+    assert gathers(f"{B},2") > 0 and gathers(f"{B},2") % meta.pf_u_fan == 0
+    for rows, _w in EXP_ROWS.values():
+        assert f"s32[{rows}]" not in hlo  # no flat view of a slice table
+    assert _whole_table_ops(hlo, {k: s for k, s in EXP_ROWS.items()}) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
