@@ -1141,7 +1141,8 @@ class DeviceEngine:
 
         # dedup request contexts (the caveat_context of the query
         # relationship IS the request context, client/client.go:241-259)
-        # a parameter column at a time, and encode them:
+        # in the native pass where the pull ran and it takes the batch,
+        # else a parameter column at a time, and encode them:
         # ``engine.context_s``, a batch, on a schema with caveats.  A check
         # with an empty context keeps -1; any other gets a row, even one
         # that names no parameter
@@ -1149,7 +1150,13 @@ class DeviceEngine:
         q_ctx = np.full(B, -1, np.int32)
         ctx_rows: List[Mapping] = []
         keyed = by_repr = 0
-        if self.caveat_plan is not None:
+        grouped = None
+        if self.caveat_plan is not None and pulled is not None:
+            grouped = _native_lower.contexts(
+                rels, self.caveat_plan.slots_of_param)
+        if grouped is not None:
+            q_ctx, ctx_rows, keyed = grouped
+        elif self.caveat_plan is not None:
             contexts = [r.caveat_context for r in rels]
             at = np.flatnonzero(np.fromiter(map(bool, contexts), bool, B))
             if at.size:
@@ -1175,7 +1182,10 @@ class DeviceEngine:
             m.inc("engine.context_checks", int(np.count_nonzero(q_ctx >= 0)))
             m.inc("engine.context_keyed_columns", keyed)
             m.inc("engine.context_repr_columns", by_repr)
-            st.note(context_s=round(context_s, 6), contexts=len(ctx_rows))
+            if grouped is not None:
+                m.inc("engine.context_native_batches")
+            st.note(context_s=round(context_s, 6), contexts=len(ctx_rows),
+                    native_ctx=grouped is not None)
         queries = {
             "q_res": q_res, "q_perm": q_perm, "q_subj": q_subj,
             "q_srel": q_srel, "q_wc": q_wc, "q_ctx": q_ctx,

@@ -9,7 +9,8 @@ built/loaded (no compiler, exotic platform), ``available()`` is False and
 callers fall back to the pure-numpy/python paths with identical results.
 
 A second library, ``lower.cpp``, is the bulk check lowering's pull over a
-batch of ``Relationship`` objects (``native/lower.py``): it reads Python
+batch of ``Relationship`` objects and the grouping of their request
+contexts (``native/lower.py``): it reads Python
 objects, so it is compiled against the interpreter's headers
 (``sysconfig.get_paths()["include"]``) and loaded with ``ctypes.PyDLL``,
 which keeps the interpreter lock through each call.  It is built and
@@ -208,6 +209,11 @@ def _bind_lower(lib: ctypes.CDLL) -> None:
         c.py_object, c.c_int64, c.py_object, c.py_object, c.py_object,
         c.POINTER(c.c_int64), c.POINTER(c.c_int32),
         c.POINTER(c.c_int32), c.POINTER(c.c_int32),
+    ]
+    lib.gl_contexts.restype = c.py_object
+    lib.gl_contexts.argtypes = [
+        c.py_object, c.c_int64, c.py_object, c.POINTER(c.c_int32),
+        c.POINTER(c.c_int64),
     ]
 
 

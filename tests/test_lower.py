@@ -2,9 +2,10 @@
 int32 query columns against a per-key reference, concurrent callers
 against one, on both interners and through both passes over the
 ``Relationship`` objects (the native pull of ``native/lower.cpp`` and the
-Python pass it falls back to), and the subject-row table of the
-two-phase programs (``subject_rows``), which the lowering no longer
-builds."""
+Python pass it falls back to), the request contexts of a caveat world
+through both (the native grouping and ``dedup_contexts``), and the
+subject-row table of the two-phase programs (``subject_rows``), which the
+lowering no longer builds."""
 
 import ctypes
 import dataclasses
@@ -17,6 +18,7 @@ import pytest
 
 from gochugaru_tpu import native, rel
 from gochugaru_tpu.engine.device import DeviceEngine, subject_rows
+from gochugaru_tpu.native import lower as native_lower
 from gochugaru_tpu.engine.plan import EngineConfig
 from gochugaru_tpu.rel.relationship import (
     WILDCARD_ID,
@@ -306,6 +308,94 @@ def test_without_the_pull_the_python_pass_gives_the_same_columns(
     assert _native_batches() - before == 1
     for k, col in want.items():
         assert col.dtype == got[k].dtype and np.array_equal(col, got[k]), k
+
+
+CAVEAT_SCHEMA = """
+caveat same_tenant(tenant string, edge_tenant string, tier int) {
+    tenant == edge_tenant && tier >= 1
+}
+definition user {}
+definition doc {
+  relation holder: user with same_tenant
+  permission view = holder
+}
+"""
+
+
+def _caveat_world():
+    """Holders under three tenants, on the native interner; returns the
+    engine and its prepared snapshot."""
+    from gochugaru_tpu.native.interner import NativeInterner
+
+    cs = compile_schema(parse_schema(CAVEAT_SCHEMA))
+    stored = [rel.must_from_triple(f"doc:d{i}", "holder", f"user:u{i % 5}")
+              .with_caveat("same_tenant", {"edge_tenant": f"t{i % 3}", "tier": 2})
+              for i in range(20)]
+    snap = build_snapshot(1, cs, NativeInterner(), stored,
+                          epoch_us=1_700_000_000_000_000)
+    engine = DeviceEngine(cs)
+    return engine, engine.prepare(snap)
+
+
+def _caveat_checks(n, seed):
+    """Checks carrying ``{tenant, tier}`` as the caveat cell sends them, a
+    sixth of them empty, some with an unknown tenant."""
+    rng = np.random.default_rng(seed)
+    sent = {k: {"tenant": f"t{k}", "tier": 2} for k in range(5)}
+    out = []
+    for d, u, k in zip(*(rng.integers(0, m, n).tolist() for m in (25, 6, 6))):
+        r = rel.must_from_triple(f"doc:d{d}", "view", f"user:u{u}")
+        out.append(r.with_caveat("", sent[k]) if k < 5 else r)
+    return out
+
+
+def _context_batches():
+    return metrics.default.counter("engine.context_native_batches")
+
+
+@pytest.mark.skipif(not native.available(), reason="no native interner")
+@pytest.mark.parametrize("how", ["disabled", "missing", "failed-build"])
+def test_without_the_library_the_contexts_group_in_python(
+        how, monkeypatch, tmp_path):
+    """The native grouping and the Python pass that runs without the
+    lowering library give one ``q_ctx`` and one context table; only the
+    first moves ``engine.context_native_batches``."""
+    engine, dsnap = _caveat_world()
+    rels = _caveat_checks(2_000, 43)
+    before = _context_batches()
+    want, want_t = engine._lower_queries(dsnap.snapshot, rels, dsnap.strings)
+    assert _context_batches() - before == 1
+    assert (want["q_ctx"] < 0).any() and want["q_ctx"].max() == 4
+    if how == "disabled":
+        monkeypatch.setattr(native, "_forced_off", native._forced_off)
+        native.set_enabled(False)
+    else:
+        _no_pull(monkeypatch, tmp_path,
+                 "#error a broken build\n" if how == "failed-build" else None)
+    assert native_lower.contexts(rels, engine.caveat_plan.slots_of_param) is None
+    got, got_t = engine._lower_queries(dsnap.snapshot, rels, dsnap.strings)
+    assert _context_batches() - before == 1
+    assert got["q_ctx"].tolist() == want["q_ctx"].tolist()
+    for name in ("vi", "vf", "pr", "host"):
+        assert np.array_equal(got_t[name], want_t[name]), name
+
+
+@pytest.mark.skipif(not native.available(), reason="no native interner")
+def test_the_native_grouping_answers_as_the_python_pass():
+    """``check_batch`` over a caveat world: the planes of the native
+    grouping equal those of the Python pass, batch for batch."""
+    engine, dsnap = _caveat_world()
+    rels = _caveat_checks(600, 44)
+    before = _context_batches()
+    got = [np.asarray(a) for a in engine.check_batch(dsnap, rels, now_us=NOW_US)]
+    assert _context_batches() - before == (
+        1 if native.lower_lib() is not None else 0)
+    with pytest.MonkeyPatch.context() as m:
+        m.setattr(native, "_forced_off", True)
+        want = [np.asarray(a) for a in engine.check_batch(dsnap, rels, now_us=NOW_US)]
+    for a, b in zip(got, want):
+        assert np.array_equal(a, b)
+    assert got[0].any() and not got[0].all()
 
 
 def test_the_reference_settles_the_rows_it_is_there_for():
